@@ -4,6 +4,7 @@ pmean FedAvg, multi-round, checkpoint/resume (the TPU-native deployment)."""
 from __future__ import annotations
 
 import os
+from typing import Any, NamedTuple
 
 import numpy as np
 
@@ -18,7 +19,21 @@ from .common import (
 log = get_logger()
 
 
+class FederatedRun(NamedTuple):
+    """What one ``fedtpu federated`` launch built and ended with — for a
+    caller that checks the run itself (chip_smoke.py), not its exit code."""
+
+    trainer: Any
+    state: Any  # the final FedState, as checkpointed
+    round_losses: list  # per round run this launch: [E, C] epoch-mean losses
+
+
 def cmd_federated(args) -> int:
+    run_federated(args)
+    return 0
+
+
+def run_federated(args) -> FederatedRun:
     import jax
 
     from ..data import stack_clients_ragged, tokenize_client
@@ -205,6 +220,7 @@ def cmd_federated(args) -> int:
         [c.val for c in clients], target_rows=val_rows_global
     )
     history = []
+    round_losses = []
     with trace(getattr(args, "profile_dir", None)):
         for r in range(start_round, cfg.fed.rounds):
             anchor = trainer.round_anchor(state)
@@ -212,6 +228,7 @@ def cmd_federated(args) -> int:
                 state, losses = trainer.fit_local(
                     state, stacked_train, epoch_offset=r * cfg.train.epochs_per_round
                 )
+                round_losses.append(losses)
                 local_val = trainer.evaluate_clients(
                     state.params, prepared=prepared_val
                 )
@@ -405,7 +422,7 @@ def cmd_federated(args) -> int:
                 _write_reports(c, final_local[c], final_agg[c], cfg.output_dir)
         if final_pers is not None:
             _save_phase_csvs(final_pers, "personalized", cfg.output_dir)
-    return 0
+    return FederatedRun(trainer, state, round_losses)
 
 
 def _save_phase_csvs(metrics: list, phase_name: str, out_dir: str) -> None:

@@ -63,6 +63,7 @@ import json
 import sys
 from typing import Sequence
 
+from ..utils.compile_cache import place_compile_cache
 from .check import cmd_check
 from .comm import cmd_client, cmd_relay, cmd_serve
 from .common import resolve_config
@@ -1961,5 +1962,8 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main(argv: Sequence[str] | None = None) -> int:
     args = build_parser().parse_args(argv)
+    # Before any command can compile: one cache directory for this process
+    # and its children (jax-free — serve/route/relay never import jax).
+    place_compile_cache()
     return args.fn(args)
 

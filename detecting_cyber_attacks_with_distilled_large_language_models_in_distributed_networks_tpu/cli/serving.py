@@ -31,7 +31,13 @@ def _parse_buckets(spec: str) -> tuple[int, ...]:
     return buckets
 
 
-def cmd_infer_serve(args) -> int:
+def build_infer_server(args):
+    """Everything ``infer-serve`` does before it listens: resolve the
+    config, restore the weights, build engine, batcher and watcher.
+    Returns the un-started :class:`ScoringServer` and the one-line
+    description of the deployment; the caller starts it, serves, and
+    closes it (cmd_infer_serve until interrupted; chip_smoke.py for a
+    few requests)."""
     from ..data.datasets import get_dataset
     from ..serving import (
         CheckpointWatcher,
@@ -207,13 +213,18 @@ def cmd_infer_serve(args) -> int:
         if registry_dir
         else ("checkpoint dir" if cfg.checkpoint_dir else "off")
     )
+    return server, (
+        f"scoring {cfg.data.dataset} flows on "
+        f"{args.host}:{server.port} (model round {engine.round_id}; "
+        f"hot reload: {reload_src}; auth "
+        f"{'on' if auth_key else 'off — open port'})"
+    )
+
+
+def cmd_infer_serve(args) -> int:
+    server, banner = build_infer_server(args)
     with server:
-        log.info(
-            f"[SERVE] scoring {cfg.data.dataset} flows on "
-            f"{args.host}:{server.port} (model round {engine.round_id}; "
-            f"hot reload: {reload_src}; auth "
-            f"{'on' if auth_key else 'off — open port'})"
-        )
+        log.info(f"[SERVE] {banner}")
         try:
             while True:
                 time.sleep(60.0)

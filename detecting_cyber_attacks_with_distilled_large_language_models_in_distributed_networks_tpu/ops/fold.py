@@ -19,17 +19,15 @@ bit-identical while moving HOW the elements are visited:
   while each accumulator block is touched once. Measured ~2.5x over
   ``naive`` once the K-leaf working set exceeds the host's last-level
   cache (the regime a 64-client round at model scale lives in).
-* ``pallas`` — a Pallas TPU kernel gridded over element blocks, each
-  program accumulating its block over K in ascending order (the same
-  per-element order; multiply kept separate from the add so the
-  compiler cannot contract them into one fused rounding). Selected only
-  on TPU hosts, and only if the kernel actually compiles — any failure
-  falls back to ``blocked`` permanently for the process.
 
-Engine choice: ``FEDTPU_FOLD_ENGINE=naive|blocked|pallas`` overrides;
-otherwise ``pallas`` on TPU backends, ``blocked`` elsewhere. The choice
-is made once per process and is observable (``engine_name``) so the
-wire-overlap span and bench record can name what folded.
+Both run on the host: the leaves arrive over the wire as numpy arrays and
+the aggregate leaves the same way, so a device engine would first have
+to ship K leaves to the chip.
+
+Engine choice: ``FEDTPU_FOLD_ENGINE=naive|blocked`` overrides; otherwise
+``blocked``. The choice is made once per process and is observable
+(``engine_name``) so the wire-overlap span and bench record can name
+what folded.
 
 Determinism contract (``fedtpu check`` SCOPE): every engine is a pure
 function of (leaves, weights) — no clocks, no RNG, no set iteration —
@@ -40,7 +38,6 @@ shuffled-arrival property test in tests/test_wire_efficiency.py).
 from __future__ import annotations
 
 import os
-import sys
 from typing import Sequence
 
 import numpy as np
@@ -50,9 +47,8 @@ import numpy as np
 #: temporary stay L2-resident on commodity hosts.
 FOLD_BLOCK_ELEMS = 1 << 15
 
-_ENGINES = ("naive", "blocked", "pallas")
+_ENGINES = ("naive", "blocked")
 _engine: str | None = None
-_pallas_fold = None
 
 
 def _pick_engine() -> str:
@@ -63,17 +59,6 @@ def _pick_engine() -> str:
                 f"FEDTPU_FOLD_ENGINE={env!r} (want {'|'.join(_ENGINES)})"
             )
         return env
-    # Never *introduce* a jax import here: an aggregation-only server is
-    # numpy+sockets and must stay that way. A TPU host that can use the
-    # Pallas engine has jax loaded already (device runtime init); anyone
-    # else opts in explicitly with FEDTPU_FOLD_ENGINE=pallas.
-    jax = sys.modules.get("jax")
-    if jax is not None:
-        try:
-            if jax.default_backend() == "tpu":
-                return "pallas"
-        except Exception:
-            pass
     return "blocked"
 
 
@@ -83,13 +68,6 @@ def engine_name() -> str:
     if _engine is None:
         _engine = _pick_engine()
     return _engine
-
-
-def _demote(reason: str) -> None:
-    """Pallas failed to build/run: fall back to ``blocked`` for the rest
-    of the process (retrying per-fold would recompile per-fold)."""
-    global _engine
-    _engine = "blocked"
 
 
 def fold_naive(
@@ -125,62 +103,6 @@ def fold_blocked(
     return acc.reshape(leaves[0].shape)
 
 
-def _build_pallas_fold(n_leaves: int, n_padded: int, block: int):
-    """Compile the TPU fold kernel for a (K, padded-n) problem shape.
-    Grid over element blocks; each program runs the full ascending-K
-    accumulation for its block — multiply kept separate from the add so
-    Mosaic cannot contract the pair into a fused multiply-add (which
-    rounds once, not twice, and would break bit-exactness vs numpy)."""
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-
-    def kernel(w_ref, x_ref, o_ref):
-        def body(k, acc):
-            t = x_ref[k, :] * w_ref[k]
-            return acc + t
-
-        o_ref[:] = jax.lax.fori_loop(
-            0, n_leaves, body, jnp.zeros(o_ref.shape, jnp.float32)
-        )
-
-    grid = n_padded // block
-    fold = pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct((n_padded,), jnp.float32),
-        grid=(grid,),
-        in_specs=[
-            pl.BlockSpec((n_leaves,), lambda i: (0,)),
-            pl.BlockSpec((n_leaves, block), lambda i: (0, i)),
-        ],
-        out_specs=pl.BlockSpec((block,), lambda i: (i,)),
-    )
-    return jax.jit(fold)
-
-
-def fold_pallas(
-    leaves: Sequence[np.ndarray], weights: Sequence[np.float32]
-) -> np.ndarray:
-    """TPU kernel fold. Raises on non-TPU/compile failure — callers go
-    through :func:`fold_ordered`, which demotes to ``blocked``."""
-    global _pallas_fold
-    n = leaves[0].size
-    k = len(leaves)
-    # Lane-aligned block: fp32 tiles are (8, 128); 8 * 128 * 32 = 32768
-    # elements keeps the kernel's VMEM footprint modest at any K.
-    block = min(FOLD_BLOCK_ELEMS, max(1024, 1 << (max(n, 1) - 1).bit_length()))
-    n_padded = -(-n // block) * block
-    key = (k, n_padded, block)
-    if _pallas_fold is None or _pallas_fold[0] != key:
-        _pallas_fold = (key, _build_pallas_fold(k, n_padded, block))
-    stack = np.zeros((k, n_padded), np.float32)
-    for i, arr in enumerate(leaves):
-        stack[i, :n] = arr.reshape(-1)
-    w = np.asarray([np.float32(w) for w in weights], np.float32)
-    out = np.asarray(_pallas_fold[1](w, stack))
-    return out[:n].reshape(leaves[0].shape)
-
-
 def fold_ordered(
     leaves: Sequence[np.ndarray],
     weights: Sequence[np.float32],
@@ -194,13 +116,7 @@ def fold_ordered(
         raise ValueError("fold_ordered needs at least one leaf")
     flat = [np.ascontiguousarray(a, np.float32).reshape(-1) for a in leaves]
     eng = engine or engine_name()
-    if eng == "pallas":
-        try:
-            out = fold_pallas(flat, weights)
-        except Exception as e:  # compile/runtime failure: demote once
-            _demote(str(e))
-            out = fold_blocked(flat, weights)
-    elif eng == "blocked":
+    if eng == "blocked":
         out = fold_blocked(flat, weights)
     else:
         out = fold_naive(flat, weights)

@@ -50,7 +50,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..train.engine import apply_warmup, prox_sq
 from .fedavg import stack_params
-from .mesh import shard_map
 
 
 def make_seq_mesh(
@@ -132,7 +131,7 @@ def make_fedseq_loss(
     ]
     if dropout:
         in_specs.append(P(clients_axis))
-    return shard_map(
+    return jax.shard_map(
         local_losses,
         mesh=mesh,
         in_specs=tuple(in_specs),
@@ -209,7 +208,7 @@ def make_fedseq_masked_loss(
     ]
     if dropout:
         in_specs.append(P(clients_axis))
-    return shard_map(
+    return jax.shard_map(
         local_losses,
         mesh=mesh,
         in_specs=tuple(in_specs),
@@ -325,7 +324,7 @@ def make_fedseq_packed_loss(
     in_specs += [P(data_axis, seq_axis), P(data_axis, seq_axis), P(data_axis)]
     if dropout:
         in_specs.append(P())
-    return shard_map(
+    return jax.shard_map(
         local_loss,
         mesh=mesh,
         in_specs=tuple(in_specs),
@@ -528,7 +527,7 @@ def build_fedseq_steps(cfg, model, optimizer, mesh: Mesh) -> FedSeqSteps:
         counts = jax.vmap(counts_one)(ce, logits, labels_l, valid_l)
         return counts, probs
 
-    eval_inner = shard_map(
+    eval_inner = jax.shard_map(
         local_eval,
         mesh=mesh,
         in_specs=(

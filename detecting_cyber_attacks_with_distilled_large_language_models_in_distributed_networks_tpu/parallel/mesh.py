@@ -25,24 +25,6 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-# ``jax.shard_map`` became public API only in newer JAX; older versions
-# (e.g. 0.4.x) ship it as jax.experimental.shard_map. One compat binding
-# here so every shard_map call site (fedseq, ring attention, tests) runs
-# on both.
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:  # pragma: no cover - exercised on jax<0.5 environments
-    from jax.experimental.shard_map import shard_map as _experimental_shard_map
-
-    def shard_map(f, **kw):
-        # check_rep=False: the experimental version's replication checker
-        # has the known scan-carry mismatch bug (jax#21945-adjacent) that
-        # the ring attention scan trips; newer JAX tracks varying axes
-        # properly (see ring_attention.py's vma/pcast handling) and keeps
-        # the check on.
-        kw.setdefault("check_rep", False)
-        return _experimental_shard_map(f, **kw)
-
 
 def make_mesh(
     clients: int = 1,
@@ -65,7 +47,8 @@ def make_mesh(
     if len(devs) < need:
         raise ValueError(
             f"mesh {'x'.join(map(str, dims))} needs {need} devices, have "
-            f"{len(devs)} (tests: jax.config.update('jax_num_cpu_devices', N))"
+            f"{len(devs)} (a virtual CPU mesh: JAX_PLATFORMS=cpu plus "
+            "jax.config.update('jax_num_cpu_devices', N) before first use)"
         )
     grid = np.array(devs[:need]).reshape(dims)
     return Mesh(grid, axis_names)

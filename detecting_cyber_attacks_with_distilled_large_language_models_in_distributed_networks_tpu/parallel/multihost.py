@@ -57,20 +57,7 @@ def initialize(
 
     # NOTE: no jax.devices()/process_count() before jax.distributed
     # initializes — any backend touch would lock in a single-process runtime.
-    # jax.distributed.is_initialized is newer-JAX API; on older versions
-    # the only signal is the internal global_state client handle (absent
-    # or unreadable -> treat as not initialized, the safe default).
-    _inited = getattr(jax.distributed, "is_initialized", None)
-    if _inited is not None:
-        already = _inited()
-    else:
-        try:
-            from jax._src import distributed as _distributed
-
-            already = _distributed.global_state.client is not None
-        except Exception:
-            already = False
-    if already:
+    if jax.distributed.is_initialized():
         return jax.process_count() > 1
     configured = (
         coordinator_address is not None

@@ -157,14 +157,10 @@ def ring_attention(
     # over every mesh axis q varies over (the ring axis alone inside a pure
     # seq shard_map; clients/data too inside the 3-axis fedseq composition);
     # mark them varying up front so the scan carry types match.
-    # (jax.typeof and the vma/pcast machinery exist only on newer JAX;
-    # older versions' shard_map has no varying-axis avals, so want_vma is
-    # empty there and _vary is the identity.)
-    _typeof = getattr(jax, "typeof", lambda _x: None)
-    want_vma = tuple(getattr(_typeof(q), "vma", ()) or ())
+    want_vma = tuple(jax.typeof(q).vma)
 
     def _vary(x):
-        have = getattr(_typeof(x), "vma", ()) or ()
+        have = jax.typeof(x).vma
         missing = tuple(a for a in want_vma if a not in have)
         if not missing:
             return x
@@ -277,10 +273,9 @@ def _sharded_ring_fn(
         + ((bias_spec,) if has_bias else ())
         + ((P(),) if has_rng else ())
     )
-    from .mesh import shard_map
 
     return jax.jit(
-        shard_map(
+        jax.shard_map(
             call, mesh=mesh, in_specs=in_specs, out_specs=seq_spec
         )
     )

@@ -102,9 +102,8 @@ class Checkpointer:
         # The saved leaf-shape manifest (internal "_leaf_shapes" key) rides
         # the JSON meta so ANY later manager instance can check template
         # compatibility before restoring — orbax's own array metadata is
-        # only readable by the manager that saved (handler registry), and
-        # some orbax versions restore into mismatched template shapes
-        # silently (see saved_compatible).
+        # only readable by the manager that saved (handler registry); see
+        # saved_compatible.
         # Tree-leaves order, NOT sorted: a multiset compare would miss two
         # tables swapping sizes (vocab 128/pos 140 -> vocab 140/pos 128 has
         # the identical shape multiset); leaves order is deterministic for
@@ -140,12 +139,12 @@ class Checkpointer:
     def saved_compatible(self, template: Any, *, step: int | None = None) -> bool:
         """Pre-restore compatibility gate: does the checkpoint's saved
         per-leaf shape list (the "_leaf_shapes" manifest save() records,
-        in tree-leaves order) match the template's? Some orbax versions
-        (0.7.x) silently restore a checkpoint into DIFFERENT template
-        shapes instead of raising — e.g. a vocab-100 embedding into a
-        vocab-140 array — which would mistrain far from the restore site.
-        Checkpoints predating the manifest -> True (the restore call
-        itself then decides)."""
+        in tree-leaves order) match the template's? A restore into
+        DIFFERENT template shapes — e.g. a vocab-100 embedding into a
+        vocab-140 array — must be refused here, by our own record, not
+        left to whatever the restore call does with the mismatch: it
+        would mistrain far from the restore site. Checkpoints predating
+        the manifest -> True (the restore call itself then decides)."""
         step = self.latest_step() if step is None else step
         if step is None:
             return False
@@ -179,23 +178,6 @@ class Checkpointer:
         if step is None:
             raise FileNotFoundError(f"no checkpoint in {self.directory}")
         abstract = _abstract(template)
-        if not hasattr(ocp, "PLACEHOLDER"):
-            # Older orbax without placeholder skipping (e.g. 0.7.x):
-            # restore the full abstract tree and keep only params. This
-            # pays the optimizer-moment materialization the placeholder
-            # path avoids — correct everywhere, memory-lean only on new
-            # orbax — instead of failing the whole predict/serve restore.
-            restored = self._mgr.restore(
-                step,
-                args=ocp.args.Composite(
-                    **{STATE_ITEM: ocp.args.StandardRestore(abstract)}
-                ),
-            )[STATE_ITEM]
-            return (
-                restored["params"]
-                if isinstance(restored, Mapping)
-                else restored.params
-            )
         masked = abstract._replace(
             **{
                 f: jax.tree.map(lambda _: ocp.PLACEHOLDER, getattr(abstract, f))
@@ -311,11 +293,12 @@ def maybe_warm_start(directory: str, template: Any) -> tuple[Any | None, int | N
                 )
                 restored = None
         if restored is not None and not _shapes_match(restored, template):
-            # Some orbax versions restore with the CHECKPOINT's shapes
-            # instead of raising when the template disagrees (e.g. the
-            # default vocab grew between runs); adopting those arrays
-            # would crash — or silently mistrain — far from here. Same
-            # degrade-to-fresh semantics as a restore error.
+            # A restore can come back with the CHECKPOINT's shapes
+            # instead of raising when the template disagrees (e.g. a
+            # manifest-less checkpoint whose vocab grew between runs);
+            # adopting those arrays would crash — or silently mistrain —
+            # far from here. Same degrade-to-fresh semantics as a
+            # restore error.
             from ..utils.logging import get_logger
 
             get_logger().warning(

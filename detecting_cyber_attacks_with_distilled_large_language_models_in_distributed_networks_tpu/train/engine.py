@@ -359,7 +359,7 @@ def _tag_gather(gather: Callable) -> Callable:
     return tagged
 
 
-def _fsdp_policy() -> Callable | None:
+def _fsdp_policy() -> Callable:
     """Remat policy for the FSDP loss region: save every forward
     intermediate EXCEPT the all-gathered weights — the checkpoint_name-
     tagged gather outputs AND the sharding-constraint outputs feeding
@@ -367,20 +367,14 @@ def _fsdp_policy() -> Callable | None:
     un-named constraint output is the same full-size array and the
     policy happily saves it, so the backward would retain the gathered
     weights anyway (verified against the saved-residual list; the
-    partial eval saves the nearest policy-saveable producer). None when
-    this jax build lacks named policies or moved the constraint
-    primitive — callers fall back to plain remat (memory still bounded,
-    at a forward replay's extra cost)."""
-    named = getattr(
-        jax.checkpoint_policies, "save_anything_except_these_names", None
+    partial eval saves the nearest policy-saveable producer). The
+    constraint primitive is a jax internal: a JAX that moves it fails
+    this import loudly rather than quietly retaining gathered weights."""
+    from jax._src.pjit import sharding_constraint_p
+
+    base = jax.checkpoint_policies.save_anything_except_these_names(
+        FSDP_GATHER_NAME
     )
-    if named is None:  # pragma: no cover - older jax fallback
-        return None
-    try:
-        from jax._src.pjit import sharding_constraint_p
-    except Exception:  # pragma: no cover - jax internals moved
-        return None
-    base = named(FSDP_GATHER_NAME)
 
     def policy(prim, *args, **params):
         if prim is sharding_constraint_p:
@@ -398,10 +392,7 @@ def fsdp_remat_loss(fn: Callable) -> Callable:
     forward replay). The remat must wrap the loss, not just the gather
     — a remat region's outputs consumed by un-rematted downstream code
     are always saved, which would defeat the policy."""
-    policy = _fsdp_policy()
-    if policy is None:  # pragma: no cover - older jax fallback
-        return jax.remat(fn)
-    return jax.remat(fn, policy=policy)
+    return jax.remat(fn, policy=_fsdp_policy())
 
 
 def make_fsdp_train_step(

@@ -23,8 +23,6 @@ from detecting_cyber_attacks_with_distilled_large_language_models_in_distributed
 from detecting_cyber_attacks_with_distilled_large_language_models_in_distributed_networks_tpu.parallel.ring_attention import (
     ring_attention_sharded,
 )
-from detecting_cyber_attacks_with_distilled_large_language_models_in_distributed_networks_tpu.parallel.mesh import shard_map
-
 
 
 def _qkv(rng, b=2, h=2, l=64, d=16, dtype=jnp.float32):
@@ -185,7 +183,7 @@ def test_ring_model_forward_matches_dot(rng, eight_devices):
     mask = jnp.asarray(mask_np)
 
     ref = model_dot.apply({"params": params}, ids, mask, True)
-    out = shard_map(
+    out = jax.shard_map(
         lambda p, i, m: model_ring.apply({"params": p}, i, m, True),
         mesh=mesh,
         in_specs=(P(), P(None, "seq"), P(None, "seq")),
@@ -221,7 +219,7 @@ def test_ring_sequence_parallel_training_matches_dot(rng, eight_devices):
     mask = jnp.asarray(mask_np)
     labels = jnp.asarray(rng.integers(0, 2, B), jnp.int32)
 
-    fwd_ring = shard_map(
+    fwd_ring = jax.shard_map(
         lambda p, i, m: model_ring.apply({"params": p}, i, m, True),
         mesh=mesh,
         in_specs=(P(), P(None, "seq"), P(None, "seq")),

@@ -11,8 +11,6 @@ from detecting_cyber_attacks_with_distilled_large_language_models_in_distributed
     hash_dropout,
     hash_keep_mask,
 )
-from detecting_cyber_attacks_with_distilled_large_language_models_in_distributed_networks_tpu.parallel.mesh import shard_map
-
 
 
 def _seed(i=0):
@@ -114,7 +112,7 @@ def test_model_seq_dropout_invariance_via_ring(eight_devices):
         mesh = Mesh(
             np.array(jax.devices()[:n_seq]).reshape(n_seq), ("seq",)
         )
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda i, m: model.apply(
                 {"params": params}, i, m, False, rngs={"dropout": key}
             ),

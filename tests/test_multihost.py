@@ -69,18 +69,11 @@ import sys, os
 sys.path.insert(0, {repo!r})
 pid = int(sys.argv[1]); port = sys.argv[2]; out = sys.argv[3]
 extra = sys.argv[4:]
-# Two local devices per process: XLA_FLAGS covers JAX versions without the
-# jax_num_cpu_devices option (it is read at backend init, which happens
-# after jax.distributed.initialize inside main()).
-os.environ["XLA_FLAGS"] = (
-    os.environ.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=2"
-).strip()
+# Two local devices per process (read at backend init, which happens after
+# jax.distributed.initialize inside main()).
 import jax
 jax.config.update("jax_platforms", "cpu")
-try:
-    jax.config.update("jax_num_cpu_devices", 2)
-except AttributeError:
-    pass
+jax.config.update("jax_num_cpu_devices", 2)
 from detecting_cyber_attacks_with_distilled_large_language_models_in_distributed_networks_tpu.cli import main
 rc = main([
     "federated",

@@ -247,13 +247,14 @@ def test_streamagg_batched_fold_one_crc_over_arrival_orders(rng):
 
 
 def test_fold_engine_env_override(monkeypatch):
-    monkeypatch.setenv("FEDTPU_FOLD_ENGINE", "gpu")
-    with pytest.raises(ValueError, match="FEDTPU_FOLD_ENGINE"):
-        fold._pick_engine()
+    for refused in ("gpu", "pallas"):
+        monkeypatch.setenv("FEDTPU_FOLD_ENGINE", refused)
+        with pytest.raises(ValueError, match="FEDTPU_FOLD_ENGINE"):
+            fold._pick_engine()
     monkeypatch.setenv("FEDTPU_FOLD_ENGINE", "naive")
     assert fold._pick_engine() == "naive"
     monkeypatch.delenv("FEDTPU_FOLD_ENGINE")
-    assert fold._pick_engine() in ("blocked", "pallas")
+    assert fold._pick_engine() == "blocked"
 
 
 # ------------------------------------------------- wire-dtype negotiation
