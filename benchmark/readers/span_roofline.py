@@ -1,0 +1,40 @@
+"""Reader ``span_roofline``: how close the device's own busy time inside a
+benchmark span comes to the roofline, in percent: the least time the chips
+could take for the work the span required (the larger of FLOPs over peak
+FLOP/s and bytes over peak bytes/s, benchmark/flops.py and peaks.json) over
+the device busy time inside that span of the traced window (xplane). Which
+roof binds is printed on an earlier line.
+
+The work is training steps: ``counter`` rows a span, and the span's ``steps``
+attribute counts the optimizer steps, each of which moves the state once.
+
+args: ``span``, ``counter``.
+"""
+
+from __future__ import annotations
+
+from .. import flops
+from ..reduce import xplane
+
+
+def read(ctx, *, span="fit", counter="rows"):
+    reduced = ctx.rec.data.get("xplane")
+    if reduced is None:
+        return None
+    busy = xplane.busy_inside(reduced, span)
+    rows = ctx.rec.total(span, counter, phase="traced")
+    if busy <= 0 or rows <= 0:
+        return None
+    peaks = ctx.peaks()
+    chips = len(reduced["chips"])
+    steps = ctx.rec.total(span, "steps", phase="traced")
+    need_f = flops.train_step_flops(ctx.model, rows)
+    need_b = flops.train_step_bytes(ctx.model) * steps
+    floor, roof = flops.roofline_floor_s(need_f, need_b, peaks, chips)
+    ctx.say(
+        f"roofline/{span}: {rows:.0f} rows in {steps:.0f} step(s) need "
+        f"{need_f / 1e12:.3f} TFLOP and at least {need_b / 1e9:.3f} GB: floor "
+        f"{floor:.4f} s on {chips} chip(s), set by the {roof} roof; device busy "
+        f"{busy:.4f} s"
+    )
+    return 100.0 * floor / busy
