@@ -4,7 +4,12 @@ Seven pieces (see each module's docstring):
 
 * :mod:`.trace` — round-scoped trace contexts with span ids propagated
   across the TCP wire protocols via an optional meta field; every
-  process appends spans to a unified events-JSONL.
+  process appends spans to a unified events-JSONL. Beside it, the
+  profiler-clock plane: ``annotate(name)`` marks the trainers' phases,
+  batches and launches as ``fedtpu:<name>`` events (vocabulary
+  ``ANNOTATIONS``) on the ``/host:CPU`` plane of a ``jax.profiler``
+  trace (``--profile-dir``, ``fedtpu obs profile --capture``), on the
+  clock of the device's operations; with no session it writes nothing.
 * :mod:`.metrics` — in-process counters/gauges/histograms exposed over a
   stdlib-HTTP ``/metrics`` endpoint in Prometheus text format, plus the
   machine-readable ``/metrics.json`` twin.
@@ -17,7 +22,9 @@ Seven pieces (see each module's docstring):
 * :mod:`.flight` — the failure flight recorder: bounded in-memory rings
   dumped as postmortem bundles on round failure / eject storm / SLO page.
 * :mod:`.profile` — the device performance plane: XLA compile ledger
-  (per-site compile/recompile accounting), strided fenced step-time
+  (per-site compile/recompile accounting; ``ledger.jit(site, fn)`` names
+  the program ``jit_<site>`` so a trace's modules and ops carry the
+  site, and every launch is a ``dispatch/<site>`` annotation), strided fenced step-time
   attribution, device-memory watermarks, and the analytic-vs-XLA FLOPs
   cross-check behind ``fedtpu obs profile`` / ``BENCH_MODE=profile``.
 * :mod:`.sentinel` — the sentinel watch daemon behind ``fedtpu obs
@@ -87,10 +94,13 @@ from .timeline import (  # noqa: F401
     timeline_table,
 )
 from .trace import (  # noqa: F401
+    ANNOTATIONS,
     SCHEMA,
     SPAN_NAMES,
     TRACE_META_KEY,
     Tracer,
+    annotate,
+    annotate_iter,
     get_global_tracer,
     get_run_id,
     maybe_span,

@@ -47,6 +47,7 @@ import time
 from typing import Any, Callable, Iterator, Mapping
 
 from .metrics import MetricsRegistry, default_registry
+from .trace import annotate
 
 #: XLA-vs-analytic FLOPs ratio bounds the bench pins (documented in the
 #: README "Device profiling" section). XLA's cost model counts the same
@@ -119,9 +120,12 @@ class CompileLedger:
     * ``fn = ledger.timed("tier.step", jax.jit(body))`` wraps the
       jitted callable so the wall seconds of any call during which a
       trace fired are attributed to that compile (trace+compile happen
-      inside the first dispatch). The wrapper costs two monotonic reads
-      and one plain int compare per call; it exposes the jitted
-      original as ``__wrapped__`` (``xla_cost_flops`` needs ``lower``).
+      inside the first dispatch). The wrapper costs two monotonic reads,
+      one plain int compare and one ``dispatch/<site>`` profiler
+      annotation per call; it exposes the jitted original as
+      ``__wrapped__`` (``xla_cost_flops`` needs ``lower``).
+      ``ledger.jit("tier.step", body, **jit_kwargs)`` is the pair in one
+      call, with the program named after the site.
 
     ``mark_warm()`` freezes the signature set: a NEW signature at a
     warm site afterwards is a *recompile* — counted, logged, listed in
@@ -261,10 +265,25 @@ class CompileLedger:
             recompile=True if recompile else None,
         )
 
+    def jit(self, site: str, fn: Callable, **jit_kwargs: Any) -> Callable:
+        """``jax.jit(fn, **jit_kwargs)`` as the program ``jit_<site>``
+        (``.`` as ``_``: ``fed.packed_step`` -> ``jit_fed_packed_step``),
+        wrapped by :meth:`timed`. ``fn`` is renamed in place (pass the
+        site's own closure), so a profile's ``XLA Modules`` line and every
+        op under it carry the ledger's site, whichever variant of the body
+        (plain, FedProx, FSDP) was built."""
+        import jax
+
+        fn.__name__ = fn.__qualname__ = str(site).replace(".", "_")
+        return self.timed(site, jax.jit(fn, **jit_kwargs))
+
     def timed(self, site: str, fn: Callable) -> Callable:
         """Wrap a jitted callable: wall seconds of any call during which
-        ``site`` traced are attributed as that compile's trace time."""
+        ``site`` traced are attributed as that compile's trace time. Every
+        call is also the profiler annotation ``dispatch/<site>``
+        (obs/trace.py ``annotate``): one program launch, host side."""
         name = str(site)
+        label = f"dispatch/{name}"
         with self._lock:
             self._site(name).timed = True
 
@@ -276,7 +295,8 @@ class CompileLedger:
             s.inflight += 1
             t0 = time.monotonic()
             try:
-                out = fn(*args, **kwargs)
+                with annotate(label):
+                    out = fn(*args, **kwargs)
             finally:
                 s.inflight -= 1
             if s.gen != gen0:  # a trace fired during this call

@@ -91,6 +91,39 @@ Span vocabulary (names are the contract the timeline tool groups by)::
 Timestamps are wall-clock unix seconds (``ts``) with a separately
 measured monotonic duration (``dur_s``): cross-process correlation needs
 a shared clock, phase arithmetic needs one that never steps backwards.
+
+Two planes
+----------
+The spans above are the OPERATOR's plane: a cross-process round timeline
+on the wall clock, one JSONL record per span, read by ``fedtpu obs
+timeline``. They cannot say what the host was doing while the DEVICE
+idled: the device's operations are timed on the profiler's clock, inside
+one process. :func:`annotate` is the second plane, for exactly that: a
+``jax.profiler.TraceAnnotation`` named ``fedtpu:<name>`` and nothing
+else — no clock read, no record, no lock. With no profiler session it is
+one Python ``with``; under a session (``utils/profiling.trace``,
+``--profile-dir``, ``fedtpu obs profile --capture``, the benchmark's
+``--trace 1``) it lands on the ``/host:CPU`` plane of the ``.xplane.pb``,
+on the clock of the device's operations. :data:`ANNOTATIONS` is its
+vocabulary (the profiler-clock twin of :data:`SPAN_NAMES`)::
+
+    fit             one whole local fit (FederatedTrainer.fit_local,
+                    Trainer._fit_loop); the client-local span's twin
+    fit/unstack     packed fit: stacked state -> per-client buffers
+                    (split, delete of the stacked leaves, copies)
+    fit/next_batch  one lockstep step's host data work: the next batch
+                    off the epoch iterator, its feed / per-client slices
+    fit/loss_read   the epoch's loss mean read back: the one point where
+                    the host waits for the device
+    fit/restack     packed fit: per-client buffers -> stacked state
+    dispatch/<site> one program launch, host side, named by its
+                    CompileLedger site (obs/profile.py ``timed``)
+    eval            one whole evaluation sweep (evaluate_stacked,
+                    Trainer.evaluate)
+    eval/read       the host read of the accumulated counts
+    agg             the round's aggregation (the agg span's twin)
+    reset           the per-round optimizer re-init
+    round_anchor    the round-start parameter copy (DP / FedOpt)
 """
 
 from __future__ import annotations
@@ -100,9 +133,11 @@ import os
 import threading
 import time
 from contextlib import contextmanager
-from typing import Any, Iterator
+from typing import Any, Iterable, Iterator, TypeVar
 
 from .flight import get_global_recorder
+
+T = TypeVar("T")
 
 #: Every span record carries this so stream consumers can reject (or
 #: version-switch on) foreign JSONL lines when files get concatenated.
@@ -136,6 +171,23 @@ SPAN_NAMES = (
     "canary-probe",
     "sentinel-eval",
     "regression-fire",
+)
+
+#: The annotation vocabulary of the profiler-clock plane (see "Two
+#: planes" above). ``dispatch/`` is a prefix: the CompileLedger's site
+#: follows it (``dispatch/fed.packed_step``).
+ANNOTATIONS = (
+    "fit",
+    "fit/unstack",
+    "fit/next_batch",
+    "fit/loss_read",
+    "fit/restack",
+    "dispatch/",
+    "eval",
+    "eval/read",
+    "agg",
+    "reset",
+    "round_anchor",
 )
 
 #: Wire meta key the trace id rides under (comm/server.py reply meta,
@@ -294,6 +346,30 @@ def maybe_span(
     else:
         with tracer.span(name, **kw) as info:
             yield info
+
+
+def annotate(name: str):
+    """``with annotate("fit/unstack"):`` — the block as a
+    ``jax.profiler.TraceAnnotation`` named ``fedtpu:<name>``, and nothing
+    else (module docstring, "Two planes"). jax is imported here, at first
+    use: the aggregation tiers import this module and stay free of it."""
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation(f"fedtpu:{name}")
+
+
+def annotate_iter(name: str, iterable: Iterable[T]) -> Iterator[T]:
+    """``iterable`` with every ``next()`` under :func:`annotate`: what a
+    generator-driven loop spends producing each item, the end of the
+    iteration included."""
+    it = iter(iterable)
+    while True:
+        with annotate(name):
+            try:
+                item = next(it)
+            except StopIteration:
+                return
+        yield item
 
 
 _GLOBAL_LOCK = threading.Lock()

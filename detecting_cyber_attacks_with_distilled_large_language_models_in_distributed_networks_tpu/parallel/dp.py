@@ -132,7 +132,7 @@ def make_dp_fedavg_step(
         in_shardings=(shardings.client, shardings.client, None, None),
         out_shardings=(shardings.client, None),
     )
-    def step(stacked_params, anchor, key, mask):
+    def dp_fedavg_step(stacked_params, anchor, key, mask):
         return dp_fedavg(
             stacked_params,
             anchor,
@@ -142,7 +142,7 @@ def make_dp_fedavg_step(
             noise_multiplier=noise_multiplier,
         )
 
-    return step
+    return dp_fedavg_step
 
 
 DEFAULT_RDP_ORDERS: tuple[float, ...] = tuple(

@@ -122,7 +122,7 @@ def make_fedavg_step(shardings: FedShardings) -> Callable:
         out_shardings=shardings.client,
         static_argnums=(),
     )
-    def step(stacked_params, weights, mask):
+    def fedavg_step(stacked_params, weights, mask):
         return fedavg(stacked_params, weights, mask)
 
-    return step
+    return fedavg_step

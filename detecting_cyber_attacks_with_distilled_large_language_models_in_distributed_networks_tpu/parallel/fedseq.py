@@ -413,23 +413,17 @@ def build_fedseq_steps(cfg, model, optimizer, mesh: Mesh) -> FedSeqSteps:
             losses,
         )
 
-    if mu > 0.0:
-        # FedProx signature: (state, batch, anchor) — the same contract
-        # FederatedTrainer.fit_local drives on the dense path.
-        train_step = partial(
-            jax.jit,
-            donate_argnums=(0,),
-            in_shardings=(state_sh, batch_sh, csh),
-            out_shardings=(state_sh, csh),
-        )(_train_body)
-    else:
-        train_step = partial(
-            jax.jit,
-            donate_argnums=(0,),
-            in_shardings=(state_sh, batch_sh),
-            out_shardings=(state_sh, csh),
-        )(lambda state, batch: _train_body(state, batch, None))
-    train_step = ledger.timed("fedseq.train_step", train_step)
+    # FedProx signature: (state, batch, anchor) — the same contract
+    # FederatedTrainer.fit_local drives on the dense path.
+    train_step = ledger.jit(
+        "fedseq.train_step",
+        _train_body
+        if mu > 0.0
+        else lambda state, batch: _train_body(state, batch, None),
+        donate_argnums=(0,),
+        in_shardings=(state_sh, batch_sh) + ((csh,) if mu > 0.0 else ()),
+        out_shardings=(state_sh, csh),
+    )
 
     ragged_batch_sh = dict(batch_sh, valid=row_sh, warmup_step=row_sh)
     masked_loss = make_fedseq_masked_loss(

@@ -12,7 +12,7 @@ matrix finalize on host from eight scalars.
 from __future__ import annotations
 
 from dataclasses import replace
-from functools import lru_cache, partial
+from functools import lru_cache
 from typing import Any, Callable, Iterator, NamedTuple
 
 import jax
@@ -29,6 +29,7 @@ from ..obs.profile import (
     note_memory,
     profiled_step_iter,
 )
+from ..obs.trace import annotate, annotate_iter
 from ..ops.metrics import (
     BinaryCounts,
     ClassCounts,
@@ -282,7 +283,6 @@ def make_train_step(
     if prox_mu > 0.0:
         mu = float(prox_mu)
 
-        @partial(jax.jit, donate_argnums=(0,))
         def train_step_prox(
             state: TrainState, batch, anchor
         ) -> tuple[TrainState, jnp.ndarray]:
@@ -299,9 +299,8 @@ def make_train_step(
             )
             return _apply_grads(state, loss, grads)
 
-        return ledger.timed(site, train_step_prox)
+        return ledger.jit(site, train_step_prox, donate_argnums=(0,))
 
-    @partial(jax.jit, donate_argnums=(0,))
     def train_step(state: TrainState, batch) -> tuple[TrainState, jnp.ndarray]:
         # Compile-ledger trace hook (obs/profile.py): this body runs once
         # per traced shape, so the note IS a compile event, never a call.
@@ -312,7 +311,7 @@ def make_train_step(
         )
         return _apply_grads(state, loss, grads)
 
-    return ledger.timed(site, train_step)
+    return ledger.jit(site, train_step, donate_argnums=(0,))
 
 
 def make_eval_step(
@@ -328,14 +327,13 @@ def make_eval_step(
     ledger = default_ledger()
     note_compile = ledger.hook(site)
 
-    @jax.jit
     def eval_step(params, batch, valid) -> tuple[BinaryCounts, jnp.ndarray]:
         note_compile(tuple(batch["input_ids"].shape))
         if gather is not None:
             params = gather(params)
         return eval_counts(model, params, batch, valid)
 
-    return ledger.timed(site, eval_step)
+    return ledger.jit(site, eval_step)
 
 
 # ----------------------------------------------------- FSDP (sharded) steps
@@ -717,36 +715,42 @@ class Trainer:
         prof = self._armed_profiler()
         first_memory = prof is not None
         last_loss = None  # carried ACROSS epochs: the drain fence target
-        for epoch in range(epoch_offset, epoch_offset + epochs):
-            # Collect device scalars and sync once per epoch — float(loss)
-            # per step would block async dispatch and stall the TPU.
-            losses: list[jnp.ndarray] = []
-            for batch, sampled in profiled_step_iter(
-                prof, self.epoch_batches(split, epoch, batch_size)
-            ):
-                if sampled:
-                    # Fenced sampled step: drain the async backlog so
-                    # the measurement is this step's own device work,
-                    # then split dispatch from device-execute.
-                    prof.drain(last_loss)
-                    t0 = prof.clock()
-                    state, loss = step_fn(state, batch)
-                    prof.note_dispatch(prof.clock() - t0)
-                    prof.fence(loss)
-                else:
-                    state, loss = step_fn(state, batch)
-                losses.append(loss)
-                last_loss = loss
-                telemetry(loss, batch_size)
-                if first_memory:
-                    first_memory = False
-                    note_memory("post-first-step")
-            avg = float(jnp.stack(losses).mean()) if losses else 0.0
-            epoch_losses.append(avg)
-            log.info(
-                f"{tag}Epoch [{epoch - epoch_offset + 1}/{epochs}], "
-                f"{loss_label}: {avg:.4f}"
-            )
+        with annotate("fit"):
+            for epoch in range(epoch_offset, epoch_offset + epochs):
+                # Collect device scalars and sync once per epoch — float(loss)
+                # per step would block async dispatch and stall the TPU.
+                losses: list[jnp.ndarray] = []
+                for batch, sampled in profiled_step_iter(
+                    prof,
+                    annotate_iter(
+                        "fit/next_batch",
+                        self.epoch_batches(split, epoch, batch_size),
+                    ),
+                ):
+                    if sampled:
+                        # Fenced sampled step: drain the async backlog so
+                        # the measurement is this step's own device work,
+                        # then split dispatch from device-execute.
+                        prof.drain(last_loss)
+                        t0 = prof.clock()
+                        state, loss = step_fn(state, batch)
+                        prof.note_dispatch(prof.clock() - t0)
+                        prof.fence(loss)
+                    else:
+                        state, loss = step_fn(state, batch)
+                    losses.append(loss)
+                    last_loss = loss
+                    telemetry(loss, batch_size)
+                    if first_memory:
+                        first_memory = False
+                        note_memory("post-first-step")
+                with annotate("fit/loss_read"):
+                    avg = float(jnp.stack(losses).mean()) if losses else 0.0
+                epoch_losses.append(avg)
+                log.info(
+                    f"{tag}Epoch [{epoch - epoch_offset + 1}/{epochs}], "
+                    f"{loss_label}: {avg:.4f}"
+                )
         return state, epoch_losses
 
     def evaluate(
@@ -760,39 +764,41 @@ class Trainer:
         """Five reference metrics + confusion matrix (+ labels/probs for
         ROC & PR curves, the reference's evaluate_model return shape,
         client1.py:150)."""
-        padded, valid = pad_split_to_batch(split, batch_size, pad_id=self.pad_id)
-        # None-init: the first batch's counts type (BinaryCounts for K=2,
-        # ClassCounts for K>2) decides the accumulator — eval_counts'
-        # static branch keeps the binary path bit-identical.
-        totals: BinaryCounts | ClassCounts | None = None
-        # Device arrays accumulate; host conversion happens once after the
-        # loop so eval pipelines like fit() does.
-        probs_dev: list[jnp.ndarray] = []
-        valid_slices: list[np.ndarray] = []
-        for start in range(0, len(padded), batch_size):
-            sl = slice(start, start + batch_size)
-            batch = {
-                "input_ids": padded.input_ids[sl],
-                "attention_mask": padded.attention_mask[sl],
-                "labels": padded.labels[sl],
-            }
-            counts, probs = self.eval_step(batch=batch, params=params, valid=valid[sl])
-            totals = counts if totals is None else totals + counts
+        with annotate("eval"):
+            padded, valid = pad_split_to_batch(split, batch_size, pad_id=self.pad_id)
+            # None-init: the first batch's counts type (BinaryCounts for K=2,
+            # ClassCounts for K>2) decides the accumulator — eval_counts'
+            # static branch keeps the binary path bit-identical.
+            totals: BinaryCounts | ClassCounts | None = None
+            # Device arrays accumulate; host conversion happens once after the
+            # loop so eval pipelines like fit() does.
+            probs_dev: list[jnp.ndarray] = []
+            valid_slices: list[np.ndarray] = []
+            for start in range(0, len(padded), batch_size):
+                sl = slice(start, start + batch_size)
+                batch = {
+                    "input_ids": padded.input_ids[sl],
+                    "attention_mask": padded.attention_mask[sl],
+                    "labels": padded.labels[sl],
+                }
+                counts, probs = self.eval_step(batch=batch, params=params, valid=valid[sl])
+                totals = counts if totals is None else totals + counts
+                if collect_probs:
+                    probs_dev.append(probs)
+                    valid_slices.append(valid[sl])
+            if totals is None:
+                totals = BinaryCounts.zero()
+            with annotate("eval/read"):
+                metrics = (
+                    finalize_class_metrics(totals)
+                    if isinstance(totals, ClassCounts)
+                    else finalize_metrics(totals)
+                )
             if collect_probs:
-                probs_dev.append(probs)
-                valid_slices.append(valid[sl])
-        if totals is None:
-            totals = BinaryCounts.zero()
-        metrics = (
-            finalize_class_metrics(totals)
-            if isinstance(totals, ClassCounts)
-            else finalize_metrics(totals)
-        )
-        if collect_probs:
-            if probs_dev:
-                all_probs = np.asarray(jnp.concatenate(probs_dev))
-                metrics["probs"] = all_probs[np.concatenate(valid_slices) == 1]
-            else:
-                metrics["probs"] = np.array([])
-            metrics["labels"] = split.labels.copy()
+                if probs_dev:
+                    all_probs = np.asarray(jnp.concatenate(probs_dev))
+                    metrics["probs"] = all_probs[np.concatenate(valid_slices) == 1]
+                else:
+                    metrics["probs"] = np.array([])
+                metrics["labels"] = split.labels.copy()
         return metrics

@@ -35,6 +35,7 @@ from ..config import ExperimentConfig
 from ..data.pipeline import StackedClients, TokenizedSplit
 from ..models.distilbert import DDoSClassifier, init_params
 from ..obs.profile import maybe_step_profiler, note_memory, profiled_step_iter
+from ..obs.trace import annotate, annotate_iter
 from ..parallel.fedavg import stack_params
 from ..parallel.mesh import FedShardings, make_mesh
 from ..train.engine import make_optimizer
@@ -249,7 +250,8 @@ class FederatedTrainer:
         )
 
     def reset_optimizer(self, state: FedState) -> FedState:
-        return state._replace(opt_state=self._opt_init(state.params))
+        with annotate("reset"):
+            return state._replace(opt_state=self._opt_init(state.params))
 
     def personalize(
         self,
@@ -398,13 +400,14 @@ class FederatedTrainer:
         prof = self._armed_profiler()
         t_unix = time.time()
         t0 = time.monotonic()
-        out = self._fit_local_impl(
-            state,
-            stacked_train,
-            batch_size=batch_size,
-            epochs=epochs,
-            epoch_offset=epoch_offset,
-        )
+        with annotate("fit"):
+            out = self._fit_local_impl(
+                state,
+                stacked_train,
+                batch_size=batch_size,
+                epochs=epochs,
+                epoch_offset=epoch_offset,
+            )
         self._trace_phase(
             "client-local",
             t_unix,
@@ -475,19 +478,22 @@ class FederatedTrainer:
         for epoch in range(epoch_offset, epoch_offset + E):
             losses = []
             batches = self._epoch_batches(stacked_train, bs, epoch)
-            for batch, sampled in profiled_step_iter(
-                prof, (b for _, b in zip(range(n_batches), batches))
+            fed = (
+                (b, self._feed(b)) for _, b in zip(range(n_batches), batches)
+            )
+            for (batch, feed), sampled in profiled_step_iter(
+                prof, annotate_iter("fit/next_batch", fed)
             ):
                 if sampled:
                     # Fenced sampled step (obs/profile.py): drain the
                     # async backlog, then split dispatch from device.
                     prof.drain(last_loss)
                     t_d = prof.clock()
-                    state, loss = step(state, self._feed(batch))
+                    state, loss = step(state, feed)
                     prof.note_dispatch(prof.clock() - t_d)
                     prof.fence(loss)
                 else:
-                    state, loss = step(state, self._feed(batch))
+                    state, loss = step(state, feed)
                 losses.append(loss)
                 last_loss = loss
                 telemetry(loss, batch["labels"].size)
@@ -495,7 +501,8 @@ class FederatedTrainer:
                     first_memory = False
                     note_memory("post-first-step")
             epoch_avg = jnp.stack(losses).mean(axis=0) if losses else jnp.zeros(self.C)
-            out.append(self._host(epoch_avg))
+            with annotate("fit/loss_read"):
+                out.append(self._host(epoch_avg))
             for c in range(self.C):
                 log.info(
                     f"Client {c} Epoch [{epoch - epoch_offset + 1}/{E}], "
@@ -508,10 +515,11 @@ class FederatedTrainer:
         """Jitted per-client tree slicer (memoized on the trainer)."""
         fn = getattr(self, "_slice_client_fn", None)
         if fn is None:
-            fn = jax.jit(
-                lambda t, c: jax.tree.map(lambda x: x[c], t),
-                static_argnums=1,
-            )
+
+            def slice_client(tree, c):
+                return jax.tree.map(lambda x: x[c], tree)
+
+            fn = jax.jit(slice_client, static_argnums=1)
             self._slice_client_fn = fn
         return fn
 
@@ -532,7 +540,7 @@ class FederatedTrainer:
         if fn is None:
             C = self.C
 
-            def unstack(params, opt_state):
+            def unstack_clients(params, opt_state):
                 return (
                     [jax.tree.map(lambda x: x[c], params) for c in range(C)],
                     [
@@ -541,7 +549,7 @@ class FederatedTrainer:
                     ],
                 )
 
-            fn = jax.jit(unstack)
+            fn = jax.jit(unstack_clients)
             self._unstack_fn_cache = fn
         return fn
 
@@ -552,10 +560,11 @@ class FederatedTrainer:
         round)."""
         fn = getattr(self, "_restack_fn_cache", None)
         if fn is None:
-            fn = jax.jit(
-                lambda *ts: jax.tree.map(lambda *xs: jnp.stack(xs), *ts),
-                out_shardings=self.sh.client,
-            )
+
+            def restack_clients(*trees):
+                return jax.tree.map(lambda *xs: jnp.stack(xs), *trees)
+
+            fn = jax.jit(restack_clients, out_shardings=self.sh.client)
             self._restack_fn_cache = fn
         return fn
 
@@ -620,10 +629,13 @@ class FederatedTrainer:
         slice_c = self._slice_client
         # FedProx anchors: fresh round-start slices, taken BEFORE the
         # unstack below donates (consumes) the stacked params.
-        anchors = (
-            [slice_c(state.params, c) for c in range(C)] if mu > 0.0 else None
-        )
-        cstates = self._unstack_cstates(state)
+        with annotate("fit/unstack"):
+            anchors = (
+                [slice_c(state.params, c) for c in range(C)]
+                if mu > 0.0
+                else None
+            )
+            cstates = self._unstack_cstates(state)
         out = []
         telemetry = self._step_telemetry()
         prof = self.step_profiler  # armed + window-reset by fit_local
@@ -632,8 +644,12 @@ class FederatedTrainer:
         for epoch in range(epoch_offset, epoch_offset + E):
             losses = []
             batches = self._epoch_batches(stacked_train, bs, epoch)
-            for batch, sampled in profiled_step_iter(
-                prof, (b for _, b in zip(range(n_batches), batches))
+            sliced = (
+                (b, [{k: v[c] for k, v in b.items()} for c in range(C)])
+                for _, b in zip(range(n_batches), batches)
+            )
+            for (batch, per_client), sampled in profiled_step_iter(
+                prof, annotate_iter("fit/next_batch", sliced)
             ):
                 # A "step" here is one full lockstep batch: C per-client
                 # dispatches. A sampled one fences the previous batch's
@@ -642,8 +658,7 @@ class FederatedTrainer:
                     prof.drain(last_loss)
                     t_d = prof.clock()
                 per = []
-                for c in range(C):
-                    cb = {k: v[c] for k, v in batch.items()}
+                for c, cb in enumerate(per_client):
                     if anchors is not None:
                         cstates[c], task = step_fn(
                             cstates[c], cb, anchors[c]
@@ -664,18 +679,20 @@ class FederatedTrainer:
             epoch_avg = (
                 jnp.stack(losses).mean(axis=0) if losses else jnp.zeros(C)
             )
-            out.append(self._host(epoch_avg))
+            with annotate("fit/loss_read"):
+                out.append(self._host(epoch_avg))
             for c in range(C):
                 log.info(
                     f"Client {c} Epoch [{epoch - epoch_offset + 1}/{E}], "
                     f"Average Loss: {out[-1][c]:.4f}"
                 )
         restack = self._restack_fn
-        state = state._replace(
-            params=restack(*[cs[0] for cs in cstates]),
-            opt_state=restack(*[cs[1] for cs in cstates]),
-            step=cstates[0][2],
-        )
+        with annotate("fit/restack"):
+            state = state._replace(
+                params=restack(*[cs[0] for cs in cstates]),
+                opt_state=restack(*[cs[1] for cs in cstates]),
+                step=cstates[0][2],
+            )
         return state, np.stack(out) if out else np.zeros((0, C))
 
     def _fit_local_ragged(
@@ -724,8 +741,9 @@ class FederatedTrainer:
                 client_offset=self.client_offset,
                 n_batches=n_batches,
             )
-            for batch in batches:
-                state, (loss, has) = step(state, self._feed(batch))
+            fed = ((b, self._feed(b)) for b in batches)
+            for batch, feed in annotate_iter("fit/next_batch", fed):
+                state, (loss, has) = step(state, feed)
                 losses.append(loss)
                 had.append(has)
                 # Mean over ACTIVE clients only — idle clients' masked loss
@@ -736,7 +754,8 @@ class FederatedTrainer:
             total = jnp.stack(losses).sum(axis=0)
             count = jnp.stack(had).sum(axis=0)
             epoch_avg = total / jnp.maximum(count, 1.0)
-            out.append(self._host(epoch_avg))
+            with annotate("fit/loss_read"):
+                out.append(self._host(epoch_avg))
             for c in range(self.C):
                 log.info(
                     f"Client {c} Epoch [{epoch - epoch_offset + 1}/{E}], "
@@ -925,14 +944,15 @@ class FederatedTrainer:
             return state
         t_unix = time.time()
         t0 = time.monotonic()
-        state = self.aggregate(
-            state,
-            weights=weights,
-            client_mask=mask,
-            anchor=anchor,
-            round_index=round_index,
-            enforce_min_fraction=not poisson,
-        )
+        with annotate("agg"):
+            state = self.aggregate(
+                state,
+                weights=weights,
+                client_mask=mask,
+                anchor=anchor,
+                round_index=round_index,
+                enforce_min_fraction=not poisson,
+            )
         self._trace_phase("agg", t_unix, time.monotonic() - t0, round_index)
         # Memory watermark at the round's aggregation boundary
         # (obs/profile.py — graceful no-op on stats-less backends).
@@ -945,7 +965,8 @@ class FederatedTrainer:
         never alias it). None when neither needs it."""
         if self.dp_fedavg_step is None and self.server_agg_step is None:
             return None
-        return jax.tree.map(jnp.copy, state.params)
+        with annotate("round_anchor"):
+            return jax.tree.map(jnp.copy, state.params)
 
     def _dp_key(self, round_index: int) -> jax.Array:
         """Per-round noise key from the run's private DP seed (fresh OS

@@ -21,6 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..data.pipeline import TokenizedSplit, pad_split_to_batch
+from ..obs.trace import annotate
 from ..ops.metrics import (
     BinaryCounts,
     ClassCounts,
@@ -85,71 +86,75 @@ def evaluate_stacked(
 ) -> list[dict]:
     """Per-client metrics dicts (reference five-metric schema) from one
     sweep of the trainer's jitted eval step over a prepared stack."""
-    stacked, valid, bs = prepared.stacked, prepared.valid, prepared.batch_size
-    C = trainer.C
-    M = stacked.labels.shape[1]
-    # Accumulate the stacked [C] counts on device; one host sync after
-    # the loop (per-batch np.asarray would block async dispatch). The
-    # counts type follows the head width (BinaryCounts for K=2,
-    # ClassCounts for K>2 — eval_counts' static branch).
-    totals: BinaryCounts | ClassCounts | None = None
-    probs_dev = []
-    for i in range(M // bs):
-        sl = slice(i * bs, (i + 1) * bs)
-        fed = trainer._feed(
-            {
-                "input_ids": stacked.input_ids[:, sl],
-                "attention_mask": stacked.attention_mask[:, sl],
-                "labels": stacked.labels[:, sl],
-                "valid": valid[:, sl],
-            }
-        )
-        batch = {k: fed[k] for k in ("input_ids", "attention_mask", "labels")}
-        counts, probs = trainer.eval_step(stacked_params, batch, fed["valid"])
-        totals = counts if totals is None else totals + counts
-        if collect_probs:
-            probs_dev.append(probs)
-    host = (
-        trainer._host(totals)
-        if totals is not None
-        else BinaryCounts(*(np.zeros(C, np.float32) for _ in BinaryCounts._fields))
-    )
-    out = []
-    all_probs = None
-    labels_g, valid_g = stacked.labels, valid
-    if probs_dev:
-        # Probs accumulate as GLOBAL [C, bs] device arrays (the eval
-        # step's output sharding); _host replicates across processes
-        # first, so every host sees every client's probabilities.
-        all_probs = np.asarray(
-            trainer._host(jnp.concatenate(probs_dev, axis=1))
-        )
-        if trainer.P > 1:
-            # The host-side labels/validity cover only LOCAL clients;
-            # gather them process-major (the global client order).
-            from jax.experimental import multihost_utils
+    with annotate("eval"):
+        stacked, valid, bs = prepared.stacked, prepared.valid, prepared.batch_size
+        C = trainer.C
+        M = stacked.labels.shape[1]
+        # Accumulate the stacked [C] counts on device; one host sync after
+        # the loop (per-batch np.asarray would block async dispatch). The
+        # counts type follows the head width (BinaryCounts for K=2,
+        # ClassCounts for K>2 — eval_counts' static branch).
+        totals: BinaryCounts | ClassCounts | None = None
+        probs_dev = []
+        for i in range(M // bs):
+            sl = slice(i * bs, (i + 1) * bs)
+            fed = trainer._feed(
+                {
+                    "input_ids": stacked.input_ids[:, sl],
+                    "attention_mask": stacked.attention_mask[:, sl],
+                    "labels": stacked.labels[:, sl],
+                    "valid": valid[:, sl],
+                }
+            )
+            batch = {k: fed[k] for k in ("input_ids", "attention_mask", "labels")}
+            counts, probs = trainer.eval_step(stacked_params, batch, fed["valid"])
+            totals = counts if totals is None else totals + counts
+            if collect_probs:
+                probs_dev.append(probs)
+        with annotate("eval/read"):
+            host = (
+                trainer._host(totals)
+                if totals is not None
+                else BinaryCounts(
+                    *(np.zeros(C, np.float32) for _ in BinaryCounts._fields)
+                )
+            )
+        out = []
+        all_probs = None
+        labels_g, valid_g = stacked.labels, valid
+        if probs_dev:
+            # Probs accumulate as GLOBAL [C, bs] device arrays (the eval
+            # step's output sharding); _host replicates across processes
+            # first, so every host sees every client's probabilities.
+            all_probs = np.asarray(
+                trainer._host(jnp.concatenate(probs_dev, axis=1))
+            )
+            if trainer.P > 1:
+                # The host-side labels/validity cover only LOCAL clients;
+                # gather them process-major (the global client order).
+                from jax.experimental import multihost_utils
 
-            M_pad = stacked.labels.shape[1]
-            labels_g = np.asarray(
-                multihost_utils.process_allgather(stacked.labels)
-            ).reshape(-1, M_pad)
-            valid_g = np.asarray(
-                multihost_utils.process_allgather(valid)
-            ).reshape(-1, M_pad)
-    for c in range(C):
-        client_counts = type(host)(*(v[c] for v in host))
-        m = (
-            finalize_class_metrics(client_counts)
-            if isinstance(client_counts, ClassCounts)
-            else finalize_metrics(client_counts)
-        )
-        if collect_probs and all_probs is not None:
-            # Padding appends rows, so the valid-row subsequence IS the
-            # original split order (pad_split_to_batch/stack_eval_splits).
-            mask_c = valid_g[c, : all_probs.shape[1]] == 1
-            m["probs"] = all_probs[c][mask_c]
-            m["labels"] = labels_g[c][mask_c]
-        out.append(m)
+                M_pad = stacked.labels.shape[1]
+                labels_g = np.asarray(
+                    multihost_utils.process_allgather(stacked.labels)
+                ).reshape(-1, M_pad)
+                valid_g = np.asarray(
+                    multihost_utils.process_allgather(valid)
+                ).reshape(-1, M_pad)
+        for c in range(C):
+            client_counts = type(host)(*(v[c] for v in host))
+            m = (
+                finalize_class_metrics(client_counts)
+                if isinstance(client_counts, ClassCounts)
+                else finalize_metrics(client_counts)
+            )
+            if collect_probs and all_probs is not None:
+                # Padding appends rows, so the valid-row subsequence IS the
+                # original split order (pad_split_to_batch/stack_eval_splits).
+                mask_c = valid_g[c, : all_probs.shape[1]] == 1
+                m["probs"] = all_probs[c][mask_c]
+                m["labels"] = labels_g[c][mask_c]
+            out.append(m)
     return out
 
 

@@ -36,6 +36,16 @@ from ..utils.logging import get_logger
 log = get_logger()
 
 
+def _signature(body: Callable, mu: float) -> Callable:
+    """The step's call signature from its ``(state, batch, anchor)`` body.
+    FedProx (``mu > 0``): the body itself — the anchor is the round-start
+    params, a separate buffer, NOT the donated state. Plain FedAvg:
+    ``(state, batch)``, no anchor transfer."""
+    if mu > 0.0:
+        return body
+    return lambda state, batch: body(state, batch, None)
+
+
 def make_packed_step(
     objective,
     optimizer,
@@ -62,7 +72,8 @@ def make_packed_step(
     grads and pins the updated params/opt leaves back onto their
     shards). None/None (the default) is the literal replicated step."""
 
-    note_compile = default_ledger().hook("fed.packed_step")
+    ledger = default_ledger()
+    note_compile = ledger.hook("fed.packed_step")
     if gather is not None:
         from .engine import _tag_gather, fsdp_remat_loss
 
@@ -92,14 +103,9 @@ def make_packed_step(
             new_opt = constrain(new_opt)
         return ((new_params, new_opt, step + 1, rng), task)
 
-    if mu > 0.0:
-        jitted = jax.jit(body, donate_argnums=(0,))
-    else:
-        jitted = jax.jit(
-            lambda cstate, batch: body(cstate, batch, None),
-            donate_argnums=(0,),
-        )
-    return default_ledger().timed("fed.packed_step", jitted)
+    return ledger.jit(
+        "fed.packed_step", _signature(body, mu), donate_argnums=(0,)
+    )
 
 
 class FedState(NamedTuple):
@@ -248,38 +254,27 @@ def build_federated_steps(
             losses,  # [C]
         )
 
+    # The FedProx anchor is one more clients-sharded argument.
+    anchor_sh = (csh,) if mu > 0.0 else ()
+
     if gather is not None:
         # No explicit in/out shardings: the constrain calls pin the FSDP
         # layout inside the program and inputs carry the caller's
         # placements — an out_shardings of ``csh`` here would force a
         # full re-gather at every step boundary.
-        body = _fsdp_step_body
-        if mu > 0.0:
-            train_step = jax.jit(body, donate_argnums=(0,))
-        else:
-            train_step = jax.jit(
-                lambda state, batch: body(state, batch, None),
-                donate_argnums=(0,),
-            )
-    elif mu > 0.0:
-        # FedProx signature: (state, batch, anchor). The anchor is the
-        # stacked round-start params — a separate buffer, NOT the
-        # donated state.params.
-        train_step = partial(
-            jax.jit,
+        train_step = ledger.jit(
+            "fed.train_step",
+            _signature(_fsdp_step_body, mu),
             donate_argnums=(0,),
-            in_shardings=(state_sh, batch_sh, csh),
-            out_shardings=(state_sh, csh),
-        )(_step_body)
+        )
     else:
-        # Plain FedAvg signature: (state, batch) — no anchor transfer.
-        train_step = partial(
-            jax.jit,
+        train_step = ledger.jit(
+            "fed.train_step",
+            _signature(_step_body, mu),
             donate_argnums=(0,),
-            in_shardings=(state_sh, batch_sh),
+            in_shardings=(state_sh, batch_sh, *anchor_sh),
             out_shardings=(state_sh, csh),
-        )(lambda state, batch: _step_body(state, batch, None))
-    train_step = ledger.timed("fed.train_step", train_step)
+        )
 
     def per_client_step_masked(params, opt_state, batch, rng, anchor):
         """Row-masked variant for the ragged stacked path: the loss
@@ -417,48 +412,30 @@ def build_federated_steps(
         the extra compilation); memoized so same-config trainers share the
         compiled executable."""
         if gather is not None:
-            body = _fsdp_ragged_body
-            if mu > 0.0:
-                jitted = jax.jit(body, donate_argnums=(0,))
-            else:
-                jitted = jax.jit(
-                    lambda state, batch: body(state, batch, None),
-                    donate_argnums=(0,),
-                )
-            return ledger.timed("fed.ragged_step", jitted)
-        if mu > 0.0:
-            jitted = partial(
-                jax.jit,
+            return ledger.jit(
+                "fed.ragged_step",
+                _signature(_fsdp_ragged_body, mu),
                 donate_argnums=(0,),
-                in_shardings=(state_sh, ragged_batch_sh, csh),
-                out_shardings=(state_sh, (csh, csh)),
-            )(_ragged_body)
-        else:
-            jitted = partial(
-                jax.jit,
-                donate_argnums=(0,),
-                in_shardings=(state_sh, ragged_batch_sh),
-                out_shardings=(state_sh, (csh, csh)),
-            )(lambda state, batch: _ragged_body(state, batch, None))
-        return ledger.timed("fed.ragged_step", jitted)
+            )
+        return ledger.jit(
+            "fed.ragged_step",
+            _signature(_ragged_body, mu),
+            donate_argnums=(0,),
+            in_shardings=(state_sh, ragged_batch_sh, *anchor_sh),
+            out_shardings=(state_sh, (csh, csh)),
+        )
 
     note_eval = ledger.hook("fed.eval_step")
 
-    @partial(
-        jax.jit,
-        in_shardings=(
-            csh,
-            {"input_ids": bsh, "attention_mask": bsh, "labels": bsh},
-            bsh,
-        ),
-    )
     def eval_step(stacked_params, batch, valid):
         note_eval(tuple(batch["input_ids"].shape))
         return jax.vmap(lambda p, b, v: eval_counts(model, p, b, v))(
             stacked_params, batch, valid
         )
 
-    eval_step = ledger.timed("fed.eval_step", eval_step)
+    eval_step = ledger.jit(
+        "fed.eval_step", eval_step, in_shardings=(csh, batch_sh, bsh)
+    )
 
     if cfg.fed.server_opt_enabled():
         from ..parallel.fedavg import make_server_optimizer, weighted_mean
@@ -506,16 +483,18 @@ def build_federated_steps(
 
     # vmapped optimizer init, compiled once (reset_optimizer runs it
     # every round — a fresh jit lambda per call would recompile).
-    opt_init = jax.jit(
-        lambda p: jax.vmap(optimizer.init)(p),
-        in_shardings=(csh,),
-        out_shardings=csh,
-    )
+    def opt_init(stacked_params):
+        return jax.vmap(optimizer.init)(stacked_params)
+
+    opt_init = jax.jit(opt_init, in_shardings=(csh,), out_shardings=csh)
     # Host-sync path for clients-sharded values: under multi-process,
     # shards on other hosts are not addressable — replicate first (an
     # all-gather over DCN), then np.asarray is local. Single process
     # short-circuits in the trainer's _host().
-    replicate = jax.jit(lambda x: x, out_shardings=sh.replicated)
+    def replicate(tree):
+        return tree
+
+    replicate = jax.jit(replicate, out_shardings=sh.replicated)
 
     return FedSteps(
         train_step=train_step,
