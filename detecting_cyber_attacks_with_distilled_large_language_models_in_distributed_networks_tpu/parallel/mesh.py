@@ -7,9 +7,11 @@ hand-rolled TCP (reference server.py:116-137). Here the cluster is a
 * ``clients`` — federated replicas. Each shard of this axis holds a set of
   client model replicas + their private data shards; the FedAvg collective
   rides this axis (ICI within a slice, DCN across slices).
-* ``data``    — per-client batch parallelism. Gradients sync over this axis
-  automatically (XLA inserts the psum when batch is sharded and params are
-  replicated along it).
+* ``data``    — per-client batch parallelism. Gradients sync over this axis:
+  the mesh tier's lockstep step takes their mean itself, per shard
+  (train/fedsteps.py ``_step_body``); in the programs left to the
+  partitioner (the ragged step, the TCP client's mesh step) XLA inserts the
+  psum when batch is sharded and params are replicated along it.
 
 For multi-host TPU pods, call ``jax.distributed.initialize()`` before
 building the mesh — ``jax.devices()`` then spans all hosts and the same

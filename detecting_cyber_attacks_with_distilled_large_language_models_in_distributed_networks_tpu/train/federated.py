@@ -6,9 +6,10 @@ state dicts, two ports, retry budgets) with:
 
 * one stacked parameter pytree ``[C, ...]`` sharded over the ``clients`` mesh
   axis — client c's replica lives on its own submesh;
-* one jitted, vmapped train step — every client advances in lockstep, each on
-  its private data shard; within a client, batch rows shard over the ``data``
-  axis and XLA psums the gradients;
+* one jitted train step, per shard — every client advances in lockstep, each
+  on its private data shard; within a client, batch rows shard over the
+  ``data`` axis and the step takes the mean of the shards' gradients
+  (train/fedsteps.py ``_step_body``);
 * the round boundary is ``fedavg`` (parallel/fedavg.py) — a single collective,
   no server process, no serialization, no sockets;
 * per-client local-vs-aggregated evaluation identical in shape to the
@@ -38,6 +39,7 @@ from ..obs.profile import maybe_step_profiler, note_memory, profiled_step_iter
 from ..obs.trace import annotate, annotate_iter
 from ..parallel.fedavg import stack_params
 from ..parallel.mesh import FedShardings, make_mesh
+from ..parallel.multihost import global_array_from_replicated
 from ..train.engine import make_optimizer
 from ..utils.logging import get_logger, phase
 
@@ -198,16 +200,22 @@ class FederatedTrainer:
         rngs = jax.vmap(jax.random.fold_in, in_axes=(None, 0))(
             jax.random.fold_in(rng, 7), jnp.arange(C)
         )
+        # Every leaf goes out committed where the jitted steps return it
+        # (params, optimizer state and keys by clients, the counter
+        # replicated): a first call on other placements than the second
+        # call's would trace the step twice.
+        step = global_array_from_replicated(
+            self.sh.replicated, np.zeros((), np.int32)
+        )
         if self.P == 1:
             stacked_params = jax.device_put(
                 stack_params(params, C), self.sh.client
             )
+            rngs = jax.device_put(rngs, self.sh.client)
         else:
             # Every process computed identical params from the same seed
             # (the reference's shared-pretrained-start, client1.py:56);
             # assemble the global [C, ...] stack from those replicas.
-            from ..parallel.multihost import global_array_from_replicated
-
             stacked_params = jax.tree.map(
                 lambda x: global_array_from_replicated(
                     self.sh.client,
@@ -233,8 +241,6 @@ class FederatedTrainer:
                 # Like params/rngs above: promote host-local replicas to
                 # global replicated arrays, or the jitted steps reject the
                 # process-local device placement.
-                from ..parallel.multihost import global_array_from_replicated
-
                 server_opt = jax.tree.map(
                     lambda x: global_array_from_replicated(
                         self.sh.replicated, np.asarray(x)
@@ -244,7 +250,7 @@ class FederatedTrainer:
         return FedState(
             params=stacked_params,
             opt_state=opt_state,
-            step=jnp.zeros((), jnp.int32),
+            step=step,
             rngs=rngs,
             server_opt=server_opt,
         )
@@ -598,7 +604,8 @@ class FederatedTrainer:
         batched-weight GEMMs run ~42% MFU vs ~57% for the identical math
         dispatched as independent per-client steps (PARITY.md r5
         decomposition). Multi-device meshes shard the clients axis and
-        keep the SPMD stacked program."""
+        run the per-shard lockstep step (fedsteps ``_step_body``), which
+        still vmaps the clients of one mesh row."""
         return (
             self.P == 1
             and self.mesh.devices.size == 1

@@ -1,8 +1,9 @@
 """Jitted federated step construction: the SPMD programs the trainer runs.
 
 One stacked ``[C, ...]`` parameter tree sharded over the ``clients`` mesh
-axis; one vmapped train step advances every client in lockstep on its
-private shard (the reference instead runs N separate OS processes,
+axis; one train step advances every client in lockstep on its private
+shard, each device stepping the clients of its mesh row on its own rows of
+their batches (the reference instead runs N separate OS processes,
 client1.py:96-115 per process). ``build_federated_steps`` is a pure
 function of (config, model, optimizer, shardings); ``aggregate_round`` is
 the round-boundary dispatch over those steps — it takes the trainer as a
@@ -44,6 +45,24 @@ def _signature(body: Callable, mu: float) -> Callable:
     if mu > 0.0:
         return body
     return lambda state, batch: body(state, batch, None)
+
+
+def shard_step_keys(rngs, step, split_rows: bool):
+    """This step's dropout keys for the clients a chip holds, inside the
+    mesh step's ``shard_map``: the lockstep counter folded into each
+    client's key, as the packed and the ragged steps fold it. Where a
+    client's batch is split over ``data`` (``split_rows``) the shard's
+    index is folded in as well, so the shards draw independent masks for
+    their own rows; with one shard nothing more is folded in and the keys
+    are those of the packed step."""
+
+    def key(k):
+        k = jax.random.fold_in(k, step)
+        if split_rows:
+            k = jax.random.fold_in(k, jax.lax.axis_index("data"))
+        return k
+
+    return jax.vmap(key)(rngs)
 
 
 def make_packed_step(
@@ -150,7 +169,10 @@ def build_federated_steps(
     """Compile-ready step closures for one experiment configuration.
 
     ``sh``: parallel.mesh.FedShardings — fixes how every input/output lays
-    over the ``clients x data`` mesh, so jit inserts the collectives (the
+    over the ``clients x data`` mesh. The train step is written per shard
+    (one ``shard_map`` over that mesh; autodiff sums the gradients over
+    ``data``, see ``per_client_step``); for the other programs — the
+    ragged step, evaluation, FedAvg — jit inserts the collectives (the
     reference's entire TCP protocol, client1.py:246-336) at trace time.
 
     ``gather``/``constrain`` spec-parameterize the STACKED steps for FSDP
@@ -190,9 +212,24 @@ def build_federated_steps(
         return total, task
 
     def per_client_step(params, opt_state, batch, rng, anchor, step):
-        (_, task), grads = jax.value_and_grad(
-            lambda p: local_loss(p, batch, rng, anchor), has_aux=True
-        )(params)
+        """One client's step on one chip's rows of its batch (inside the
+        ``shard_map`` of ``_step_body``): the chips of a mesh row hold the
+        same params and equal shares of the batch, so the mean of their
+        gradients and losses over ``data`` is the whole batch's."""
+        shards = sh.mesh.shape["data"]
+
+        def objective(p):
+            total, task = local_loss(p, batch, rng, anchor)
+            return total / shards, task
+
+        # Params enter replicated over ``data`` and the objective varies
+        # with the shard's rows, so autodiff sums the shards' gradients
+        # itself, where a weight meets the rows: in the encoder's compute
+        # dtype (bf16), as the partitioner reduced them in the stacked
+        # program, and half the bytes of a mean of the fp32 gradients.
+        # The 1/shards above makes that sum the mean.
+        (_, task), grads = jax.value_and_grad(objective, has_aux=True)(params)
+        task = jax.lax.pmean(task, "data")
         updates, opt_state = optimizer.update(grads, opt_state, params)
         updates = apply_warmup(updates, step, wsteps)
         return optax.apply_updates(params, updates), opt_state, task
@@ -203,14 +240,40 @@ def build_federated_steps(
     note_train = ledger.hook("fed.train_step")
 
     def _step_body(state: FedState, batch, anchor):
+        """The lockstep step, per shard: each chip steps the clients of
+        its mesh row on its own rows of their batches, and draws dropout
+        bits for those rows only (a generator XLA cannot partition, as
+        ``rbg``, would otherwise draw the whole fleet's on every chip)."""
         note_train(tuple(batch["input_ids"].shape))
-        step_rngs = jax.vmap(jax.random.fold_in, in_axes=(0, None))(
-            state.rngs, state.step
+        split_rows = sh.mesh.shape["data"] > 1
+
+        def shard_step(params, opt_state, rngs, step, batch, anchor):
+            return jax.vmap(
+                per_client_step,
+                in_axes=(0, 0, 0, 0, 0 if mu > 0.0 else None, None),
+            )(
+                params,
+                opt_state,
+                batch,
+                shard_step_keys(rngs, step, split_rows),
+                anchor,
+                step,
+            )
+
+        cspec, bspec = csh.spec, bsh.spec
+        params, opt_state, losses = jax.shard_map(
+            shard_step,
+            mesh=sh.mesh,
+            in_specs=(cspec, cspec, cspec, sh.replicated.spec, bspec, cspec),
+            out_specs=cspec,
+        )(
+            state.params,
+            state.opt_state,
+            state.rngs,
+            state.step,
+            batch,
+            anchor,
         )
-        params, opt_state, losses = jax.vmap(
-            per_client_step,
-            in_axes=(0, 0, 0, 0, 0 if mu > 0.0 else None, None),
-        )(state.params, state.opt_state, batch, step_rngs, anchor, state.step)
         return (
             state._replace(
                 params=params, opt_state=opt_state, step=state.step + 1

@@ -249,6 +249,29 @@ def test_step_site_traces_are_what_step_compiles_reads(fed):
     assert ledger.report()["sites"][fed.site]["compiles"] == sum(ledger.compile_counts(fed.site).values())
 
 
+def test_step_site_seconds_are_what_step_trace_s_reads(fed):
+    """The benchmark's ``step_trace_s`` (reader ``ledger_seconds``) is the
+    ledger's ``trace_s`` summed over the sites ``step_compiles`` counts at:
+    the wall seconds of the calls in which the train-step site traced."""
+    sys.path.insert(0, REPO)
+    from benchmark.readers import ledger_seconds
+
+    specs = {}
+    for name in ("step_trace_s", "step_compiles"):
+        with open(os.path.join(REPO, "benchmark", "layer_metrics", f"{name}.json")) as f:
+            specs[name] = json.load(f)
+    sites = specs["step_trace_s"]["args"]["sites"]
+    assert sites == specs["step_compiles"]["args"]["sites"] and fed.site in sites
+    said = []
+    ctx = type("Ctx", (), {"say": staticmethod(said.append)})
+    report = default_ledger().report()["sites"]
+    value = ledger_seconds.read(ctx, sites=sites)
+    assert value == sum(report[s]["trace_s"] for s in sites if s in report)
+    assert value >= report[fed.site]["trace_s"] > 0.0
+    assert ledger_seconds.read(ctx, sites=["no.such_site"]) is None
+    assert fed.site in said[0] and len(said) == 1
+
+
 def test_round_anchor_is_annotated_when_it_copies(tok, eight_devices, tmp_path):
     trainer = FederatedTrainer(
         fed_cfg(tok, 1, 1, server_opt="momentum"), pad_id=tok.pad_id,
