@@ -41,7 +41,9 @@ def run(ctx: Context) -> dict:
     train, held = pool.take(order[:train_rows]), pool.take(order[train_rows:])
     rows_per_fit = E * (train_rows // bs) * bs
     with ctx.rec.span("init_state"):
-        params = harness.init_params_on_device(model_cfg, ctx.seed, train_cfg.prng_impl)
+        params = harness.init_params_on_device(
+            ctx.family, model_cfg, ctx.seed, train_cfg.prng_impl
+        )
         state = trainer.init_state(seed=ctx.seed, params=params)
         del params
         jax.block_until_ready(state.opt_state)

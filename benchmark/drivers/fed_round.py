@@ -107,7 +107,9 @@ def build(ctx: Context):
         rows_per_fit = steps_per_fit * bs
     prepared = trainer.prepare_eval(evals)
     with ctx.rec.span("init_state"):
-        params = harness.init_params_on_device(model_cfg, ctx.seed, cfg.train.prng_impl)
+        params = harness.init_params_on_device(
+            ctx.family, model_cfg, ctx.seed, cfg.train.prng_impl
+        )
         state = trainer.init_state(seed=ctx.seed, params=params)
         del params
         jax.block_until_ready(state.params)
@@ -172,6 +174,8 @@ def check_weighted_mean(ctx: Context, b: dict, before: dict) -> None:
 
     w = b["weights"] / b["weights"].sum()
     leaves = jax.tree.leaves(b["state"].params)
+    # The worst over the sampled leaves and the checked rounds, in limits.
+    name = "fedavg.mean_err_over_limit"
     for i, pre in before.items():
         want = np.tensordot(w, pre, axes=(0, 0))
         got = np.asarray(leaves[i], np.float64)
@@ -179,7 +183,8 @@ def check_weighted_mean(ctx: Context, b: dict, before: dict) -> None:
         # ulps of the leaf's own magnitude.
         tol = 1e-5 * max(float(np.abs(want).max()), 1e-6)
         worst = float(np.abs(got - want[None]).max())
-        if worst > tol:
+        ctx.compare(name, max(ctx.compared.get(name, [0.0])[0], worst / tol), 1.0)
+        if not worst <= tol:
             ctx.fail(
                 f"FedAvg leaf {i}: differs from the numpy weighted mean by {worst} (limit {tol})"
             )
@@ -215,7 +220,7 @@ def run(ctx: Context) -> dict:
     )
     state = b["state"]
     identical = replicas_identical(state)
-    if not identical:
+    if not ctx.compare("replicas_differ", int(not identical), 0):
         ctx.fail("client replicas differ after FedAvg")
     crc = replica0_crc(state)
     params0 = jax.tree.map(lambda x: x[0], state.params)

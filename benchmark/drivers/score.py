@@ -32,11 +32,6 @@ from .. import harness
 from ..harness import Context, pkg
 
 SAMPLE_REPLIES = 32
-#: A served probability against the reference's: bf16 through the served
-#: path measured 0.002-0.008 on the chip (PERF.md section 7), the reference
-#: rounded to float8 0.032 (6 layers) and 0.103 (24 layers)
-#: (tools/tolerance_probe.py).
-REPLY_TOL_ABS = 0.02
 
 
 def start_server(ctx: Context) -> dict:
@@ -59,7 +54,7 @@ def start_server(ctx: Context) -> dict:
     ckpt_dir = os.path.join(ctx.workdir, "ckpt")
     with ctx.rec.span("checkpoint"):
         params = jax.device_get(
-            harness.init_params_on_device(model_cfg, ctx.seed, cfg.train.prng_impl)
+            harness.init_params_on_device(ctx.family, model_cfg, ctx.seed, cfg.train.prng_impl)
         )
         trainer = pkg("train.engine").Trainer(model_cfg, cfg.train, pad_id=tok.pad_id)
         # Checkpointer saves a whole TrainState. A fresh one's Adam moments
@@ -177,11 +172,9 @@ def check_served(ctx: Context, s: dict, table: dict, ok: np.ndarray) -> None:
     """After the window and the memory reading: the served parameters are
     the checkpoint's to the bit and the program's model on them agrees with
     the plain float32 reference (harness.check_model); a seeded sample of
-    answered requests lies within REPLY_TOL_ABS of the reference forward of
-    the same sentences; every reply names round 1."""
+    answered requests lies within the family's ``reply_abs`` of the
+    reference forward of the same sentences; every reply names round 1."""
     import jax
-
-    from ..reference import encoder_fp32
 
     served = s["server"].engine.snapshot()[0]
     same = all(
@@ -191,7 +184,7 @@ def check_served(ctx: Context, s: dict, table: dict, ok: np.ndarray) -> None:
     if not same:
         ctx.fail("the served parameters are not the checkpoint's")
     ctx.rec.data["reference"] = harness.check_model(
-        ctx, served, s["split"], what="served model", bind=True
+        ctx, served, s["split"], what="served model", key="served", bind=True
     )
     rounds = table["round"][ok]
     if len(rounds) and not (rounds == 1).all():
@@ -204,7 +197,7 @@ def check_served(ctx: Context, s: dict, table: dict, ok: np.ndarray) -> None:
     pick = rng.choice(answered, size=min(SAMPLE_REPLIES, len(answered)), replace=False)
     flows = table["flow"][pick].astype(int)
     split = s["split"]
-    _, want = encoder_fp32.forward(
+    _, want = ctx.family.reference(
         served, split.input_ids[flows], split.attention_mask[flows], ctx.model
     )
     want = np.asarray(want, np.float64)
@@ -212,13 +205,14 @@ def check_served(ctx: Context, s: dict, table: dict, ok: np.ndarray) -> None:
     p_ref = (e / e.sum(-1, keepdims=True))[:, 1]
     p_got = table["prob"][pick]
     err = float(np.abs(p_got - p_ref).max())
+    limit = ctx.family.TOLERANCES["reply_abs"]
     ctx.say(
         f"correct/replies: {len(pick)} served probabilities vs the float32 reference: "
-        f"max |dp| {err:.6f} (limit {REPLY_TOL_ABS}); the reference's answers span "
+        f"max |dp| {err:.6f} (limit {limit}); the reference's answers span "
         f"{p_ref.max() - p_ref.min():.6f}"
     )
-    if not np.isfinite(p_got).all() or err > REPLY_TOL_ABS:
-        ctx.fail(f"served probabilities differ from the reference by {err} (limit {REPLY_TOL_ABS})")
+    if not ctx.compare("replies.max_abs_dp", err, limit):
+        ctx.fail(f"served probabilities differ from the reference by {err} (limit {limit})")
     ctx.rec.data["reference"]["max_abs_dp"] = err
 
 

@@ -71,6 +71,16 @@ class Recorder:
     def total(self, name: str, key: str, phase: str = "window") -> float:
         return float(sum(s.get(key, 0) for s in self.select(name, phase)))
 
+    def counters(self, name: str, phase: str = "window") -> dict[str, float]:
+        """Every numeric attribute of the spans ``name``, summed: what a
+        driver counted on them (``rows``, ``steps``, ...)."""
+        out: dict[str, float] = {}
+        for s in self.select(name, phase):
+            for key, x in s.items():
+                if key not in ("t0", "t1") and isinstance(x, (int, float)) and not isinstance(x, bool):
+                    out[key] = out.get(key, 0.0) + x
+        return out
+
 
 #: Untraced whole rounds a window holds at the least, however short.
 MIN_ROUNDS = 4
@@ -134,7 +144,7 @@ def round_results(ctx: "Context", rounds: list[dict], parts: tuple) -> dict:
     it (total over total) and the median round, with every part's seconds
     on an earlier line."""
     failed = sum(1 for x in rounds if not x["losses_finite"])
-    if failed:
+    if not ctx.compare("rounds_nonfinite", failed, 0):
         ctx.fail(f"{failed} round(s) had a non-finite loss")
     say_rounds(ctx, parts)
     return {
@@ -254,6 +264,14 @@ class Context:
     trace_path: str | None = None
     memory: tuple = (0, {})  # peak bytes on the fullest chip, every chip's stats
     problems: list = dataclasses.field(default_factory=list)
+    compared: dict = dataclasses.field(default_factory=dict)  # name -> [number, limit]
+
+    def compare(self, name: str, value: float, limit: float, *, least: bool = False) -> bool:
+        """One number that decides ``correct``, kept beside its limit for
+        the result line; ``least``: the limit is a floor, not a ceiling.
+        Returns whether it holds (a NaN never does)."""
+        self.compared[name] = [float(value), float(limit)]
+        return bool(value >= limit) if least else bool(value <= limit)
 
     def fail(self, problem: str) -> None:
         """A check that decides ``correct`` did not hold. The run goes on
@@ -270,14 +288,20 @@ class Context:
         return "withheld" if self.rehearsal else f"{x:.4f}{unit}"
 
     @property
+    def family(self):
+        """The module ``benchmark/families/<family>.py`` that the
+        configuration names: everything the yardstick knows of the model's
+        architecture."""
+        from . import families
+
+        return families.load(self.config)
+
+    @property
     def model(self) -> dict:
-        """The model as it is run; a rehearsal swaps in the tiny preset's
-        sizes and keeps every other key of the configuration."""
-        if not self.rehearsal:
-            return self.config["model"]
-        tiny = dataclasses.asdict(pkg("config").ModelConfig.tiny())
-        keep = ("gelu", "dropout", "attention_dropout", "head_dropout", "n_classes")
-        return {**tiny, **{k: self.config["model"][k] for k in keep}}
+        """The model as it is run; a rehearsal swaps in the family's tiny
+        model."""
+        model = self.config["model"]
+        return self.family.tiny(model) if self.rehearsal else model
 
     def peaks(self) -> dict:
         """The published peaks of the device; a device that is not in
@@ -288,7 +312,8 @@ class Context:
         return flops.load_peaks("TPU v5 lite" if self.rehearsal else self.devices[0].device_kind)
 
     def model_config(self):
-        return pkg("config").ModelConfig(**self.model)
+        """The program's configuration object of the model as it is run."""
+        return self.family.model_config(self.model)
 
     def scaled(self, key: str, tiny: int) -> int:
         """A traffic size; a rehearsal takes the small stand-in."""
@@ -334,14 +359,12 @@ class Context:
 
 
 # ------------------------------------------------------- weights and data
-def init_params_on_device(model_cfg, seed: int, prng_impl: str):
+def init_params_on_device(family, model_cfg, seed: int, prng_impl: str):
     """The model's weights, random from the seed, made on the device in one
     jitted call in the type they are trained and served in."""
     import jax
 
-    m = pkg("models.distilbert")
-    model = m.DDoSClassifier(model_cfg)
-    return jax.jit(lambda k: m.init_params(model, model_cfg, k))(
+    return jax.jit(lambda k: family.init_params(model_cfg, k))(
         jax.random.key(seed, impl=prng_impl)
     )
 
@@ -363,36 +386,9 @@ def tokenised_flows(ctx: Context, n: int, seed: int, tok):
 
 
 # ------------------------------------------------------------ correctness
-#: The program computes the encoder in bf16 (8 bits of mantissa) with
-#: float32 softmax and LayerNorm statistics, and the head in float32; the
-#: reference is float32 throughout. What is compared is what separates one
-#: input from another: each sequence's last hidden states over its real
-#: tokens (``[tokens, dim]``, relative L2 error), not two logits, which a
-#: young model gives nearly alike for every flow.
-#: tools/tolerance_probe.py measured at the published sizes, on random
-#: weights (PERF.md section 2): the program against the reference 0.73%
-#: (6 layers) and 1.16% (24 layers), the reference rounded to bfloat16 the
-#: same; the reference with every weight and sub-layer output rounded to
-#: float8 (e4m3) 11.2% and 19.5%. The limit sits midway on a log scale: 3 x
-#: over the worst bf16, 3 x under the best float8. The nearest other
-#: sequence lies 28-57% away, so a model that ignored or mixed up its
-#: inputs fails the binding.
-HIDDEN_TOL_REL = 0.035
-#: The logits follow from the CLS vector through the head, so theirs is the
-#: second check: the error against the logit a unit-variance CLS vector
-#: gives (``logit_scale``; a young model answers with logits of 0.2, which
-#: would make a plain relative error a lottery). The probe: the program
-#: 1.0% and 2.5% (6 and 24 layers), the float8 reference 15.9% and 38%; on
-#: the chip bf16 measured 0.07-0.8% in the training cells and up to 2.4%
-#: through the 24-layer served path (PERF.md). Midway on a log scale again.
-LOGIT_TOL_REL = 0.06
-
-
-def logit_scale(params, want: np.ndarray) -> float:
-    """The larger of the largest reference logit and the head's largest
-    column norm (0.55-0.64 here)."""
-    head = np.asarray(params["classifier"]["kernel"], np.float64)
-    return max(float(np.abs(want).max()), float(np.linalg.norm(head, axis=0).max()))
+#: What is compared, against which plain reference and within which limits
+#: is the family's (``benchmark/families/<family>.py``: ``program``,
+#: ``reference``, ``TOLERANCES`` with their evidence, ``logit_scale``).
 
 
 def _rel_l2(err: np.ndarray, ref: np.ndarray) -> np.ndarray:
@@ -420,49 +416,48 @@ def compare_hidden(got: np.ndarray, want: np.ndarray, mask: np.ndarray) -> dict:
     }
 
 
-def check_model(ctx: Context, params, split, *, what: str, bind: bool, n: int = 16) -> dict:
-    """The program's model (its own classes, jitted, as its eval path calls
-    them) on the weights ``params`` against the plain float32 reference on
-    ``n`` seeded sequences: the encoder's last hidden states within
-    HIDDEN_TOL_REL, the logits within LOGIT_TOL_REL of the logit scale and,
-    with ``bind``, every sequence bound to its input."""
+def check_model(
+    ctx: Context, params, split, *, what: str, key: str, bind: bool, n: int = 16
+) -> dict:
+    """The program's model (the family's ``program``: the program's own
+    classes, jitted, as its eval path calls them) on the weights ``params``
+    against the family's plain float32 ``reference`` on ``n`` seeded
+    sequences, within the family's ``TOLERANCES``: the last hidden states
+    within ``hidden_rel``, the logits within ``logit_rel`` of the logit
+    scale and, with ``bind``, every sequence bound to its input. ``key``
+    names the numbers compared in the result line."""
     import jax
 
-    from .reference import encoder_fp32
-
+    family = ctx.family
+    tol = family.TOLERANCES
     rng = np.random.default_rng(ctx.seed + 1009)
     idx = rng.choice(len(split), size=min(n, len(split)), replace=False)
     ids, mask = split.input_ids[idx], split.attention_mask[idx]
-    m = pkg("models.distilbert")
-    model_cfg = ctx.model_config()
-
-    def program(p, i, a):
-        hidden = m.DistilBertEncoder(model_cfg).apply({"params": p["encoder"]}, i, a, True)
-        return hidden, m.DDoSClassifier(model_cfg).apply({"params": p}, i, a, True)
-
-    hidden, logits = (np.asarray(x, np.float32) for x in jax.jit(program)(params, ids, mask))
+    program = jax.jit(family.program(ctx.model_config()))
+    hidden, logits = (np.asarray(x, np.float32) for x in program(params, ids, mask))
     want_hidden, want = (
-        np.asarray(x, np.float32) for x in encoder_fp32.forward(params, ids, mask, ctx.model)
+        np.asarray(x, np.float32) for x in family.reference(params, ids, mask, ctx.model)
     )
     if not (np.isfinite(hidden).all() and np.isfinite(logits).all()):
         ctx.fail(f"{what}: non-finite hidden states or logits")
         return {}
     out = compare_hidden(hidden, want_hidden, mask)
-    out["logit_rel_err"] = float(np.abs(logits - want).max()) / logit_scale(params, want)
-    out.update(tolerance_rel=HIDDEN_TOL_REL, sequences=int(len(idx)))
+    out["logit_rel_err"] = float(np.abs(logits - want).max()) / family.logit_scale(params, want)
+    out.update(tolerance_rel=tol["hidden_rel"], sequences=int(len(idx)))
+    bound = f"limit {tol['binding']:g}" if bind else "no limit on trained weights"
     ctx.say(
         f"correct/{what}: program vs float32 reference on {len(idx)} sequences: last hidden "
         f"states differ by at most {100 * out['hidden_rel_err']:.3f}% (relative L2 over a "
-        f"sequence's tokens; limit {100 * HIDDEN_TOL_REL:g}%); the nearest other sequence "
+        f"sequence's tokens; limit {100 * tol['hidden_rel']:g}%); the nearest other sequence "
         f"lies {100 * out['nearest_other']:.2f}% away, at least {out['binding']:.1f} x the "
-        f"error ({'limit 2' if bind else 'no limit on trained weights'}); logits differ by at most {100 * out['logit_rel_err']:.3f}% of "
-        f"the logit scale (limit {100 * LOGIT_TOL_REL:g}%)"
+        f"error ({bound}); logits differ by at most {100 * out['logit_rel_err']:.3f}% of "
+        f"the logit scale (limit {100 * tol['logit_rel']:g}%)"
     )
-    if out["hidden_rel_err"] > HIDDEN_TOL_REL:
+    if not ctx.compare(f"{key}.hidden_rel", out["hidden_rel_err"], tol["hidden_rel"]):
         ctx.fail(f"{what}: hidden states differ from the reference by {out['hidden_rel_err']:.4f} relative")
-    if bind and out["binding"] < 2.0:
+    if bind and not ctx.compare(f"{key}.binding", out["binding"], tol["binding"], least=True):
         ctx.fail(f"{what}: the comparison cannot bind the inputs (nearest other sequence at {out['binding']:.2f} x the error)")
-    if out["logit_rel_err"] > LOGIT_TOL_REL:
+    if not ctx.compare(f"{key}.logit_rel", out["logit_rel_err"], tol["logit_rel"]):
         ctx.fail(f"{what}: logits differ from the reference by {out['logit_rel_err']:.4f} of the logit scale")
     return out
 
@@ -477,9 +472,13 @@ def check_trained(ctx: Context, params, split, *, what: str) -> dict:
     chip runs, PR 22; held-out accuracy 50%), and no comparison of outputs
     binds the inputs of a model that ignores them. The program is the same
     on either weights, and random ones separate flows by 28-60%."""
-    out = check_model(ctx, params, split, what=f"{what}, trained", bind=False)
-    fresh = init_params_on_device(ctx.model_config(), ctx.seed, pkg("config").TrainConfig().prng_impl)
-    out["seed_weights"] = check_model(ctx, fresh, split, what=f"{what}, the seed's weights", bind=True)
+    out = check_model(ctx, params, split, what=f"{what}, trained", key="trained", bind=False)
+    fresh = init_params_on_device(
+        ctx.family, ctx.model_config(), ctx.seed, pkg("config").TrainConfig().prng_impl
+    )
+    out["seed_weights"] = check_model(
+        ctx, fresh, split, what=f"{what}, the seed's weights", key="seed", bind=True
+    )
     return out
 
 

@@ -164,13 +164,19 @@ def main(argv=None) -> int:
     try:
         out = driver.run(ctx)
         window_compiles = int(ctx.meter.get("window", "compiles"))
-        if window_compiles:
+        if not ctx.compare("window_compiles", window_compiles, 0):
             ctx.fail(f"{window_compiles} compilation(s) inside the window")
         result = assemble(ctx, manifest, out)
     finally:
         shutil.rmtree(ctx.workdir, ignore_errors=True)
     for problem in ctx.problems:
         ctx.say(f"NOT CORRECT: {problem}")
+    # Every number that decided ``correct`` beside its limit: the last
+    # lines of standard error, and the last key of the result line.
+    for name, (value, limit) in ctx.compared.items():
+        sys.stderr.write(f"compared {name}: {value!r} (limit {limit!r})\n")
+    sys.stderr.flush()
+    result["compared"] = ctx.compared
     print(json.dumps(result), flush=True)
     return 0
 

@@ -8,11 +8,12 @@ small trace recorded on the v5e and kept beside this file
 (``tiny_fed.xplane.pb.xz``: two tiny-preset federated rounds under the
 benchmark's spans, PR 22), the load generator's due-time clock and lateness
 against a stub client, the benchmark's round loop against
-``FederatedTrainer.run``, the FLOPs copy against the program's arithmetic,
-the flow template against the program's, and the manifest against its data
-files. Not part of tier-1 (``tests/`` is outside what the benchmark PR may
-touch). Prints one line a check and exits non-zero if any failed. Nothing
-printed here is a speed.
+``FederatedTrainer.run``, each family's FLOPs against the program's
+arithmetic, the flow template against the program's, and the manifest against
+its data files. Not part of tier-1 (``tests/`` is outside what a benchmark PR
+may touch); ``selftest/test_families.py`` runs the quick ones under pytest.
+Prints one line a check and exits non-zero if any failed. Nothing printed here
+is a speed.
 """
 
 from __future__ import annotations
@@ -200,28 +201,35 @@ def check_round_loop() -> str:
 
 # ------------------------------------------------------------------- flops
 def check_flops() -> str:
-    """The FLOPs copy equals ``utils/profiling.py`` today, and the parameter
-    count equals what the program builds, for every configuration."""
+    """Every configuration's family counts the FLOPs ``utils/profiling.py``
+    counts today, and its parameter count equals what the program builds."""
     import jax
 
-    from benchmark import flops, harness
+    from benchmark import families, harness
 
     prof = harness.pkg("utils.profiling")
-    config = harness.pkg("config")
-    m = harness.pkg("models.distilbert")
     seen = []
     for path in sorted(glob.glob(os.path.join(ROOT, "benchmark", "configs", "*.json"))):
         with open(path) as f:
             conf = json.load(f)
-        model = conf["model"]
-        cfg = config.ModelConfig(**model)
+        family, model = families.load(conf), conf["model"]
+        cfg = family.model_config(model)
         for rows in (1, 64):
-            assert flops.forward_flops(model, rows) == prof.forward_flops(cfg, rows)
-            assert flops.train_step_flops(model, rows) == prof.train_step_flops(cfg, rows)
-        built = jax.eval_shape(lambda: m.init_params(m.DDoSClassifier(cfg), cfg, jax.random.key(0)))
+            assert family.forward_flops(model, rows) == prof.forward_flops(cfg, rows)
+            assert family.train_step_flops(model, rows) == prof.train_step_flops(cfg, rows)
+        built = jax.eval_shape(lambda: family.init_params(cfg, jax.random.key(0)))
         n = sum(int(np.prod(x.shape)) for x in jax.tree.leaves(built))
-        assert n == flops.param_count(model) == conf["parameters"], (n, flops.param_count(model))
+        assert n == family.param_count(model) == conf["parameters"], (n, family.param_count(model))
+        assert family.train_step_bytes(model) == 32.0 * n
         seen.append(f"{conf['name']} {n:,}")
+    return "; ".join(seen)
+
+
+def check_peaks() -> str:
+    """The table of peaks holds the v5e's published row, an unknown device
+    is an error, and the roofline's floor names the roof that sets it."""
+    from benchmark import flops
+
     peaks = flops.load_peaks("TPU v5 lite")
     assert peaks["bf16_flops_per_s"] == 197e12 and peaks["hbm_bytes_per_s"] == 819e9
     try:
@@ -232,7 +240,7 @@ def check_flops() -> str:
         raise AssertionError("an unknown device got a peak")
     assert flops.roofline_floor_s(197e12, 1.0, peaks)[1] == "compute"
     assert flops.roofline_floor_s(1.0, 819e9, peaks) == (1.0, "memory")
-    return "; ".join(seen)
+    return "v5e 197 TFLOP/s bf16, 819 GB/s"
 
 
 def check_flows() -> str:
@@ -319,10 +327,10 @@ def check_manifest() -> str:
     return f"{len(cells)} cells, {len(e2e)} end-to-end and {len(m['per_layer'])} per-layer metrics"
 
 
-CHECKS = (
-    check_manifest, check_flows, check_flops, check_xplane, check_compare, check_loadgen,
-    check_round_loop,
-)
+#: Checks that touch no device and end in seconds: selftest/test_families.py
+#: runs each as a case of one parametrised test.
+QUICK = (check_manifest, check_flows, check_flops, check_peaks, check_compare)
+CHECKS = (*QUICK, check_xplane, check_loadgen, check_round_loop)
 
 
 def main() -> int:

@@ -12,7 +12,8 @@ time is spent. It compiles, it does not run: nothing here is a speed.
 
 ``--clients`` / ``--batch`` try another size than the traffic file's. Prints
 the bytes per chip (arguments, outputs, temporaries, total) and, for a mesh,
-the collectives the compiler put into the step.
+the collectives the compiler put into the step, with the bytes of their
+results by element type.
 """
 
 from __future__ import annotations
@@ -24,11 +25,43 @@ import re
 import sys
 import time
 
+import numpy as np
+
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(os.path.dirname(HERE))
+
+
+COLLECTIVE = re.compile(
+    r" = (?P<result>.+?) (?P<op>all-reduce|all-gather|reduce-scatter|collective-permute|all-to-all)"
+    r"(?:-start)?\("
+)
+ARRAY = re.compile(r"\b([a-z]+\d+[a-z0-9]*)\[([\d,]*)\]")
+
+
+def collectives(hlo: str) -> dict[str, dict]:
+    """The collectives in a compiled program's text, by operation: how many,
+    and the bytes of their results by element type. A result is whatever
+    stands between ``=`` and the operation's name: one array, or the tuple of
+    arrays into which the compiler combines most gradient all-reduces
+    (matching an array-typed result only said "1 all-reduce" of a program
+    with five). The ``-done`` half of an asynchronous pair is not counted;
+    its ``-start`` half is, and where that carries the operands beside the
+    results (all-gather, collective-permute) both are in the bytes."""
+    out: dict[str, dict] = {}
+    for line in hlo.splitlines():
+        m = COLLECTIVE.search(line)
+        if m is None:
+            continue
+        op = out.setdefault(m["op"], {"count": 0, "bytes": collections.Counter()})
+        op["count"] += 1
+        for dtype, dims in ARRAY.findall(m["result"]):
+            bits = int(re.search(r"\d+", dtype)[0])
+            elements = int(np.prod([int(d) for d in dims.split(",") if d], dtype=np.int64))
+            op["bytes"][dtype] += elements * bits // 8
+    return out
 
 
 def jitted(step):
@@ -47,19 +80,19 @@ def main() -> int:
 
     import jax
     import jax.numpy as jnp
-    import numpy as np
     from jax.experimental import topologies
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
-    from benchmark import harness
+    from benchmark import families, harness
 
     jax.config.update("jax_enable_compilation_cache", False)
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         entry = next(w for w in json.load(f)["workloads"] if w["name"] == args.workload)
-    model = harness.load_json("configs", f"{entry['config']}.json")["model"]
+    conf = harness.load_json("configs", f"{entry['config']}.json")
+    family = families.load(conf)
     t = harness.load_json("traffic", f"{entry['traffic']}.json")
     config = harness.pkg("config")
-    model_cfg = config.ModelConfig(**model)
+    model_cfg = family.model_config(conf["model"])
     bs = args.batch or int(t["batch"])
     L = model_cfg.max_len
     topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
@@ -97,9 +130,8 @@ def main() -> int:
             NamedSharding(mesh, P("clients")), NamedSharding(mesh, P("clients", "data")),
             NamedSharding(mesh, P()),
         )
-        m = harness.pkg("models.distilbert")
         params = jax.eval_shape(
-            lambda: m.init_params(trainer.model, model_cfg, jax.random.key(0, impl="rbg"))
+            lambda: family.init_params(model_cfg, jax.random.key(0, impl="rbg"))
         )
         stacked = jax.tree.map(
             lambda x: jax.ShapeDtypeStruct((C, *x.shape), x.dtype, sharding=client), params
@@ -136,10 +168,15 @@ def main() -> int:
         f"{gb(mem.temp_size_in_bytes)}, generated code {gb(mem.generated_code_size_in_bytes)}; "
         f"live at once about {gb(total)} of 16 GB"
     )
-    ops = collections.Counter(
-        re.findall(r"= \S+ (all-reduce|all-gather|reduce-scatter|collective-permute|all-to-all)(?:-start)?\(", compiled.as_text())
-    )
-    print(f"[rehearse] collectives in the program: {dict(ops) or 'none'}")
+    found = collectives(compiled.as_text())
+    if not found:
+        print("[rehearse] collectives in the program: none")
+    for op, x in found.items():
+        by_type = ", ".join(f"{dt} {n / 1e6:.1f} MB" for dt, n in sorted(x["bytes"].items()))
+        print(
+            f"[rehearse] collectives in the program: {x['count']} {op}, results "
+            f"{sum(x['bytes'].values()) / 1e6:.1f} MB ({by_type})"
+        )
     return 0
 
 

@@ -1,10 +1,11 @@
 #!/usr/bin/env python3
-"""What the tolerances of ``harness.check_model`` let through, shown once.
+"""What a family's tolerances let through ``harness.check_model``, shown once.
 
     JAX_PLATFORMS=cpu python3 benchmark/tools/tolerance_probe.py --config bert-large-l128
 
 At the configuration's published sizes, on seeded weights and 16 seeded flow
-sentences, against the plain float32 reference (benchmark/reference):
+sentences, against the plain float32 reference of the configuration's family
+(benchmark/families/<family>.py, benchmark/reference):
 
 - the program's own model (bf16 encoder): it has to pass;
 - the reference with every weight and every sub-layer's output rounded to
@@ -39,13 +40,13 @@ def main() -> int:
     import jax
     import jax.numpy as jnp
 
-    from benchmark import flows, harness
-    from benchmark.reference import encoder_fp32
+    from benchmark import families, flows, harness
 
-    model = harness.load_json("configs", f"{args.config}.json")["model"]
-    cfg = harness.pkg("config").ModelConfig(**model)
-    m = harness.pkg("models.distilbert")
-    params = harness.init_params_on_device(cfg, args.seed, "threefry2x32")
+    config = harness.load_json("configs", f"{args.config}.json")
+    family, model = families.load(config), config["model"]
+    tol = family.TOLERANCES
+    cfg = family.model_config(model)
+    params = harness.init_params_on_device(family, cfg, args.seed, "threefry2x32")
     tok = harness.pkg("data").default_tokenizer()
     texts, _ = flows.make_flows(args.sequences, args.seed)
     enc = tok.batch_encode(texts, max_len=model["max_len"])
@@ -55,29 +56,27 @@ def main() -> int:
         e = np.exp(z - z.max(-1, keepdims=True))
         return (e / e.sum(-1, keepdims=True))[:, 1]
 
-    def reference(rnd=lambda a: a):
-        h, z = encoder_fp32.forward(params, ids, mask, model, rnd)
+    def reference(**rnd):
+        h, z = family.reference(params, ids, mask, model, **rnd)
         return np.asarray(h, np.float32), np.asarray(z, np.float64)
 
+    forward = jax.jit(family.program(cfg))
+
     def program(i, a):
-        fn = jax.jit(lambda p, i, a: (
-            m.DistilBertEncoder(cfg).apply({"params": p["encoder"]}, i, a, True),
-            m.DDoSClassifier(cfg).apply({"params": p}, i, a, True),
-        ))
-        h, z = fn(params, i, a)
+        h, z = forward(params, i, a)
         return np.asarray(h, np.float32), np.asarray(z, np.float64)
 
     want, z_want = reference()
-    p_want, scale = p_attack(z_want), harness.logit_scale(params, z_want)
+    p_want, scale = p_attack(z_want), family.logit_scale(params, z_want)
     rows = {
         "program (bf16 encoder)": program(ids, mask),
-        "reference rounded to bfloat16": reference(lambda a: a.astype(jnp.bfloat16).astype(jnp.float32)),
-        "reference rounded to float8_e4m3": reference(lambda a: a.astype(jnp.float8_e4m3fn).astype(jnp.float32)),
+        "reference rounded to bfloat16": reference(rnd=lambda a: a.astype(jnp.bfloat16).astype(jnp.float32)),
+        "reference rounded to float8_e4m3": reference(rnd=lambda a: a.astype(jnp.float8_e4m3fn).astype(jnp.float32)),
         "program fed the next sequence": program(np.roll(ids, 1, 0), np.roll(mask, 1, 0)),
     }
     print(
         f"[probe] {args.config}: {len(texts)} sequences, seed {args.seed}, on {jax.devices()[0].platform}; "
-        f"limits: hidden {100 * harness.HIDDEN_TOL_REL:g}%, binding 2, logits {100 * harness.LOGIT_TOL_REL:g}% of the "
+        f"limits: hidden {100 * tol['hidden_rel']:g}%, binding {tol['binding']:g}, logits {100 * tol['logit_rel']:g}% of the "
         f"scale {scale:.3f}; the reference's P(attack) spans "
         f"{p_want.max() - p_want.min():.5f}"
     )
@@ -85,8 +84,8 @@ def main() -> int:
         r = harness.compare_hidden(got, want, mask)
         logit_err = np.abs(z_got - z_want).max() / scale
         ok = (
-            r["hidden_rel_err"] <= harness.HIDDEN_TOL_REL and r["binding"] >= 2.0
-            and logit_err <= harness.LOGIT_TOL_REL
+            r["hidden_rel_err"] <= tol["hidden_rel"] and r["binding"] >= tol["binding"]
+            and logit_err <= tol["logit_rel"]
         )
         print(
             f"[probe] {name}: hidden {100 * r['hidden_rel_err']:.3f}%, nearest other "
