@@ -283,11 +283,11 @@ def bench_eval() -> None:
     valid = jax.device_put(np.ones(batch_size, np.int32))
 
     for _ in range(warmup):
-        counts, _ = trainer.eval_step(state.params, batch, valid)
+        counts, *_ = trainer.eval_step(state.params, batch, valid)
     _sync(counts)
     t0 = time.perf_counter()
     for _ in range(steps):
-        counts, _ = trainer.eval_step(state.params, batch, valid)
+        counts, *_ = trainer.eval_step(state.params, batch, valid)
     _sync(counts)
     dt = time.perf_counter() - t0
     sps = batch_size * steps / dt

@@ -134,7 +134,7 @@ def _obs_setup(
 
 
 # ------------------------------------------------------------------ config
-def _preset_model(preset: str, vocab_size: int) -> ModelConfig:
+def _preset_model(preset: str, vocab_size: int):
     # One registry (models/presets.py) behind every entrypoint's
     # --preset; adding a scale point is a registry entry, not an
     # if-chain edit here.
@@ -172,11 +172,13 @@ def resolve_config(args: argparse.Namespace, *, vocab_size: int) -> ExperimentCo
         with open(args.config) as f:
             cfg = ExperimentConfig.from_dict(json.load(f))
     else:
+        from ..models.presets import PRESET_DATA
+
         preset = getattr(args, "preset", "tiny")
         model = _preset_model(preset, vocab_size)
         cfg = ExperimentConfig(
             model=model,
-            data=DataConfig(max_len=model.max_len),
+            data=DataConfig(max_len=model.max_len, **PRESET_DATA.get(preset, {})),
         )
 
     model_kw: dict[str, Any] = {}
@@ -489,6 +491,10 @@ def _load_clients(args, cfg: ExperimentConfig, tok, num_clients: int):
                 args.csv, cfg.data, num_clients, tok, max_len=cfg.model.max_len
             )
     splits = _load_client_splits(args, cfg, num_clients)
+    if cfg.data.window_flows:
+        from ..data import window_client
+
+        splits = [window_client(s, cfg.data.window_flows) for s in splits]
     with phase("tokenize", tag="DATA"):
         return [tokenize_client(s, tok, max_len=cfg.model.max_len) for s in splits]
 

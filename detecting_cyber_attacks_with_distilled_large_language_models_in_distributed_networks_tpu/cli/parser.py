@@ -135,7 +135,8 @@ def _add_flight_dir(p: argparse.ArgumentParser) -> None:
 def _add_common(p: argparse.ArgumentParser) -> None:
     p.add_argument("--config", help="JSON config file (ExperimentConfig.to_dict shape)")
     p.add_argument(
-        "--preset", default="tiny", help="tiny|distilbert|bert|bert-large"
+        "--preset", default="tiny",
+        help="tiny|distilbert|bert|bert-large|kimi-linear-tiny|kimi-linear-ep32"
     )
     p.add_argument(
         "--gelu",

@@ -131,6 +131,133 @@ class ModelConfig:
 
 
 @dataclass(frozen=True)
+class KimiLinearConfig:
+    """Hybrid linear-attention / latent-attention decoder with a sparse
+    expert FFN, and the paper's classification head on each row's LAST REAL
+    token (``models/kimi_linear.py``).
+
+    Defaults are the published ``config.json`` of
+    moonshotai/Kimi-Linear-48B-A3B-Instruct: 27 pre-norm (RMSNorm) layers of
+    width 2304; the mixer of layer ``i`` (1-indexed) is full latent attention
+    without positions (MLA, ``mla_use_nope``) where ``i`` is in
+    ``full_attn_layers`` and Kimi Delta Attention (KDA, a gated delta-rule
+    linear attention) elsewhere; the FFN is a dense SwiGLU in the first
+    ``first_dense_layers`` layers and, after them, ``n_experts`` routed
+    SwiGLU experts (sigmoid router, top ``experts_per_token`` by score +
+    selection bias, renormalised, times ``routed_scale``) plus
+    ``n_shared_experts`` shared ones. No position encoding anywhere.
+
+    ``experts_held`` / ``expert_offset`` say which experts THIS chip holds
+    (expert parallelism's share): the router keeps its ``n_experts`` outputs
+    and the layer computes its own experts' part of the result for the
+    tokens routed to them; what the absent experts would add is left out.
+    The held experts share one row buffer (``ops/moe.py``); slots beyond it
+    are COUNTED (the step's and the evaluation's ``overflow``), never
+    silently dropped.
+
+    Frozen and hashable: the engine memoises its compiled steps on it
+    (``train/engine.py::_cached_engine_steps``).
+    """
+
+    #: The type's key in :data:`MODEL_CONFIG_TYPES`; in a serialised
+    #: section it says which type to make again.
+    family: str = "kimi_linear"
+    vocab_size: int = 163840
+    max_len: int = 4096
+    dim: int = 2304
+    n_layers: int = 27
+    full_attn_layers: tuple[int, ...] = (4, 8, 12, 16, 20, 24, 27)
+    first_dense_layers: int = 1
+    # KDA
+    kda_heads: int = 32
+    kda_head_dim: int = 128
+    conv_kernel: int = 4
+    #: Rank of the two low-rank gates (decay and output); the source's
+    #: config does not give it: the family's convention is the head width.
+    gate_rank: int = 128
+    # MLA
+    n_heads: int = 32
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    # FFN
+    hidden_dim: int = 9216
+    expert_dim: int = 1024
+    n_experts: int = 256
+    experts_per_token: int = 8
+    n_shared_experts: int = 1
+    routed_scale: float = 2.446
+    experts_held: int = 256
+    expert_offset: int = 0
+    rms_norm_eps: float = 1e-5
+    initializer_range: float = 0.02
+    n_classes: int = 2
+    pad_token_id: int = 0
+    compute_dtype: str = "bfloat16"
+    param_dtype: str = "float32"
+    #: Per-layer recomputation: each block's forward is replayed in the
+    #: backward pass instead of keeping its intermediates.
+    remat: bool = False
+
+    def __post_init__(self) -> None:
+        if self.n_layers < 1:
+            raise ValueError(f"n_layers={self.n_layers} must be >= 1")
+        if not 0 < self.experts_held <= self.n_experts - self.expert_offset:
+            raise ValueError(
+                f"experts_held={self.experts_held} at expert_offset="
+                f"{self.expert_offset} is not a share of n_experts={self.n_experts}"
+            )
+        if self.experts_per_token > self.n_experts:
+            raise ValueError("experts_per_token exceeds n_experts")
+
+    def mixer(self, layer: int) -> str:
+        """``"mla"`` or ``"kda"`` for the 0-indexed ``layer``."""
+        return "mla" if layer + 1 in self.full_attn_layers else "kda"
+
+    def is_moe(self, layer: int) -> bool:
+        return layer >= self.first_dense_layers
+
+    def replace(self, **kw: Any) -> "KimiLinearConfig":
+        return dataclasses.replace(self, **kw)
+
+    @classmethod
+    def ep32_cut(cls, **kw: Any) -> "KimiLinearConfig":
+        """One chip's share of a deployment in which 32 chips share each
+        layer: layers 1-5 (the leading dense layer and one whole period of
+        three KDA to one MLA), 8 of the 256 experts, an eighth of the
+        vocabulary; every width as published (~555 M parameters)."""
+        kw.setdefault("n_layers", 5)
+        kw.setdefault("full_attn_layers", (4,))
+        kw.setdefault("experts_held", 8)
+        kw.setdefault("vocab_size", 20480)
+        kw.setdefault("remat", True)
+        return cls(**kw)
+
+    @classmethod
+    def tiny(cls, **kw: Any) -> "KimiLinearConfig":
+        """Small config for tests / CI on CPU, fp32: three layers hold
+        every kind of part (KDA + dense FFN, MLA + experts, KDA + experts)."""
+        defaults = dict(
+            vocab_size=256, max_len=96, dim=32, n_layers=3,
+            full_attn_layers=(2,), kda_heads=2, kda_head_dim=16, gate_rank=8,
+            n_heads=2, kv_lora_rank=16, qk_nope_head_dim=16,
+            qk_rope_head_dim=8, v_head_dim=16, hidden_dim=64,
+            expert_dim=24, n_experts=16, experts_per_token=4, experts_held=4,
+            compute_dtype="float32",
+        )
+        return cls(**{**defaults, **kw})
+
+
+#: THE registry of the model families beside the BERT encoder: ``family``
+#: value -> configuration type (a ``ModelConfig`` has no ``family`` key).
+#: ``ExperimentConfig.from_dict`` picks a section's type by it, and
+#: ``models.family_module`` the module ``models/<family>.py`` that holds the
+#: family's ``Classifier`` and ``forward_flops``.
+MODEL_CONFIG_TYPES: dict[str, type] = {"kimi_linear": KimiLinearConfig}
+
+
+@dataclass(frozen=True)
 class DataConfig:
     """CICIDS2017-style flow CSV -> text -> token arrays.
 
@@ -171,8 +298,14 @@ class DataConfig:
     # (client1.py:370) at the cost of one extra XLA compilation. Eval is
     # unaffected (it always counts every example via row masks).
     drop_remainder: bool = True
+    # Rows of a long-context model (data/windows.py): 0 = one flow a row
+    # (the reference's); k > 0 = k consecutive flows of one source joined
+    # into one document, labelled by whether the window holds an attack.
+    window_flows: int = 0
 
     def __post_init__(self) -> None:
+        if self.window_flows < 0:
+            raise ValueError(f"window_flows={self.window_flows} must be >= 0")
         if self.dirichlet_alpha <= 0.0:
             # numpy 2.x draws an all-zero Dirichlet for alpha=0 silently,
             # which would hand every sample to the last client.
@@ -927,7 +1060,7 @@ class MeshConfig:
 
 @dataclass(frozen=True)
 class ExperimentConfig:
-    model: ModelConfig = field(default_factory=ModelConfig)
+    model: ModelConfig | KimiLinearConfig = field(default_factory=ModelConfig)
     data: DataConfig = field(default_factory=DataConfig)
     train: TrainConfig = field(default_factory=TrainConfig)
     fed: FedConfig = field(default_factory=FedConfig)
@@ -973,7 +1106,9 @@ class ExperimentConfig:
     @classmethod
     def from_dict(cls, d: Mapping[str, Any]) -> "ExperimentConfig":
         sections = {
-            "model": ModelConfig,
+            "model": MODEL_CONFIG_TYPES.get(
+                dict(d.get("model", {})).get("family"), ModelConfig
+            ),
             "data": DataConfig,
             "train": TrainConfig,
             "fed": FedConfig,
@@ -1017,7 +1152,7 @@ class ExperimentConfig:
         trained under the then-default erf GELU, so an absent key means
         "exact", not the current ``tanh`` default."""
         model = dict(d.get("model", {}))
-        if "gelu" not in model:
+        if "gelu" not in model and "family" not in model:
             model["gelu"] = "exact"
         out = dict(d)
         out["model"] = model

@@ -49,6 +49,7 @@ from .tokenizer import (  # noqa: F401
     build_domain_vocab,
     default_tokenizer,
 )
+from .windows import render_windows, window_client, window_split  # noqa: F401
 from .streaming import (  # noqa: F401
     stream_client_tokens,
     stream_client_tokens_for,

@@ -309,8 +309,11 @@ class DDoSClassifier(nn.Module):
 def init_params(
     model: nn.Module, cfg: ModelConfig, rng: jax.Array, batch_size: int = 2
 ) -> Any:
-    dummy_ids = jnp.zeros((batch_size, cfg.max_len), jnp.int32)
-    dummy_mask = jnp.ones((batch_size, cfg.max_len), jnp.int32)
+    # No parameter's shape depends on the row length: a long-context
+    # configuration is initialised on a short dummy row.
+    length = min(cfg.max_len, 128)
+    dummy_ids = jnp.zeros((batch_size, length), jnp.int32)
+    dummy_mask = jnp.ones((batch_size, length), jnp.int32)
     return model.init({"params": rng}, dummy_ids, dummy_mask, True)["params"]
 
 

@@ -8,21 +8,39 @@ does not fit a small accelerator's HBM next to its optimizer state —
 it is the demonstration scale for ``train --fsdp`` and the sharded
 scorer (``infer-serve --data-parallel N --fsdp``), where params live
 split per-leaf across the mesh and are gathered at use.
+
+A preset named after a published model keeps the published vocabulary
+table: the CLI hands every preset its tokenizer's size (148 ids for the
+domain WordPiece), and sizing ``distilbert`` by it used to build a 43 M
+model under the name of a 66 M one. Only the ``tiny`` presets, which stand
+for no published model, are sized by the tokenizer.
 """
 
 from __future__ import annotations
 
 from typing import Any, Callable
 
-from ..config import ModelConfig
+from ..config import KimiLinearConfig, ModelConfig
 
-#: name -> ModelConfig factory. Ordered small -> large so help strings
+#: name -> config factory. Ordered small -> large so help strings
 #: and error messages read as the scale ladder.
-PRESETS: dict[str, Callable[..., ModelConfig]] = {
+PRESETS: dict[str, Callable[..., Any]] = {
     "tiny": ModelConfig.tiny,
     "distilbert": ModelConfig.distilbert_base,
     "bert": ModelConfig.bert_base,
     "bert-large": ModelConfig.bert_large,
+    "kimi-linear-tiny": KimiLinearConfig.tiny,
+    "kimi-linear-ep32": KimiLinearConfig.ep32_cut,
+}
+
+#: Presets that stand for no published model: their table is the tokenizer's.
+TOKENIZER_SIZED = ("tiny", "kimi-linear-tiny")
+
+#: DataConfig fields a preset's rows need (``cli/common.py::resolve_config``):
+#: a long-context preset reads windows of consecutive flows, not single flows.
+PRESET_DATA: dict[str, dict[str, Any]] = {
+    "kimi-linear-tiny": {"window_flows": 2},
+    "kimi-linear-ep32": {"window_flows": 28},
 }
 
 
@@ -31,9 +49,11 @@ def preset_names() -> tuple[str, ...]:
     return tuple(PRESETS)
 
 
-def model_preset(name: str, **kw: Any) -> ModelConfig:
-    """Resolve a preset name to its ModelConfig (ValueError on unknown —
-    CLI callers wrap it into their SystemExit idiom)."""
+def model_preset(name: str, **kw: Any):
+    """Resolve a preset name to its model configuration (ValueError on
+    unknown — CLI callers wrap it into their SystemExit idiom). A
+    ``vocab_size`` is the tokenizer's: it sizes the ``tiny`` presets' table,
+    and must fit inside a published preset's own."""
     try:
         factory = PRESETS[name]
     except KeyError:
@@ -41,4 +61,12 @@ def model_preset(name: str, **kw: Any) -> ModelConfig:
             f"unknown model preset {name!r} "
             f"(one of: {'|'.join(PRESETS)})"
         ) from None
+    if name not in TOKENIZER_SIZED and "vocab_size" in kw:
+        published = factory().vocab_size
+        if kw["vocab_size"] > published:
+            raise ValueError(
+                f"preset {name!r} has a {published}-row vocabulary table; the "
+                f"tokenizer needs {kw['vocab_size']}"
+            )
+        kw = {**kw, "vocab_size": published}
     return factory(**kw)

@@ -300,6 +300,25 @@ def default_registry() -> MetricsRegistry:
     return _DEFAULT
 
 
+def publish_route(slots, overflow: int, *, first_expert: int = 0) -> None:
+    """What a fit read of an expert model's routing counters
+    (train/engine.py ``fit/route_read``): the token-slots routed to each
+    expert this chip holds, labelled by the expert's index in the whole
+    layer, and the slots its buffers could not take (must stay 0: they are
+    not in the model's result)."""
+    reg = default_registry()
+    for i, n in enumerate(slots):
+        reg.counter(
+            "fedtpu_moe_routed_slots_total",
+            help="token-slots routed to an expert this chip holds, summed over layers",
+            labels={"expert": str(first_expert + i)},
+        ).inc(float(n))
+    reg.counter(
+        "fedtpu_moe_overflow_slots_total",
+        help="token-slots beyond a held expert's buffer (not computed)",
+    ).inc(float(overflow))
+
+
 class _Handler(BaseHTTPRequestHandler):
     registry: MetricsRegistry  # set per server class below
 

@@ -854,13 +854,13 @@ def _serving_bucket_storm(*, seed: int = 0) -> dict:
     import numpy as np
 
     from ..config import ModelConfig
-    from ..models.distilbert import DDoSClassifier, init_params
+    from ..models import build_classifier, init_params
     from ..serving.engine import ScoreEngine
 
     cfg = ModelConfig.tiny()
     eng = ScoreEngine(
         cfg,
-        init_params(DDoSClassifier(cfg), cfg, jax.random.key(seed)),
+        init_params(build_classifier(cfg), cfg, jax.random.key(seed)),
         buckets=(1, 4),
     )
     eng.warmup()  # pays both bucket compiles, then marks the site warm

@@ -115,6 +115,8 @@ vocabulary (the profiler-clock twin of :data:`SPAN_NAMES`)::
                     off the epoch iterator, its feed / per-client slices
     fit/loss_read   the epoch's loss mean read back: the one point where
                     the host waits for the device
+    fit/route_read  an expert model's routing counters read back beside
+                    the loss (Trainer._read_route; none for other models)
     fit/restack     packed fit: per-client buffers -> stacked state
     dispatch/<site> one program launch, host side, named by its
                     CompileLedger site (obs/profile.py ``timed``)
@@ -181,6 +183,7 @@ ANNOTATIONS = (
     "fit/unstack",
     "fit/next_batch",
     "fit/loss_read",
+    "fit/route_read",
     "fit/restack",
     "dispatch/",
     "eval",

@@ -57,7 +57,7 @@ from typing import Any
 import numpy as np
 
 from ..config import ModelConfig
-from ..models.distilbert import DDoSClassifier
+from ..models import build_classifier
 from ..obs.profile import CompileLedger, maybe_step_profiler, profile_stride
 from ..utils.logging import get_logger
 
@@ -126,7 +126,7 @@ class ScoreEngine:
             )
         else:
             self._gather_prog = None
-        model = DDoSClassifier(model_cfg)
+        model = build_classifier(model_cfg)
 
         def _probs(p, input_ids, attention_mask):
             # Trace-time hook: this Python body runs exactly once per

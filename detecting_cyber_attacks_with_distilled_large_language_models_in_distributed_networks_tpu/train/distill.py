@@ -26,7 +26,7 @@ import optax
 
 from ..config import DistillConfig, ModelConfig, TrainConfig
 from ..data.pipeline import TokenizedSplit
-from ..models.distilbert import DDoSClassifier
+from ..models import build_classifier
 from .engine import Trainer, TrainState, apply_warmup
 
 
@@ -127,7 +127,7 @@ class DistillTrainer(Trainer):
             )
         self.teacher_cfg = teacher_cfg
         self.distill_cfg = distill_cfg
-        self.teacher_model = DDoSClassifier(teacher_cfg)
+        self.teacher_model = build_classifier(teacher_cfg)
         self.distill_step = self._make_distill_step()
 
     def _make_distill_step(self):
@@ -169,7 +169,9 @@ class DistillTrainer(Trainer):
             )
             updates = apply_warmup(updates, state.step, self.train_cfg.warmup_steps)
             params = optax.apply_updates(state.params, updates)
-            return TrainState(params, opt_state, state.step + 1, state.rng), loss
+            return TrainState(
+                params, opt_state, state.step + 1, state.rng, state.route
+            ), loss
 
         return step
 

@@ -22,13 +22,16 @@ import optax
 
 from ..config import ModelConfig, TrainConfig
 from ..data.pipeline import TokenizedSplit, batch_iterator, pad_split_to_batch
-from ..models.distilbert import DDoSClassifier, init_params
+from ..models import build_classifier, init_params
+from ..models.distilbert import DDoSClassifier
+from ..models.routing import ROUTE, route_totals, route_zeros
 from ..obs.profile import (
     default_ledger,
     maybe_step_profiler,
     note_memory,
     profiled_step_iter,
 )
+from ..obs.metrics import publish_route
 from ..obs.trace import annotate, annotate_iter
 from ..ops.metrics import (
     BinaryCounts,
@@ -49,6 +52,10 @@ class TrainState(NamedTuple):
     opt_state: Any
     step: jnp.ndarray  # int32 scalar
     rng: jax.Array  # dropout PRNG key, folded per step
+    # Routing counters of a model with expert layers (models/routing.py),
+    # accumulated by the step on the device since fit last read them; None
+    # (no leaf: the same compiled program) for a model that routes nothing.
+    route: Any = None
 
 
 def warmup_factor(step: jnp.ndarray, warmup_steps: int) -> jnp.ndarray:
@@ -127,16 +134,25 @@ def make_optimizer(cfg: TrainConfig) -> optax.GradientTransformation:
 
 
 def loss_fn(model: DDoSClassifier, params, batch, rng) -> jnp.ndarray:
-    logits = model.apply(
+    return loss_and_route_fn(model, params, batch, rng)[0]
+
+
+def loss_and_route_fn(model: DDoSClassifier, params, batch, rng):
+    """The train step's objective and, beside it, the routing counters the
+    model's expert layers sowed in this forward pass (``{}`` for a model
+    without: nothing is added to its program)."""
+    logits, sown = model.apply(
         {"params": params},
         batch["input_ids"],
         batch["attention_mask"],
         False,  # train mode: dropout active
         rngs={"dropout": rng},
+        mutable=[ROUTE],
     )
-    return optax.softmax_cross_entropy_with_integer_labels(
+    loss = optax.softmax_cross_entropy_with_integer_labels(
         logits, batch["labels"]
     ).mean()
+    return loss, route_totals(sown)
 
 
 def masked_loss_fn(model: DDoSClassifier, params, batch, rng) -> jnp.ndarray:
@@ -171,9 +187,19 @@ def eval_counts(
     to the pre-K-class path — and K > 2 accumulates the [K, K] confusion
     matrix with ``P(any attack) = 1 - P(class 0)`` as the scalar score the
     serving/drift plane consumes (one [0, 1] score axis for every K)."""
-    logits = model.apply(
-        {"params": params}, batch["input_ids"], batch["attention_mask"], True
+    return eval_counts_and_route(model, params, batch, valid)[:2]
+
+
+def eval_counts_and_route(model: DDoSClassifier, params, batch, valid):
+    """:func:`eval_counts`' two results and, third, the routing counters the
+    model's expert layers sowed in this forward pass (``{}`` for a model
+    without: nothing is added to its program), so that an evaluation counts
+    the slots its expert buffers could not take as a fit does."""
+    logits, sown = model.apply(
+        {"params": params}, batch["input_ids"], batch["attention_mask"], True,
+        mutable=[ROUTE],
     )
+    routed = route_totals(sown)
     per_example = optax.softmax_cross_entropy_with_integer_labels(
         logits, batch["labels"]
     )
@@ -184,10 +210,10 @@ def eval_counts(
     if int(logits.shape[-1]) == 2:
         counts = binary_counts(logits, batch["labels"], loss, valid)
         probs = jax.nn.softmax(logits, axis=-1)[:, 1]
-        return counts, probs
+        return counts, probs, routed
     counts = class_counts(logits, batch["labels"], loss, valid)
     probs = 1.0 - jax.nn.softmax(logits, axis=-1)[:, 0]
-    return counts, probs
+    return counts, probs, routed
 
 
 def make_step_telemetry(
@@ -261,13 +287,15 @@ def make_train_step(
     if gather is not None:
         tagged = _tag_gather(gather)
         loss_rm = fsdp_remat_loss(
-            lambda p, batch, step_rng: loss_fn(model, tagged(p), batch, step_rng)
+            lambda p, batch, step_rng: loss_and_route_fn(
+                model, tagged(p), batch, step_rng
+            )
         )
     else:
         def loss_rm(p, batch, step_rng):
-            return loss_fn(model, p, batch, step_rng)
+            return loss_and_route_fn(model, p, batch, step_rng)
 
-    def _apply_grads(state, loss, grads):
+    def _apply_grads(state, loss, grads, routed):
         # The ONE update tail (constrain -> optimizer -> warmup -> apply)
         # shared by the plain and prox entries — the update math cannot
         # drift between them.
@@ -278,7 +306,10 @@ def make_train_step(
         params = optax.apply_updates(state.params, updates)
         if constrain is not None:
             params, opt_state = constrain(params), constrain(opt_state)
-        return TrainState(params, opt_state, state.step + 1, state.rng), loss
+        route = state.route
+        if route is not None:
+            route = jax.tree.map(jnp.add, route, routed)
+        return TrainState(params, opt_state, state.step + 1, state.rng, route), loss
 
     if prox_mu > 0.0:
         mu = float(prox_mu)
@@ -290,14 +321,13 @@ def make_train_step(
             step_rng = jax.random.fold_in(state.rng, state.step)
 
             def prox_loss(p, batch, step_rng):
-                return loss_rm(p, batch, step_rng) + 0.5 * mu * prox_sq(
-                    p, anchor
-                )
+                loss, routed = loss_rm(p, batch, step_rng)
+                return loss + 0.5 * mu * prox_sq(p, anchor), routed
 
-            loss, grads = jax.value_and_grad(prox_loss)(
+            (loss, routed), grads = jax.value_and_grad(prox_loss, has_aux=True)(
                 state.params, batch, step_rng
             )
-            return _apply_grads(state, loss, grads)
+            return _apply_grads(state, loss, grads, routed)
 
         return ledger.jit(site, train_step_prox, donate_argnums=(0,))
 
@@ -306,10 +336,10 @@ def make_train_step(
         # per traced shape, so the note IS a compile event, never a call.
         note_compile(tuple(batch["input_ids"].shape))
         step_rng = jax.random.fold_in(state.rng, state.step)
-        loss, grads = jax.value_and_grad(loss_rm)(
+        (loss, routed), grads = jax.value_and_grad(loss_rm, has_aux=True)(
             state.params, batch, step_rng
         )
-        return _apply_grads(state, loss, grads)
+        return _apply_grads(state, loss, grads, routed)
 
     return ledger.jit(site, train_step, donate_argnums=(0,))
 
@@ -320,18 +350,19 @@ def make_eval_step(
     gather: Callable | None = None,
     site: str = "engine.eval_step",
 ) -> Callable:
-    """Jitted eval step -> (BinaryCounts, P(class 1) probs for ROC/PR).
+    """Jitted eval step -> (BinaryCounts, P(class 1) probs for ROC/PR,
+    the routing counters of a model with expert layers or ``{}``).
     ``gather`` places shard-at-rest params replicated at use (the FSDP
     entry :func:`make_fsdp_eval_step`); no remat needed — eval saves no
     residuals."""
     ledger = default_ledger()
     note_compile = ledger.hook(site)
 
-    def eval_step(params, batch, valid) -> tuple[BinaryCounts, jnp.ndarray]:
+    def eval_step(params, batch, valid) -> tuple[BinaryCounts, jnp.ndarray, dict]:
         note_compile(tuple(batch["input_ids"].shape))
         if gather is not None:
             params = gather(params)
-        return eval_counts(model, params, batch, valid)
+        return eval_counts_and_route(model, params, batch, valid)
 
     return ledger.jit(site, eval_step)
 
@@ -451,7 +482,7 @@ def _cached_engine_steps(model_cfg: ModelConfig, train_cfg: TrainConfig):
     suite) shares one set of compiled executables. Callers go through
     :func:`_engine_steps`, which canonicalizes step-irrelevant fields out
     of the key."""
-    model = DDoSClassifier(model_cfg)
+    model = build_classifier(model_cfg)
     optimizer = make_optimizer(train_cfg)
     return (
         model,
@@ -529,6 +560,10 @@ class Trainer:
         # fit time because the CLI installs the stride after trainers
         # are built.
         self.step_profiler = maybe_step_profiler("train")
+        # What fit read of the routing counters (a model with expert
+        # layers), summed since the caller last set it to None: slots by
+        # held expert and the overflow that must stay 0.
+        self.last_route: dict | None = None
 
     def init_state(self, seed: int | None = None, params: Any | None = None) -> TrainState:
         seed = self.train_cfg.seed if seed is None else seed
@@ -541,6 +576,7 @@ class Trainer:
             opt_state=self._init_opt_state(params),
             step=jnp.zeros((), jnp.int32),
             rng=jax.random.fold_in(rng, 1),
+            route=route_zeros(self.model_cfg),
         )
 
     def _place_init_params(self, params: Any) -> Any:
@@ -747,11 +783,46 @@ class Trainer:
                 with annotate("fit/loss_read"):
                     avg = float(jnp.stack(losses).mean()) if losses else 0.0
                 epoch_losses.append(avg)
+                if state.route is not None:
+                    state = self._read_route(state)
                 log.info(
                     f"{tag}Epoch [{epoch - epoch_offset + 1}/{epochs}], "
                     f"{loss_label}: {avg:.4f}"
                 )
         return state, epoch_losses
+
+    def _read_route(self, state: TrainState) -> TrainState:
+        """Read the routing counters the steps accumulated since the last
+        read (beside the epoch's loss: the host is waiting there anyway),
+        publish them (obs/metrics.py), keep them for the caller
+        (``last_route``, summed over a fit's epochs by ``fit``'s callers
+        that need it) and start the next count from zero."""
+        with annotate("fit/route_read"):
+            read = jax.device_get(state.route)
+        slots = np.asarray(read["slots"], np.int64)
+        overflow = int(read["overflow"])
+        self._note_route(slots, overflow, "fit")
+        prev = self.last_route or {"slots": 0, "overflow": 0}
+        self.last_route = {
+            "slots": prev["slots"] + slots,
+            "overflow": prev["overflow"] + overflow,
+        }
+        return state._replace(route=jax.tree.map(jnp.zeros_like, state.route))
+
+    def _note_route(self, slots: np.ndarray, overflow: int, where: str) -> None:
+        """Publish what ``where`` (``fit`` or ``evaluate``) read of the
+        routing counters, and say so loudly when the expert buffers could
+        not take every slot: those slots are NOT in the model's result
+        (ops/moe.py), so the epoch trained, or the evaluation judged,
+        another function than the model's."""
+        publish_route(slots, overflow, first_expert=self.model_cfg.expert_offset)
+        if overflow:
+            log.error(
+                f"{where}: {overflow} of {int(slots.sum())} token-slots routed to the "
+                f"experts held here were beyond their row buffer and NOT computed "
+                f"(ops/moe.py::CAPACITY_FACTOR sizes it; "
+                f"fedtpu_moe_overflow_slots_total counts them)"
+            )
 
     def evaluate(
         self,
@@ -763,7 +834,9 @@ class Trainer:
     ) -> dict:
         """Five reference metrics + confusion matrix (+ labels/probs for
         ROC & PR curves, the reference's evaluate_model return shape,
-        client1.py:150)."""
+        client1.py:150). For a model with expert layers also
+        ``routed_overflow``: the token-slots its expert buffers could not
+        take in this evaluation (published and logged like a fit's)."""
         with annotate("eval"):
             padded, valid = pad_split_to_batch(split, batch_size, pad_id=self.pad_id)
             # None-init: the first batch's counts type (BinaryCounts for K=2,
@@ -774,6 +847,7 @@ class Trainer:
             # loop so eval pipelines like fit() does.
             probs_dev: list[jnp.ndarray] = []
             valid_slices: list[np.ndarray] = []
+            route: dict | None = None  # {} all along for a model that routes nothing
             for start in range(0, len(padded), batch_size):
                 sl = slice(start, start + batch_size)
                 batch = {
@@ -781,8 +855,9 @@ class Trainer:
                     "attention_mask": padded.attention_mask[sl],
                     "labels": padded.labels[sl],
                 }
-                counts, probs = self.eval_step(batch=batch, params=params, valid=valid[sl])
+                counts, probs, routed = self.eval_step(batch=batch, params=params, valid=valid[sl])
                 totals = counts if totals is None else totals + counts
+                route = routed if route is None else jax.tree.map(jnp.add, route, routed)
                 if collect_probs:
                     probs_dev.append(probs)
                     valid_slices.append(valid[sl])
@@ -794,6 +869,12 @@ class Trainer:
                     if isinstance(totals, ClassCounts)
                     else finalize_metrics(totals)
                 )
+                if route:
+                    read = jax.device_get(route)
+                    metrics["routed_overflow"] = int(read["overflow"])
+                    self._note_route(
+                        np.asarray(read["slots"], np.int64), metrics["routed_overflow"], "evaluate"
+                    )
             if collect_probs:
                 if probs_dev:
                     all_probs = np.asarray(jnp.concatenate(probs_dev))

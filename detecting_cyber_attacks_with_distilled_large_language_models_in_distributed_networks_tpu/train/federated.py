@@ -34,7 +34,7 @@ import numpy as np
 
 from ..config import ExperimentConfig
 from ..data.pipeline import StackedClients, TokenizedSplit
-from ..models.distilbert import DDoSClassifier, init_params
+from ..models import build_classifier, init_params
 from ..obs.profile import maybe_step_profiler, note_memory, profiled_step_iter
 from ..obs.trace import annotate, annotate_iter
 from ..parallel.fedavg import stack_params
@@ -115,7 +115,7 @@ class FederatedTrainer:
         else:
             self.client_offset = 0
         self.sh = FedShardings(self.mesh)
-        self.model = DDoSClassifier(cfg.model)
+        self.model = build_classifier(cfg.model)
         self.optimizer = make_optimizer(cfg.train)
         # Observability (obs/trace.py): set by the CLI (or any caller) to
         # emit per-round client-local/agg phase spans; None by default —

@@ -575,12 +575,12 @@ def build_federated_steps(
 
 @lru_cache(maxsize=None)
 def _cached_federated_steps(cfg, mesh) -> FedSteps:
-    from ..models.distilbert import DDoSClassifier
+    from ..models import build_classifier
     from ..parallel.mesh import FedShardings
     from .engine import make_optimizer
 
     return build_federated_steps(
-        cfg, DDoSClassifier(cfg.model), make_optimizer(cfg.train), FedShardings(mesh)
+        cfg, build_classifier(cfg.model), make_optimizer(cfg.train), FedShardings(mesh)
     )
 
 

@@ -46,6 +46,11 @@ def forward_flops(
     are O(L·D) — negligible against the D² terms and excluded, which also
     matches how XLA's own cost model attributes transformer step cost.
     """
+    from ..models import family_module
+
+    module = family_module(cfg)
+    if module is not None:  # another family counts its own (models/<family>.py)
+        return module.forward_flops(cfg, batch_size, seq_len)
     L = seq_len if seq_len is not None else cfg.max_len
     D, F = cfg.dim, cfg.hidden_dim
     per_layer = 8 * L * D * D + 4 * L * L * D + 4 * L * D * F

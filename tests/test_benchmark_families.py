@@ -1,0 +1,254 @@
+"""Tier-1 hook for the benchmark's model-family seam (PR 27 could not add a
+file under ``tests/``): the cases of ``benchmark/selftest/test_families.py``
+run here as they are, plus cases for the second family the benchmark now has
+(``kimi_linear``) through the same seam, and the new cell's CPU rehearsal.
+
+Two of the selftest's cases quote a table of the BERT configurations only
+(``QUOTED``; ``family == "bert_encoder"`` for every configuration): they are
+taken over here for the configurations the table knows, and the second is
+asked of every configuration's own family.
+"""
+
+import json
+import os
+import subprocess
+import sys
+import types
+
+import numpy as np
+import pytest
+
+from benchmark.selftest import test_families as _cases
+from benchmark.selftest.test_families import *  # noqa: F401,F403  (the cases and their fixture)
+
+from benchmark import families, harness
+from benchmark.families import kimi_linear
+
+ROOT = _cases.ROOT
+KIMI = "kimi-linear-48b-a3b-ep32"
+CELL = "kimilinear-window-fit-l4k"
+
+
+@pytest.mark.parametrize("name", [n for n in _cases.CONFIGS if n in _cases.QUOTED])
+def test_counts_are_the_quoted(name):
+    _cases.test_counts_are_the_quoted(name)
+
+
+def test_every_configuration_names_a_family_that_is_there():
+    assert KIMI in _cases.CONFIGS
+    for name in _cases.CONFIGS:
+        conf = harness.load_json("configs", f"{name}.json")
+        assert os.path.isfile(os.path.join(ROOT, "benchmark", "families", f"{conf['family']}.py"))
+        assert families.load(conf).__name__.endswith(conf["family"])
+
+
+# ------------------------------------------------ the second family's cases
+def test_kimi_family_loads_and_counts_what_the_program_builds():
+    import jax
+
+    conf = harness.load_json("configs", f"{KIMI}.json")
+    family, model = families.load(conf), conf["model"]
+    assert family is kimi_linear
+    cfg = family.model_config(model)
+    built = jax.eval_shape(lambda: family.init_params(cfg, jax.random.key(0)))
+    n = sum(int(np.prod(x.shape)) for x in jax.tree.leaves(built))
+    assert n == family.param_count(model) == conf["parameters"] == 555_253_122
+    assert set(built) == {"encoder", "classifier"}
+    assert family.train_step_bytes(model, steps=8) == 8 * 32.0 * n
+    # every width as published; the cuts are the three the file lists
+    src = conf["source_config"]
+    assert (cfg.dim, cfg.hidden_dim, cfg.expert_dim) == (src["hidden_size"], src["intermediate_size"], src["moe_intermediate_size"])
+    assert (cfg.kda_heads, cfg.kda_head_dim, cfg.conv_kernel) == (32, 128, 4)
+    assert (cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim) == (512, 128, 64, 128)
+    assert (cfg.n_experts, cfg.experts_per_token, cfg.routed_scale) == (256, 8, 2.446)
+    assert (cfg.n_layers, cfg.experts_held, cfg.vocab_size) == (5, 8, 20480)
+    assert all(conf[k] == src[k] for k in src if k not in conf["reduced"])
+    # the yardstick counts the chunk the program runs
+    assert family.KDA_CHUNK == harness.pkg("ops.kda").CHUNK
+
+
+def test_kimi_flops_and_bytes_rise_with_the_slots_really_routed():
+    model = harness.load_json("configs", f"{KIMI}.json")["model"]
+    fam = kimi_linear
+    base = fam.train_step_flops(model, 32, steps=8, tokens=32 * 4096, routed_slots_here=0)
+    more = fam.train_step_flops(model, 32, steps=8, tokens=32 * 4096, routed_slots_here=262144)
+    assert 0 < base < more and more - base == 3 * 262144 * 6 * 2304 * 1024
+    mean = fam.train_step_flops(model, 32)
+    assert base < mean < more  # without the counter: the mean load (0.25 slots a token a layer)
+    assert 1.85e9 < mean / (32 * 4096) < 1.95e9  # about 1.9 GFLOP a token to train
+    f0, b0 = fam.scope_work(model, "moe/experts", tokens=131072.0, steps=8, routed_slots_here=1000)
+    f1, b1 = fam.scope_work(model, "moe/experts", tokens=131072.0, steps=8, routed_slots_here=2000)
+    assert 0 < f0 < f1 and 0 < b0 < b1
+    f, b = fam.scope_work(model, "kda/chunks", tokens=131072.0)
+    assert f > 0 and b > 0 and fam.scope_work(model, "mla", tokens=1.0) is None
+
+
+def test_kimi_tiny_is_the_tiny_preset():
+    model = harness.load_json("configs", f"{KIMI}.json")["model"]
+    tiny = kimi_linear.tiny(model)
+    cfg = kimi_linear.model_config(tiny)
+    assert cfg.dim == 32 and cfg.remat is True and cfg.routed_scale == 2.446
+    assert {cfg.mixer(i) for i in range(cfg.n_layers)} == {"kda", "mla"}
+
+
+def _kimi_context(monkeypatch, name, **overrides):
+    mod = types.ModuleType(f"benchmark.families.{name}")
+    mod.__dict__.update({k: v for k, v in vars(kimi_linear).items() if not k.startswith("__")})
+    mod.__dict__.update(overrides)
+    monkeypatch.setitem(sys.modules, mod.__name__, mod)
+    conf = {**harness.load_json("configs", f"{KIMI}.json"), "family": name}
+    ctx = _cases.context(conf)
+    tok = harness.pkg("data").default_tokenizer()
+    _, split = harness.tokenised_flows(ctx, 8, ctx.seed, tok)
+    params = harness.init_params_on_device(ctx.family, ctx.model_config(), ctx.seed, "threefry2x32")
+    return ctx, params, split
+
+
+def test_kimi_program_agrees_with_its_reference_through_check_model(monkeypatch):
+    ctx, params, split = _kimi_context(monkeypatch, "standin_k")
+    harness.check_model(ctx, params, split, what="kimi", key="k", bind=True, n=4)
+    assert not ctx.problems, ctx.problems
+    assert ctx.compared["k.hidden_rel"][0] < 1e-4
+    assert ctx.compared["k.hidden_rel"][1] == kimi_linear.TOLERANCES["hidden_rel"]
+
+
+@pytest.mark.parametrize("fault", _cases.FAULTS)
+def test_a_planted_fault_in_the_kimi_program_is_not_correct(monkeypatch, fault):
+    alter, says = _cases.FAULTS[fault]
+
+    def program(model_cfg):
+        forward = kimi_linear.program(model_cfg)
+        return lambda p, i, a: alter(*forward(p, i, a))
+
+    ctx, params, split = _kimi_context(monkeypatch, "standin_f", program=program)
+    harness.check_model(ctx, params, split, what="faulty", key="k", bind=True, n=4)
+    assert any(says in p for p in ctx.problems), (ctx.problems, ctx.compared)
+
+
+def test_the_timed_step_is_judged_against_a_reference_step_and_planted_faults_are_not_correct(monkeypatch):
+    """The new driver's comparison of the timed step, at the tiny preset: one
+    launch of ``engine.train_step`` gives its loss, its gradient (Adam's
+    first moment) and the parameters' change, and ``judge_step`` holds each
+    to the family's limit against the reference's loss, gradient and first
+    Adam step, the reference computing under the step's own choice of experts.
+    A state left unchanged, a step on half the batch, a fault in ONE small
+    leaf (which moves the whole tree's figure almost nothing) and a router
+    that chooses other experts each come out not correct."""
+    import copy
+
+    import jax
+
+    from benchmark.drivers import window_fit
+
+    ctx, _, split = _kimi_context(monkeypatch, "standin_s")
+    cfg = ctx.model_config()
+    config = harness.pkg("config")
+    train_cfg = config.TrainConfig(learning_rate=2e-5, seed=ctx.seed, log_every=0)
+    trainer = harness.pkg("train.engine").Trainer(cfg, train_cfg, pad_id=0)
+    fresh = lambda: harness.init_params_on_device(ctx.family, cfg, ctx.seed, train_cfg.prng_impl)  # noqa: E731
+    batch = {
+        "input_ids": split.input_ids[:4], "attention_mask": split.attention_mask[:4],
+        "labels": np.array([0, 1, 1, 0], np.int32),
+    }
+    got = window_fit.timed_step(ctx, trainer, fresh(), batch)
+    want = window_fit.reference_step(ctx, fresh(), batch, train_cfg, got["routes"])
+    assert got["overflow"] == 0 and set(got["grads"]) == set(want["grads"]) == {"encoder", "classifier"}
+    mask = batch["attention_mask"]
+    out = window_fit.judge_step(ctx, got, want, mask, what="sound")
+    assert not ctx.problems, (ctx.problems, ctx.compared)
+    assert {"step.loss_abs", "step.grad_rel", "step.grad_leaf_rel", "step.update_rel", "step.flip_share"} <= set(ctx.compared)
+    assert out["flipped"] == 0 and out["slots"] == int(mask.sum()) * cfg.experts_per_token * len(got["routes"])
+    assert out["grad_rel_whole"] < 1e-3 and out["update_rel"] < 0.2  # fp32 compute: rounding, and a few signs of tiny gradients
+    # the parameters really moved by the learning rate, as the reference Adam step says
+    moved = np.abs(got["update"]["classifier"]["kernel"])
+    assert 0.9 * 2e-5 < float(np.median(moved)) < 1.1 * 2e-5
+
+    def judged(planted, says):
+        ctx.problems.clear()
+        window_fit.judge_step(ctx, planted, want, mask, what="planted")
+        assert any(says in p for p in ctx.problems), (says, ctx.problems, ctx.compared)
+
+    judged({**got, "update": jax.tree.map(np.zeros_like, got["update"])}, "parameters' change")
+    assert ctx.compared["step.update_rel"][0] == 1.0  # what a state left unchanged reads
+    half = {k: np.concatenate([v[:2], v[:2]]) for k, v in batch.items()}
+    judged(window_fit.timed_step(ctx, trainer, fresh(), half), "gradient differs")
+    one_leaf = copy.deepcopy(got)
+    one_leaf["grads"]["encoder"]["layer_0"]["kda"]["dt_bias"] *= 3.0
+    judged(one_leaf, "['dt_bias'] differs")
+    assert ctx.compared["step.grad_rel"][0] < ctx.compared["step.grad_rel"][1]  # the whole tree's figure still passes
+    # a router that chooses other experts: a tenth of the slots moved to the next expert
+    moved = copy.deepcopy(got)
+    for idx in moved["routes"]:
+        idx[:, ::3, 0] = (idx[:, ::3, 0] + 1) % cfg.n_experts
+    judged(moved, "router chose other experts")
+
+
+def test_a_cache_too_small_for_the_cell_is_left_alone():
+    """Under a size cap that cannot hold the cell's programs the driver
+    compiles outside the persistent cache (the chip machine caps it at 192
+    MiB, and the cell's writes emptied it for the BERT cells); an uncapped or
+    a large cache is used as ever, and a rehearsal changes nothing."""
+    import jax
+
+    from benchmark.drivers import window_fit
+
+    ctx = _cases.context(harness.load_json("configs", f"{KIMI}.json"), rehearsal=False)
+    was = (jax.config.jax_enable_compilation_cache, jax.config.jax_compilation_cache_max_size)
+    try:
+        for cap, rehearsal, stays in ((-1, False, True), (2**30, False, True), (192 * 2**20, True, True), (192 * 2**20, False, False)):
+            jax.config.update("jax_enable_compilation_cache", True)
+            jax.config.update("jax_compilation_cache_max_size", cap)
+            ctx.rehearsal = rehearsal
+            window_fit.leave_a_small_cache_alone(ctx)
+            assert jax.config.jax_enable_compilation_cache is stays, (cap, rehearsal)
+        assert "capped at 192 MiB" in ctx.said[-1]
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was[0])
+        jax.config.update("jax_compilation_cache_max_size", was[1])
+
+
+def test_the_new_cells_rehearsal_ends_in_a_result_line():
+    env = {**os.environ, "JAX_PLATFORMS": "cpu", "JAX_ENABLE_COMPILATION_CACHE": "0"}
+    done = subprocess.run(
+        [sys.executable, os.path.join(ROOT, "benchmark", "run.py"), "--workload", CELL, "--rehearse-cpu"],
+        capture_output=True, text=True, timeout=600, env=env, cwd=ROOT,
+    )
+    assert done.returncode == 0, done.stderr[-2000:]
+    result = json.loads(done.stdout.strip().splitlines()[-1])
+    assert result["rehearsal"] is True and result["correct"] is True, result
+    assert set(result["metrics"]) == {"train_samples_per_s", "round_s", "setup_s"}
+    assert {"routed_overflow", "step.loss_abs", "step.grad_rel", "window_compiles"} <= set(result["compared"])
+
+
+def test_scope_time_is_the_union_of_the_events_under_the_scope():
+    """reduce/scope_ops.py: an event finds its path by its program and its
+    instruction name in the compiled text; a while covers its body."""
+    from benchmark.reduce import scope_ops
+
+    text = "\n".join([
+        "HloModule jit_engine_train_step, entry_computation_layout={()->()}",
+        '  %while.1 = (s32[]) while(%t), condition=%c, body=%b, metadata={op_name="jit(engine_train_step)/encoder/layer_1/kda/kda/chunks/while"}',
+        '  %fusion.2 = f32[4] fusion(%x), kind=kLoop, metadata={op_name="jit(engine_train_step)/encoder/layer_1/kda/kda/chunks/while/body/mul"}',
+        '  ROOT %dot.3 = f32[4] dot(%x, %y), metadata={op_name="jit(engine_train_step)/encoder/layer_1/moe/moe/experts/dot_general"}',
+        "  %copy.4 = f32[4] copy(%x)",
+    ])
+    assert scope_ops.paths_by_program([text])["jit_engine_train_step"]["dot.3"].endswith("moe/experts/dot_general")
+    ctx = _cases.context(harness.load_json("configs", f"{KIMI}.json"), rehearsal=False)
+    ctx.trace_path = "unused"
+    names = ["%while.1 = (s32[]) while(...)", "%fusion.2 = f32[4] fusion(...)", "%dot.3 = f32[4] dot(...)", "%copy.4 = f32[4] copy(%x)"]
+    ops = (names, np.array([10.0, 20.0, 200.0, 300.0]), np.array([100.0, 30.0, 50.0, 10.0]))
+    modules = (["jit_engine_train_step(123)"], np.array([0.0]), np.array([1000.0]))
+    ctx.rec.data.update(
+        hlo_texts=[text],
+        xplane={"window": (0.0, 1000.0), "chips": [0], "busy_s": 190e-9, "trace": {"chips": {0: {"ops": ops, "modules": modules}}}},
+    )
+    table = scope_ops.of(ctx)
+    assert scope_ops.time_under(table, "kda") == 100.0  # the body's 30 ns lie inside the while's 100
+    assert scope_ops.time_under(table, "kda/chunks") == 100.0
+    assert scope_ops.time_under(table, "moe/experts") == 50.0 and scope_ops.time_under(table, "mla") == 0.0
+    from benchmark.readers import xplane_scope_share
+
+    assert xplane_scope_share.read(ctx, scope="moe") == pytest.approx(100.0 * 50.0 / 190.0)
+    # without the programs' texts (the parent's program, another driver) there is nothing to read
+    ctx.rec.data.pop("scope_ops"), ctx.rec.data.pop("hlo_texts")
+    assert xplane_scope_share.read(ctx, scope="moe") is None
