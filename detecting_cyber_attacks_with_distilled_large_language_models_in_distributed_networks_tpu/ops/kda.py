@@ -19,11 +19,24 @@ the state ``S`` (``G_t`` the running sum of ``g`` from the chunk's start):
     S' = Diag(e^{G_C}) S + (K * e^{G_C - G})^T U
 
 with ``A[t, s] = sum_c k_tc k_sc e^{G_tc - G_sc}`` and ``B`` the same with
-``q_t``. The triangular system is solved by substitution (``W`` and ``U0``
-below are its two right-hand sides, so the scan over chunks holds matrix
-products only), the states pass from chunk to chunk through ``lax.scan``, and
-the scan's body is checkpointed so that the backward pass keeps one state a
-chunk.
+``q_t``. What runs where: the pair matrices ``A`` and ``B`` and the
+triangular system, solved by substitution, are plain XLA (``W`` and ``U0``
+below are the system's two right-hand sides, so what passes from chunk to
+chunk is matrix products only):
+
+    U = U0_c - W_c S        O_c = Q_c S + B_c U        S <- decay_c * S + K_c^T U
+
+and that recurrence is one Pallas kernel a pass. ``kda_chunks_fwd`` walks the
+chunks of :data:`HEADS` heads in order with their states in VMEM (a state
+never goes through HBM between two chunks), reads the operands in place from
+``[B, H, N, C, .]`` (``W`` and ``U0`` as the two halves of the substitution's
+solution) and, where a gradient will be asked for, writes beside ``O`` the
+state each chunk STARTED from; ``kda_chunks_bwd`` walks the chunks backwards
+with the state's cotangent in VMEM, makes ``U`` again from that saved state
+and gives the operands' gradients; a ``jax.custom_vjp`` joins the two. The
+saved states (``[N, dk, dv]`` float32 a row and head) live inside one row's
+backward pass: the rows are computed one after another, each under
+``jax.checkpoint``. Off the TPU both kernels run in Pallas' interpreter.
 
 ``e^{G_t - G_s}`` cannot be split into ``e^{G_t}`` times ``e^{-G_s}`` over a
 whole chunk: a fast head forgets by ``e^{-100}`` in 64 tokens and the second
@@ -40,9 +53,14 @@ initialisation gives at most about 2).
 
 from __future__ import annotations
 
+import functools
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 #: Tokens a chunk: an implementation size, not the model's (any multiple of
 #: BLOCK gives the recurrence's result; 64 keeps the in-chunk matrices small
@@ -50,6 +68,9 @@ import numpy as np
 CHUNK = 64
 #: Rows and columns of the blocks the in-chunk matrices are built from.
 BLOCK = 16
+#: Heads a grid step of the recurrence's kernels: an implementation size too
+#: (the grid's fixed cost a step is shared by that many independent chains).
+HEADS = 8
 #: Largest exponent of a diagonal block's inverse decay (float32 holds e^88).
 MAX_BLOCK_DECAY = 80.0
 
@@ -117,7 +138,10 @@ def kda_chunked(q, k, v, g, beta, *, dtype=jnp.float32):
     the matrix products read (their sums, the decays and the state are
     float32). Any length: the tail is padded with tokens that write
     nothing (``beta = 0``, ``g = 0``). The batch's rows are computed one
-    after another. Returns float32 ``[B, H, L, dv]``."""
+    after another: a row's pair matrices and substitution in XLA, its
+    recurrence over chunks in the two kernels (interpreted off the TPU), the
+    chunks' starting states kept from the forward kernel for the reverse one
+    within that row's backward pass. Returns float32 ``[B, H, L, dv]``."""
     @jax.checkpoint
     def one_row(x):
         return _kda_chunked(*(a[None] for a in x), dtype)[0]
@@ -130,7 +154,7 @@ def kda_chunked(q, k, v, g, beta, *, dtype=jnp.float32):
 
 def _kda_chunked(q, k, v, g, beta, dtype):
     chunk = CHUNK
-    B, H, L, dk = q.shape
+    B, H, L, _ = q.shape
     dv = v.shape[-1]
     pad = -L % chunk
     if pad:
@@ -151,27 +175,151 @@ def _kda_chunked(q, k, v, g, beta, dtype):
     sol = jax.scipy.linalg.solve_triangular(
         b * A + jnp.eye(chunk, dtype=jnp.float32), rhs, lower=True, unit_diagonal=True
     )
-    W, U0 = sol[..., :dk], sol[..., dk:]
     G_end = G[..., -1:, :]
-    xs = (
-        W.astype(dtype), U0, (q * eG).astype(dtype), Bqk.astype(dtype),
-        (k * jnp.exp(G_end - G)).astype(dtype), jnp.exp(G_end[..., 0, :]),
+    O = _chunk_recurrence(
+        sol, (q * eG).astype(dtype), Bqk.astype(dtype), (k * jnp.exp(G_end - G)).astype(dtype), jnp.exp(G_end)
     )
-    xs = tuple(jnp.moveaxis(x, 2, 0) for x in xs)
+    return O.reshape(B, H, L + pad, dv)[:, :, :L]
 
-    @jax.checkpoint
-    def step(S, x):
-        W_c, U0_c, Q_c, B_c, K_c, decay = x
-        S_in = S.astype(dtype)
-        U = U0_c - jnp.einsum("bhtk,bhkv->bhtv", W_c, S_in, preferred_element_type=jnp.float32)
+
+# ------------------------------------------------- the recurrence over chunks
+# Both kernels work on the TRANSPOSED state ``St = S^T`` (``[dv, dk]``): the
+# decay then runs along the lanes and multiplies the state as the ``[1, dk]``
+# block it arrives as. ``W`` and ``U0`` are read as XLA has them, the two
+# halves of the substitution's solution ``[W | U0]`` (float32; ``W`` is
+# rounded to the products' type where it is read), and the reverse kernel
+# writes their gradients in the same form: no slice, cast or concatenation of
+# the solution stands beside a kernel.
+_NT = (((1,), (1,)), ((), ()))  # x @ y^T
+_TN = (((0,), (0,)), ((), ()))  # x^T @ y
+
+
+def _dot(x, y, dims=(((1,), (0,)), ((), ()))):
+    return jax.lax.dot_general(x, y, dims, preferred_element_type=jnp.float32)
+
+
+def _fwd_kernel(sol_ref, Q_ref, B_ref, K_ref, decay_ref, O_ref, *rest):
+    """One chunk of :data:`HEADS` heads: ``U = U0 - W S``, ``O = Q S + B U``,
+    ``S <- decay * S + K^T U``. Where a second result is asked for, the state
+    the chunk started from goes out as the reverse kernel's residual."""
+    *St0_refs, St_ref = rest  # the optional result, then the scratch
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        St_ref[...] = jnp.zeros_like(St_ref)
+
+    dtype, dk = Q_ref.dtype, Q_ref.shape[-1]
+    for h in range(Q_ref.shape[1]):
+        St = St_ref[h]
+        for St0_ref in St0_refs:
+            St0_ref[0, h, 0] = St
+        St_in = St.astype(dtype)
+        U = sol_ref[0, h, 0, :, dk:] - _dot(sol_ref[0, h, 0, :, :dk].astype(dtype), St_in, _NT)
         U_in = U.astype(dtype)
-        O = jnp.einsum("bhtk,bhkv->bhtv", Q_c, S_in, preferred_element_type=jnp.float32)
-        O = O + jnp.einsum("bhts,bhsv->bhtv", B_c, U_in, preferred_element_type=jnp.float32)
-        S = decay[..., None] * S + jnp.einsum(
-            "bhtk,bhtv->bhkv", K_c, U_in, preferred_element_type=jnp.float32
-        )
-        return S, O
+        O_ref[0, h, 0] = _dot(Q_ref[0, h, 0], St_in, _NT) + _dot(B_ref[0, h, 0], U_in)
+        St_ref[h] = decay_ref[0, h, 0] * St + _dot(U_in, K_ref[0, h, 0], _TN)
 
-    _, O = jax.lax.scan(step, jnp.zeros((B, H, dk, dv), jnp.float32), xs)
-    O = jnp.moveaxis(O, 0, 2).reshape(B, H, L + pad, dv)
-    return O[:, :, :L]
+
+def _bwd_kernel(
+    sol_ref, Q_ref, B_ref, K_ref, decay_ref, St0_ref, dO_ref,
+    dsol_ref, dQ_ref, dB_ref, dK_ref, ddecay_ref, dSt_ref,
+):
+    """The same chunk, walked from the last to the first: ``dSt_ref`` holds
+    the cotangent of the state AFTER the chunk; ``U`` is made again from the
+    saved starting state. Every product reads the forward's type, every sum
+    is float32."""
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        dSt_ref[...] = jnp.zeros_like(dSt_ref)
+
+    dtype, dk = Q_ref.dtype, Q_ref.shape[-1]
+    for h in range(Q_ref.shape[1]):
+        W, Q, K = sol_ref[0, h, 0, :, :dk].astype(dtype), Q_ref[0, h, 0], K_ref[0, h, 0]
+        St, dSt = St0_ref[0, h, 0], dSt_ref[h]
+        St_in, dSt_in, dO_in = St.astype(dtype), dSt.astype(dtype), dO_ref[0, h, 0].astype(dtype)
+        U_in = (sol_ref[0, h, 0, :, dk:] - _dot(W, St_in, _NT)).astype(dtype)
+        dU = _dot(B_ref[0, h, 0], dO_in, _TN) + _dot(K, dSt_in, _NT)
+        dU_in = dU.astype(dtype)
+        dsol_ref[0, h, 0, :, :dk] = -_dot(dU_in, St_in)
+        dsol_ref[0, h, 0, :, dk:] = dU
+        dQ_ref[0, h, 0] = _dot(dO_in, St_in).astype(dtype)
+        dB_ref[0, h, 0] = _dot(dO_in, U_in, _NT).astype(dtype)
+        dK_ref[0, h, 0] = _dot(U_in, dSt_in).astype(dtype)
+        ddecay_ref[0, h, 0] = (dSt * St).sum(0, keepdims=True)
+        dSt_ref[h] = decay_ref[0, h, 0] * dSt + _dot(dO_in, Q, _TN) - _dot(dU_in, W, _TN)
+
+
+@functools.partial(jax.jit, static_argnames=("kernel", "name", "results", "reverse", "interpret"))
+def _recurrence_call(kernel, name, operands, results, reverse, interpret):
+    """``pallas_call`` over ``(rows, heads / HEADS, chunks)``, the chunks last
+    and in order (backwards where ``reverse``). An operand or result is
+    ``[B, H, N, rows, width]`` and is read or written in place, one chunk of
+    :data:`HEADS` heads a grid step; ``results`` gives each result's
+    ``(rows, width, dtype)``. The transposed state is the one scratch. Jitted,
+    so that a step's sixteen launches of three kernels are traced and lowered
+    three times, not sixteen."""
+    B, H, N, _, dk = operands[1].shape
+    dv = operands[0].shape[-1] - dk
+    heads = math.gcd(H, HEADS)
+
+    def spec(rows, width):
+        return pl.BlockSpec((1, heads, 1, rows, width), lambda b, h, c: (b, h, N - 1 - c if reverse else c, 0, 0))
+
+    return pl.pallas_call(
+        kernel,
+        grid=(B, H // heads, N),
+        in_specs=[spec(*x.shape[-2:]) for x in operands],
+        out_specs=[spec(rows, width) for rows, width, _ in results],
+        out_shape=[jax.ShapeDtypeStruct((B, H, N, rows, width), t) for rows, width, t in results],
+        scratch_shapes=[pltpu.VMEM((heads, dv, dk), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+        name=name,
+    )(*operands)
+
+
+def _recurrence_fwd(sol, Q, Bqk, K, decay, states=True):
+    C, dk = Q.shape[-2:]
+    dv = sol.shape[-1] - dk
+    O, *St0 = _recurrence_call(
+        _fwd_kernel, "kda_chunks_fwd", (sol, Q, Bqk, K, decay),
+        ((C, dv, jnp.float32),) + ((dv, dk, jnp.float32),) * states,
+        reverse=False, interpret=jax.default_backend() != "tpu",
+    )
+    return O, (sol, Q, Bqk, K, decay, *St0)
+
+
+def _recurrence_bwd(res, dO):
+    sol, Q = res[:2]
+    C, dk = Q.shape[-2:]
+    return tuple(_recurrence_call(
+        _bwd_kernel, "kda_chunks_bwd", (*res, dO),
+        ((C, sol.shape[-1], jnp.float32), (C, dk, Q.dtype), (C, C, Q.dtype), (C, dk, Q.dtype), (1, dk, jnp.float32)),
+        reverse=True, interpret=jax.default_backend() != "tpu",
+    ))
+
+
+@jax.custom_vjp
+def _recurrence(sol, Q, Bqk, K, decay):
+    return _recurrence_fwd(sol, Q, Bqk, K, decay, states=False)[0]
+
+
+_recurrence.defvjp(_recurrence_fwd, _recurrence_bwd)
+
+
+def _chunk_recurrence(sol, Q, Bqk, K, decay):
+    """``O`` of the three lines above for every chunk in order, the state
+    starting at 0. ``sol``: ``[B, H, N, C, dk + dv]`` float32, the
+    substitution's ``[W | U0]``; ``Q``, ``K``: ``[B, H, N, C, dk]`` and ``Bqk``:
+    ``[B, H, N, C, C]`` in the products' type; ``decay``: ``[B, H, N, 1, dk]``
+    float32. On the TPU the head widths are padded with zeros to whole lanes
+    of 128 (a padded channel holds a state of 0 and adds 0 to every
+    product)."""
+    dk = Q.shape[-1]
+    dv = sol.shape[-1] - dk
+    pk, pv = (-dk % 128, -dv % 128) if jax.default_backend() == "tpu" else (0, 0)
+    if not (pk or pv):
+        return _recurrence(sol, Q, Bqk, K, decay)
+    wide = lambda x, p: jnp.pad(x, ((0, 0),) * 4 + ((0, p),))  # noqa: E731
+    sol = jnp.concatenate([wide(sol[..., :dk], pk), wide(sol[..., dk:], pv)], axis=-1)
+    return _recurrence(sol, wide(Q, pk), Bqk, wide(K, pk), wide(decay, pk))[..., :dv]

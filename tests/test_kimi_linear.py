@@ -4,6 +4,7 @@ plain forms, the whole program against the plain float32 reference
 share test of expert parallelism, and the engine's normal path on it. CPU,
 small sizes, seeded random weights."""
 
+import collections
 import dataclasses
 
 import numpy as np
@@ -38,6 +39,8 @@ from detecting_cyber_attacks_with_distilled_large_language_models_in_distributed
     causal_attention,
 )
 from detecting_cyber_attacks_with_distilled_large_language_models_in_distributed_networks_tpu.ops.kda import (
+    CHUNK,
+    _chunk_recurrence,
     kda_chunked,
     kda_recurrent,
 )
@@ -80,6 +83,19 @@ def _rel(a, b):
     return float(jnp.linalg.norm((a - b).ravel()) / (jnp.linalg.norm(b.ravel()) + 1e-30))
 
 
+def _eqns(jaxpr, path="", outer=()):
+    """Every equation of a jaxpr and of the jaxprs inside it, with the named
+    scopes down to it and the primitives it is nested in."""
+    for eqn in jaxpr.eqns:
+        here = f"{path}/{eqn.source_info.name_stack}"
+        yield here, outer, eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub, here, (*outer, eqn.primitive.name))
+
+
 # ------------------------------------------------------------------ the ops
 def _kda_inputs(L, seed=0, B=2, H=2, d=16, decay=2.0):
     rng = np.random.default_rng(seed)
@@ -92,12 +108,18 @@ def _kda_inputs(L, seed=0, B=2, H=2, d=16, decay=2.0):
     return q, k, v, g, beta
 
 
-@pytest.mark.parametrize("L", [128, 150, 47, 200])
-def test_chunked_kda_is_the_token_recurrence(L):
-    """Outputs and gradients over one to four chunks, lengths that are no
-    multiple of the chunk, and a decay (e^-100 a chunk) that a whole-chunk
-    factorisation would overflow on."""
-    x = _kda_inputs(L)
+@pytest.mark.parametrize(
+    "L, decay",
+    [(128, 2.0), (150, 2.0), (47, 2.0), (200, 2.0), (64, 2.0), (100, 2.0), (160, 2.0), (256, 2.0), (160, 3.0)],
+    ids=["L128", "L150", "L47", "L200", "one-chunk", "padded-tail", "L160", "L256", "forgets-within-a-chunk"],
+)
+def test_chunked_kda_is_the_token_recurrence(L, decay):
+    """Outputs and all five gradients through the recurrence's kernels (under
+    the interpreter here) over one to four chunks, lengths that are no
+    multiple of the chunk, a decay (e^-100 a chunk) that a whole-chunk
+    factorisation would overflow on, and one (e^-150) under which a chunk's
+    last tokens see nothing of the state it started from."""
+    x = _kda_inputs(L, decay=decay)
     want = kda_recurrent(*x)
     got = kda_chunked(*x)
     assert np.isfinite(np.asarray(got)).all()
@@ -107,6 +129,64 @@ def test_chunked_kda_is_the_token_recurrence(L):
     g_got = jax.grad(loss(kda_chunked), argnums=(0, 1, 2, 3, 4))(*x)
     for a, b in zip(g_got, g_want):
         assert _rel(a, b) < 1e-5
+
+
+def test_chunked_kda_in_bf16_is_near_its_float32():
+    """The products read bfloat16, the sums, the decays and the state stay
+    float32: outputs and gradients lie within bfloat16's rounding of the
+    float32 ones, not at it (something was rounded) and not far from it
+    (0.35% the outputs, 0.5% the gradients and 3.5% the log-decay's, whose
+    terms cancel; the scan over chunks these kernels replaced read the same)."""
+    x = _kda_inputs(160)
+    loss = lambda dtype: (lambda *a: (kda_chunked(*a, dtype=dtype) ** 2).sum())  # noqa: E731
+    err = _rel(kda_chunked(*x, dtype=jnp.bfloat16), kda_chunked(*x))
+    assert 1e-4 < err < 2e-2, err
+    for a, b, limit in zip(
+        jax.grad(loss(jnp.bfloat16), argnums=(0, 1, 2, 3, 4))(*x), jax.grad(loss(jnp.float32), argnums=(0, 1, 2, 3, 4))(*x),
+        (1.5e-2, 1.5e-2, 1.5e-2, 6e-2, 1.5e-2),
+    ):
+        assert 1e-4 < _rel(a, b) < limit, _rel(a, b)
+
+
+def _plain_recurrence(W, U0, Q, Bqk, K, decay):
+    """The three lines the kernels compute, as a ``lax.scan`` over chunks: the
+    kernels' reference (``ops/kda.py`` held this scan until the kernels took
+    its place). Every operand ``[B, H, N, ...]``, ``decay`` ``[B, H, N, 1, dk]``."""
+    def step(S, x):
+        W_c, U0_c, Q_c, B_c, K_c, decay_c = x
+        U = U0_c - jnp.einsum("bhtk,bhkv->bhtv", W_c, S)
+        O = jnp.einsum("bhtk,bhkv->bhtv", Q_c, S) + jnp.einsum("bhts,bhsv->bhtv", B_c, U)
+        return decay_c[..., 0, :, None] * S + jnp.einsum("bhtk,bhtv->bhkv", K_c, U), O
+
+    B, H, _, _, dk = W.shape
+    xs = tuple(jnp.moveaxis(x, 2, 0) for x in (W, U0, Q, Bqk, K, decay))
+    _, O = jax.lax.scan(step, jnp.zeros((B, H, dk, U0.shape[-1]), jnp.float32), xs)
+    return jnp.moveaxis(O, 0, 2)
+
+
+@pytest.mark.parametrize("H", [3, 16], ids=["a-head-a-step", "two-steps-of-8-heads"])
+def test_the_backward_kernel_is_the_gradient_of_the_plain_scan(H):
+    """``dW``, ``dU0``, ``dQ``, ``dB``, ``dK`` and ``ddecay`` of the reverse
+    kernel against ``jax.grad`` of the scan, on operands of the kernel's own
+    (three chunks; keys wider than values), for a count of heads that
+    :data:`HEADS` does not divide and one that fills two grid steps. The
+    kernels take ``W`` and ``U0`` as the one array ``[W | U0]`` the
+    substitution leaves and give their gradients in that form."""
+    rng = np.random.default_rng(7)
+    B, N, C, dk, dv = 2, 3, CHUNK, 16, 8
+    normal = lambda *shape: jnp.asarray(rng.normal(size=(B, H, N, *shape)) * 0.3, jnp.float32)  # noqa: E731
+    x = (
+        normal(C, dk), normal(C, dv), normal(C, dk), normal(C, C), normal(C, dk),
+        jnp.asarray(rng.uniform(0.2, 1.0, size=(B, H, N, 1, dk)), jnp.float32),
+    )
+    cot = normal(C, dv)
+    kernels = lambda W, U0, *rest: _chunk_recurrence(jnp.concatenate([W, U0], -1), *rest)  # noqa: E731
+    assert _rel(kernels(*x), _plain_recurrence(*x)) < 1e-6
+    loss = lambda fn: (lambda *a: (fn(*a) * cot).sum())  # noqa: E731
+    got = jax.grad(loss(kernels), argnums=tuple(range(6)))(*x)
+    want = jax.grad(loss(_plain_recurrence), argnums=tuple(range(6)))(*x)
+    for name, a, b in zip(("dW", "dU0", "dQ", "dB", "dK", "ddecay"), got, want):
+        assert a.shape == b.shape and _rel(a, b) < 1e-5, (name, _rel(a, b))
 
 
 def test_kda_padding_after_the_real_tokens_changes_no_real_state():
@@ -299,21 +379,12 @@ def test_the_recomputation_keeps_the_routers_choice(tiny_params):
         jax.grad(lambda p: loss_fn(build_classifier(cfg), p, batch, jax.random.key(0)))
     )(tiny_params)
 
-    def top_ks(jaxpr, recomputed: bool) -> tuple[int, int]:
-        inside = outside = 0
-        for eqn in jaxpr.eqns:
-            if eqn.primitive.name == "top_k":
-                inside, outside = inside + recomputed, outside + (not recomputed)
-            for value in eqn.params.values():
-                for sub in value if isinstance(value, (list, tuple)) else [value]:
-                    sub = getattr(sub, "jaxpr", sub)
-                    if hasattr(sub, "eqns"):
-                        a, b = top_ks(sub, recomputed or eqn.primitive.name == "remat2")
-                        inside, outside = inside + a, outside + b
-        return inside, outside
+    top_ks = [
+        "remat2" in outer for _, outer, eqn in _eqns(jaxpr.jaxpr) if eqn.primitive.name == "top_k"
+    ]  # inside a recomputation?
 
     n_moe = sum(cfg.is_moe(i) for i in range(cfg.n_layers))
-    assert top_ks(jaxpr.jaxpr, False) == (0, n_moe)
+    assert top_ks == [False] * n_moe
 
 
 # --------------------------------------------------- the engine's normal path
@@ -339,6 +410,30 @@ def test_trainer_fit_evaluate_and_checkpoint_round_trip(tmp_path, tiny_params):
     for a, b in zip(jax.tree.leaves(back.params), jax.tree.leaves(state.params)):
         assert np.array_equal(np.asarray(a), np.asarray(b))
     assert int(back.step) == 6
+
+
+def test_the_train_step_runs_the_recurrence_in_its_two_kernels(tiny_params):
+    """Did the mechanism engage: ``engine.train_step`` for the tiny
+    configuration (two chunks a row, four rows) holds, under every KDA layer's
+    scope ``kda/chunks``, the forward kernel (once as run and once a
+    recomputation: per layer and per row) and the reverse one, by their names;
+    and the only loop left under that scope is the map over the batch's rows:
+    none over the chunks."""
+    cfg = TINY.replace(max_len=2 * CHUNK, remat=True)
+    ids, mask = _rows(cfg, [128, 100, 80, 70])
+    batch = {"input_ids": ids, "attention_mask": mask, "labels": np.array([0, 1, 0, 1], np.int32)}
+    trainer = Trainer(cfg, TrainConfig(log_every=0), pad_id=0)
+    state = trainer.init_state(seed=0, params=jax.tree.map(jnp.copy, tiny_params))
+    jaxpr = jax.make_jaxpr(trainer.train_step.__wrapped__)(state, batch)
+
+    eqns = [(path, eqn) for path, _, eqn in _eqns(jaxpr.jaxpr) if "kda/chunks" in path]
+    kernels = collections.Counter(eqn.params["name"] for _, eqn in eqns if eqn.primitive.name == "pallas_call")
+    kda_layers = [i for i in range(cfg.n_layers) if cfg.mixer(i) == "kda"]
+    assert kda_layers and kernels == {"kda_chunks_fwd": 3 * len(kda_layers), "kda_chunks_bwd": len(kda_layers)}, kernels
+    for layer in kda_layers:
+        assert any(f"layer_{layer}/kda/kda/chunks" in path for path, eqn in eqns if eqn.primitive.name == "pallas_call")
+    loops = [eqn.params.get("length") for _, eqn in eqns if eqn.primitive.name in ("scan", "while")]
+    assert loops and set(loops) == {len(ids)}, loops  # the rows' lax.map, forward and transposed
 
 
 def test_overflow_is_counted_and_said_loudly_by_fit_and_by_evaluate(monkeypatch):

@@ -1,0 +1,46 @@
+"""The Pallas kernels of a benchmark cell's path, compiled at the cell's
+widths for the chip the cells run on, without the chip: the installed TPU
+compiler refuses here what it would refuse there (a block that does not fit
+the tiling, more VMEM than a kernel may use). It compiles, it does not run:
+nothing here is a result or a speed. All such compiles live in this one
+file: the process that describes the topology holds the TPU's library."""
+
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from detecting_cyber_attacks_with_distilled_large_language_models_in_distributed_networks_tpu.ops import kda
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - whatever keeps the compiler from describing a chip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["kda_chunks_fwd", "kda_chunks_bwd"])
+def test_the_kda_recurrence_compiles_for_the_v5e_at_the_cells_widths(one_chip, monkeypatch, grad):
+    """One row of ``kimilinear-window-fit-l4k``: 32 heads of 128, 64 chunks of
+    64 tokens, bfloat16 products. The wrapper asks the backend whether to
+    interpret; the test answers for the chip it compiles for."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    B, H, N, C, d = 1, 32, 64, kda.CHUNK, 128
+    arg = lambda rows, width, dtype: jax.ShapeDtypeStruct((B, H, N, rows, width), dtype, sharding=one_chip)  # noqa: E731
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    args = (arg(C, 2 * d, f32), arg(C, d, bf16), arg(C, C, bf16), arg(C, d, bf16), arg(1, d, f32))
+    fn = kda._chunk_recurrence
+    if grad:
+        fn = jax.grad(lambda *a: kda._chunk_recurrence(*a).sum(), argnums=tuple(range(5)))
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == (2 if grad else 1)
+    assert "kda_chunks_bwd" in text if grad else "kda_chunks_fwd" in text
