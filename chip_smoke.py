@@ -230,7 +230,7 @@ class Smoke:
 
     def phase_fence(self) -> dict:
         """§2: one bf16 matmul chain of known FLOPs, timed to
-        ``jax.block_until_ready`` and to bench.py's scalar read-back."""
+        ``jax.block_until_ready`` and to a host read-back of one scalar."""
         import jax
         import jax.numpy as jnp
         import numpy as np

@@ -23,8 +23,7 @@ Layout:
 * :mod:`analysis.thread_rules` — concurrency pass (cross-thread
   attribute writes must be lock-guarded or pragma'd).
 * :mod:`analysis.obs_rules` — obs-vocabulary pass (span names ⊆
-  SPAN_NAMES, consistent metric registration, bench headline fields
-  actually produced).
+  SPAN_NAMES, consistent metric registration).
 * :mod:`analysis.lockorder` — runtime lock-order detector (a
   ``threading.Lock``/``RLock`` wrapper building a per-creation-site
   acquisition graph; cycles = deadlock risk).

@@ -68,7 +68,7 @@ SCOPE = (
     # Sharded scorer (ISSUE 20): the serving engine's bucket programs
     # and shard layout sit inside the crc contract too — a sharded
     # replica must replay the replicated engine's probs bit-for-bit
-    # (bench's serve_fsdp_crc_exact), which any nondeterministic
+    # (tests/test_serving_fsdp.py), which any nondeterministic
     # bucketing/padding/placement choice here would break.
     "serving/engine.py",
 )

@@ -1,7 +1,7 @@
 """Obs-vocabulary pass: the observability contracts stay closed.
 
-Three cross-cutting vocabularies hold the obs layer together, and all
-three are string-matched at runtime with no compiler in the loop:
+Two cross-cutting vocabularies hold the obs layer together, and both
+are string-matched at runtime with no compiler in the loop:
 
 ``obs-span-vocab``
     Every span name emitted through a ``Tracer`` (``tracer.span(...)``,
@@ -21,14 +21,6 @@ three are string-matched at runtime with no compiler in the loop:
     exactly one module (two tiers independently minting the same name
     will drift in help text and labels; share it from one place
     instead).
-
-``bench-headline``
-    Every headline field bench.py ASSERTS present (the
-    ``[k for k in (...) if k not in rec]`` exit-3 pattern) must be
-    produced somewhere (a dict-literal key or ``rec[...] =`` store in
-    bench.py or the package). An asserted-but-never-produced field
-    means the bench exits 3 on every run — this catches the rename
-    half-done before the driver does.
 """
 
 from __future__ import annotations
@@ -39,7 +31,6 @@ from typing import Iterator
 from .core import Finding, Project, call_name, register, str_const
 
 TRACE_REL = "obs/trace.py"
-BENCH_REL = "bench.py"
 
 _METRIC_KINDS = ("counter", "gauge", "histogram")
 
@@ -180,77 +171,4 @@ def check_metric_once(project: Project) -> Iterator[Finding]:
                 f"({', '.join(mods)}) — help text and labels will drift; "
                 "register it in one place and share the reference",
             )
-
-
-@register(
-    "bench-headline",
-    "every headline field bench.py asserts present is actually "
-    "produced by a record builder",
-)
-def check_bench_headline(project: Project) -> Iterator[Finding]:
-    bench = project.module(BENCH_REL)
-    if bench is None or bench.tree is None:
-        return
-    # Asserted: string constants S appearing in an `S not in X` compare
-    # (the exit-3 missing-fields pattern) anywhere in bench.py, plus the
-    # comprehension form where the iterated tuple holds the candidates.
-    asserted: dict[str, int] = {}
-    for node in bench.walk():
-        if isinstance(node, ast.Compare) and len(node.ops) == 1 and isinstance(
-            node.ops[0], ast.NotIn
-        ):
-            s = str_const(node.left)
-            if s is not None:
-                asserted.setdefault(s, node.lineno)
-        elif isinstance(
-            node, (ast.ListComp, ast.SetComp, ast.GeneratorExp)
-        ):
-            # The `[k for k in (...) if k not in rec]` assert shape: a
-            # comprehension over a literal tuple whose filter is NotIn.
-            for gen in node.generators:
-                if isinstance(gen.iter, (ast.Tuple, ast.List)) and any(
-                    isinstance(cond, ast.Compare)
-                    and len(cond.ops) == 1
-                    and isinstance(cond.ops[0], ast.NotIn)
-                    for cond in gen.ifs
-                ):
-                    for elt in gen.iter.elts:
-                        v = str_const(elt)
-                        if v is not None:
-                            asserted.setdefault(v, elt.lineno)
-    if not asserted:
-        return
-    # Produced: dict-literal keys and `X["k"] = ...` stores, bench.py +
-    # package wide (records cross the module boundary via stats()/
-    # timeline dicts).
-    produced: set[str] = set()
-    for m in project.modules:
-        for node in m.walk():
-            if isinstance(node, ast.Dict):
-                for k in node.keys:
-                    v = str_const(k) if k is not None else None
-                    if v is not None:
-                        produced.add(v)
-            elif isinstance(node, (ast.Assign, ast.AugAssign)):
-                targets = (
-                    node.targets
-                    if isinstance(node, ast.Assign)
-                    else [node.target]
-                )
-                for t in targets:
-                    if isinstance(t, ast.Subscript):
-                        v = str_const(t.slice)
-                        if v is not None:
-                            produced.add(v)
-    for name, line in sorted(asserted.items()):
-        if name not in produced:
-            yield Finding(
-                "bench-headline",
-                bench.rel,
-                line,
-                f"bench.py asserts headline field {name!r} but nothing "
-                "in bench.py or the package produces it — every run "
-                "would exit 3 (half-done rename?)",
-            )
-
 

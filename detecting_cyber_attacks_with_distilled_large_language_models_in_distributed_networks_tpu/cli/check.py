@@ -1,15 +1,14 @@
 """fedtpu check — run the invariant-aware static-analysis passes.
 
     fedtpu check                      # scan the repo, human-readable
-    fedtpu check --json               # machine-readable (bench/CI)
+    fedtpu check --json               # machine-readable (CI)
     fedtpu check --rules determinism,unguarded
     fedtpu check --baseline ANALYSIS_BASELINE.json
     fedtpu check --list-rules
 
 Exit codes: 0 = clean (pragma'd/baselined findings allowed), 1 = at
 least one NON-baselined finding, 2 = usage/internal error. The tier-1
-verify recipe runs this next to the fast lane; bench.py's ``check``
-record asserts ``check_findings_new == 0`` (exit 3 on regression).
+lane runs it on the shipping tree (tests/test_analysis.py's self-scan).
 
 Suppression is always reviewed: a per-line
 ``# fedtpu: allow(<rule>): reason`` pragma at the site, or an entry

@@ -141,7 +141,7 @@ def _add_common(p: argparse.ArgumentParser) -> None:
     p.add_argument(
         "--gelu",
         choices=["exact", "tanh"],
-        help="FFN activation: tanh (default, ~20%% faster on TPU, within a "
+        help="FFN activation: tanh (default, cheaper on TPU, within a "
         "few bf16 ulps of erf) or exact (HF's erf form, fp32 parity)",
     )
     p.add_argument(
@@ -1014,8 +1014,8 @@ def build_parser() -> argparse.ArgumentParser:
         "On a promotion the fleet manager drains one replica at a time "
         "(router pick-set removal -> in-flight wait -> hot-swap -> "
         "readmit), so the serving pointer moves under load without "
-        "dropping a request — the bench pins "
-        "router_rolling_reload_dropped == 0.",
+        "dropping a request (tests/test_router.py pins zero rejects "
+        "across a reload).",
     )
     _add_common(p)  # model/tokenizer/dataset resolution flags
     p.add_argument("--host", default="0.0.0.0")

@@ -16,7 +16,7 @@ Payload layout for a tensor of ``n`` elements::
 Each chunk's scale is ``max|chunk| / 127``; values quantize as
 ``clip(rint(x / scale), -127, 127)``. Overhead is one fp32 per 4096
 elements (~0.1%), so the wire cost is ~4x below fp32 — the
-``--wire-dtype int8`` arm of the wire-efficiency bench.
+``--wire-dtype int8`` arm tests/test_wire_efficiency.py pins.
 
 Determinism contract (this module is in the ``fedtpu check``
 determinism-pass SCOPE): both directions are pure elementwise numpy on

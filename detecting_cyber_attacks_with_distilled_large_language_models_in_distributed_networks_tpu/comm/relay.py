@@ -73,7 +73,7 @@ def aggregate_tree(
     weighted barrier mean, then the barrier mean of the partials
     weighted by each group's weight mass — exactly the fp32 ops, in
     exactly the order, the relay tier performs. The A/B harnesses
-    (tests/test_fleet.py, bench.py fleet) pin the live depth-2 root
+    (tests/test_fleet.py, tests/test_scenario.py) pin the live depth-2 root
     aggregate against this crc-bit-exactly.
 
     ``groups`` may nest to ANY depth: an element that is itself a list
@@ -207,7 +207,7 @@ class RelayAggregator:
             float(upward_topk) if upward_topk is not None else None
         )
         #: Cumulative parent-facing upload payload bytes (the
-        #: ``relay_upward_bytes`` bench headline / /metrics counter):
+        #: ``fedtpu_relay_upward_bytes_total`` /metrics counter):
         #: what the sparse upward tier exists to shrink.
         self.upward_bytes = 0
         from ..obs import metrics as _obs_metrics

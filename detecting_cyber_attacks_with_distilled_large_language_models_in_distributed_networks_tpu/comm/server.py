@@ -463,15 +463,15 @@ class AggregationServer:
         # phase accounting. phase_seconds accumulates where each round's
         # wall went — wait (accept + straggler + upload wire), agg
         # (aggregation compute), reply (fan-out) — the measured comm/
-        # compute breakdown bench.py's comm_phase_* headline fields and
-        # the /metrics endpoint report. last_trace is the most recent
+        # compute breakdown the timeline tool and the /metrics endpoint
+        # report (tests/test_obs.py). last_trace is the most recent
         # round's (trace id, round index) for callers (the controller)
         # that stamp their own follow-on spans with the round's identity.
         # Streamed uploads + streaming chunk aggregation (PR 5): the
         # preferred chunk size advertised in every reply's meta (wire.py
         # STREAM_META_KEY — plain meta, old clients interop unchanged).
         # 0 disables BOTH the advert and eager folding: every round then
-        # runs the stop-the-world barrier shape (the bench's A/B arm).
+        # runs the stop-the-world barrier shape (test_stream.py's A/B arm).
         # Secure-agg rounds never advertise: a masked upload's unmask
         # protocol needs the full contributor set resolved before any
         # aggregate exists, so those stay single-frame by design.
@@ -488,8 +488,8 @@ class AggregationServer:
         self.stream_chunk_bytes = int(stream_chunk_bytes)
         # Cross-round streaming totals: bytes folded during the wait
         # phase (overlapped with the wire) vs after it, and the peak
-        # aggregation-state footprint — the comm_overlap_frac /
-        # server_peak_agg_bytes bench headline fields.
+        # aggregation-state footprint — what comm_overlap_frac() and the
+        # wire-overlap span's peak_agg_bytes report.
         # One lock for every stream_totals mutation: upload handlers on
         # the pool increment fallback/upload counters while serve_round
         # folds reply/peak stats — per-key dict ops are GIL-atomic, but
@@ -617,7 +617,7 @@ class AggregationServer:
             "of stalling its parent)",
         )
         # Plain attribute twins for harnesses that hold the server object
-        # (bench chaos arm, tests): mutated under _totals_lock like
+        # (the scenario harness, tests): mutated under _totals_lock like
         # stream_totals.
         self.tree_totals = {
             "subtree_failures": 0,
@@ -3300,8 +3300,8 @@ class AggregationServer:
                 # in-flight) in the cross-round max.
                 tot["last_round_peak_bytes"] = s["peak_bytes"]
                 # Compiled-fold telemetry (ops/fold.py): which engine
-                # folded and at what throughput — the bench's
-                # fold_throughput_gbps headline source.
+                # folded and at what throughput — the source of the
+                # fedtpu_server_fold_throughput_gbps gauge.
                 tot["fold_engine"] = s["fold_engine"]
                 tot["last_fold_throughput_gbps"] = s[
                     "fold_throughput_gbps"
@@ -3497,7 +3497,7 @@ class AggregationServer:
     def comm_overlap_frac(self) -> float:
         """Bytes-weighted fraction of this server's aggregation input
         folded while the round's wire phase was still active (0.0 on a
-        pure barrier run) — the bench's ``comm_overlap_frac`` headline."""
+        pure barrier run); tests/test_stream.py holds it above 0 streamed."""
         with self._totals_lock:
             early = self.stream_totals["early_bytes"]
             tot = early + self.stream_totals["late_bytes"]

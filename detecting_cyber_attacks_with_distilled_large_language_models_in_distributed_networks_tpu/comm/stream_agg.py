@@ -67,7 +67,7 @@ class StreamAgg:
 
     ``eager=False`` disables freezing/folding entirely: every upload is
     held and ``finalize`` computes the barrier mean at close. That is the
-    non-pipelined A/B arm the bench compares against.
+    non-pipelined A/B arm tests/test_stream.py compares against.
     """
 
     def __init__(
@@ -102,7 +102,7 @@ class StreamAgg:
         #: unconditionally (even on the poisoned path) so a dropped
         #: client can never leak into the strategy's view of the round.
         self._strategy_stats: dict[int, dict[str, float]] = {}
-        # accounting (the obs layer's wire-overlap span + bench headline)
+        # accounting (the obs layer's wire-overlap span)
         self._cur_bytes = 0
         self.peak_bytes = 0
         self.early_bytes = 0

@@ -57,7 +57,7 @@ class ModelConfig:
     # Compute Q/K/V with ONE [D, 3D] matmul over kernels concatenated at
     # apply time (the parameter tree keeps the separate q/k/v layout, so
     # checkpoints and HF conversion are unaffected). Same math, fewer
-    # larger MXU dispatches; measured via BENCH_FUSED_QKV.
+    # larger MXU dispatches. No cell turns it on: not measured on the chip.
     fused_qkv: bool = False
     # Mesh axis the sequence dimension is sharded over when attention_impl
     # is "ring" (the forward must run inside shard_map with this axis bound).
@@ -348,8 +348,8 @@ class TrainConfig:
     # (per-epoch averages only).
     log_every: int = 100
     # Dropout-key PRNG implementation. "rbg" (counter-based, the standard
-    # TPU choice for dropout masks) is ~10 points of MFU cheaper than
-    # "threefry2x32" on the flagship model; both are valid JAX key impls.
+    # TPU choice for dropout masks) is cheaper on the chip than
+    # "threefry2x32" (no cell runs the latter); both are valid JAX key impls.
     prng_impl: str = "rbg"
     # Which parameters the optimizer updates. "all" (default) is normal
     # training; "head" freezes the encoder and trains only the classifier

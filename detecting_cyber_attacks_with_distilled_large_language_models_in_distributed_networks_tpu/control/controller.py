@@ -138,7 +138,7 @@ class ControllerStats:
     label_rejections: int = 0
     drift_triggers: int = 0
     #: round-engine wall seconds (inside serve_round) vs full cycle wall:
-    #: the orchestration overhead the bench record reports.
+    #: the orchestration overhead is what the second adds to the first.
     round_wall_s: float = 0.0
     cycle_wall_s: float = 0.0
     promotion_latency_s: list = field(default_factory=list)

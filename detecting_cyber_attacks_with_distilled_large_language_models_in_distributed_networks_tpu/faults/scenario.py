@@ -16,8 +16,8 @@ mixes).
 Two payload modes:
 
 * synthetic (default) — deterministic model-shaped fp32 trees per
-  (client, round); fast enough for the fast test lane and the bench
-  record. Partition still matters: the server runs weighted FedAvg and
+  (client, round); fast enough for the fast test lane.
+  Partition still matters: the server runs weighted FedAvg and
   each client's weight is its shard size, so quantity/label skew
   changes the mean.
 * ``train=True`` — a tiny real model trains on the partitioned
@@ -530,7 +530,7 @@ def run_cell(
             result.accuracy = round(float(m["Accuracy"]), 4)
             # Comparator surface: the final aggregate's held-out
             # accuracy, labeled by cell and strategy — what the
-            # strategy sweep (and BENCH_MODE=strategy) scrapes to pin
+            # strategy sweep (tests/test_strategies.py) reads to pin
             # the non-IID lift over the fedavg baseline cells.
             from ..obs import metrics as obs_metrics
 

@@ -198,8 +198,8 @@ class TransformerBlock(nn.Module):
             name="lin1",
         )(x)
         # cfg.gelu: "exact" = HF's erf GELU (fp32 parity); "tanh" = the
-        # tanh form, within a few bf16 ulps of erf and ~20% faster per
-        # step on TPU v5e (config.py ModelConfig.gelu).
+        # tanh form, within a few bf16 ulps of erf and cheaper on the
+        # chip; every BERT cell runs erf (config.py ModelConfig.gelu).
         h = jax.nn.gelu(h, approximate=(cfg.gelu == "tanh"))
         h = nn.Dense(
             cfg.dim,

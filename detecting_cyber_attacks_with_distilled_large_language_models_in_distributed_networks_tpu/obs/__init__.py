@@ -26,7 +26,7 @@ Seven pieces (see each module's docstring):
   the program ``jit_<site>`` so a trace's modules and ops carry the
   site, and every launch is a ``dispatch/<site>`` annotation), strided fenced step-time
   attribution, device-memory watermarks, and the analytic-vs-XLA FLOPs
-  cross-check behind ``fedtpu obs profile`` / ``BENCH_MODE=profile``.
+  cross-check behind ``fedtpu obs profile``.
 * :mod:`.sentinel` — the sentinel watch daemon behind ``fedtpu obs
   sentinel``: known-truth canary probes through the live serving chain,
   continuous journal-tailing supervised drift between gates, and a

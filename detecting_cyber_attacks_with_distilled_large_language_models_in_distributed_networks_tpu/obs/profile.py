@@ -36,8 +36,8 @@ This module is the device-side judgment layer, four pieces:
   analytic ``train_step_flops`` behind the MFU headline can be pinned
   against what XLA actually built (:data:`FLOPS_RATIO_TOLERANCE`).
 
-``run_profile_session`` drives all four end-to-end (the single
-implementation behind ``fedtpu obs profile`` and ``BENCH_MODE=profile``).
+``run_profile_session`` drives all four end-to-end (the
+implementation behind ``fedtpu obs profile``).
 """
 
 from __future__ import annotations
@@ -49,7 +49,7 @@ from typing import Any, Callable, Iterator, Mapping
 from .metrics import MetricsRegistry, default_registry
 from .trace import annotate
 
-#: XLA-vs-analytic FLOPs ratio bounds the bench pins (documented in the
+#: XLA-vs-analytic FLOPs ratio bounds tests/test_profile.py pins (see the
 #: README "Device profiling" section). XLA's cost model counts the same
 #: 2·M·N·K per matmul the analytic model does, but additionally counts
 #: elementwise/softmax/optimizer FLOPs the analytic model deliberately
@@ -731,8 +731,8 @@ def run_profile_session(
     ``steps`` real engine steps with the step profiler armed, snapshot
     memory at the phase boundaries, cross-check analytic vs XLA FLOPs,
     and storm the bucketed serving path asserting zero recompiles.
-    The single implementation behind ``fedtpu obs profile`` and
-    ``BENCH_MODE=profile``; ``capture_dir`` wraps ``jax.profiler``
+    The implementation behind ``fedtpu obs profile``;
+    ``capture_dir`` wraps ``jax.profiler``
     around the profiled steps (utils/profiling.trace)."""
     import jax
     import numpy as np
@@ -787,8 +787,8 @@ def run_profile_session(
     # Warm ONLY the site this session just exercised: a blanket
     # mark_warm would freeze sibling sites with zero or partial
     # signature sets and misflag their next legitimate first compile
-    # (e.g. the headline bench tracing a different batch size right
-    # after BENCH_MODE=profile) as a shape leak.
+    # (e.g. a caller tracing a different batch size right after this
+    # session) as a shape leak.
     ledger.mark_warm("engine.train_step")
 
     with trace(capture_dir):

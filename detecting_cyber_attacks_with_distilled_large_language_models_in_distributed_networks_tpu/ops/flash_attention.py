@@ -50,9 +50,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-# Measured sweet spot on TPU v5e (B=8, H=12, D=64, L=2048): (256, 512) runs
-# 2.3x faster than (128, 128) — bigger K blocks amortize the per-matmul MXU
-# ramp — and overtakes XLA's fused dot attention from L~2048. Shorter
+# Block sizes chosen before PR 21 on an installation that no longer exists
+# (B=8, H=12, D=64, L=2048): bigger K blocks amortize the per-matmul MXU
+# ramp. No cell runs this kernel, so the ledger has no figure for it. Shorter
 # sequences clamp to L automatically.
 DEFAULT_BLOCK_Q = 256
 DEFAULT_BLOCK_K = 512

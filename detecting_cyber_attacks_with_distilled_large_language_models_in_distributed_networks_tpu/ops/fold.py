@@ -26,7 +26,7 @@ to ship K leaves to the chip.
 
 Engine choice: ``FEDTPU_FOLD_ENGINE=naive|blocked`` overrides; otherwise
 ``blocked``. The choice is made once per process and is observable
-(``engine_name``) so the wire-overlap span and bench record can name
+(``engine_name``) so the wire-overlap span can name
 what folded.
 
 Determinism contract (``fedtpu check`` SCOPE): every engine is a pure

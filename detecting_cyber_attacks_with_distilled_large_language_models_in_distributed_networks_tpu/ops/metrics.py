@@ -14,7 +14,7 @@ truth, cols = prediction) instead of four scalars, and
 per-class support. K = 2 is NOT a parallel implementation — it routes
 through the binary kernels verbatim, so the multi-class path is
 bit-identical to the binary one on the same inputs (the crc contract
-bench.py's labels arm pins).
+tests/test_labels.py pins).
 """
 
 from __future__ import annotations

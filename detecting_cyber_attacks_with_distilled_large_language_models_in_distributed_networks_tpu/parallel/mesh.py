@@ -242,7 +242,7 @@ def fsdp_gather_program(tree, mesh: Mesh, *, note=None):
     replicated inputs — a data-dependent 1-ulp drift, with zero
     all-reduces or partitioned contractions in sight. The serving crc
     contract (sharded probs bit-identical to the replicated engine's,
-    bench ``serve_fsdp_crc_exact``) needs the CONSUMER program compiled
+    tests/test_serving_fsdp.py) needs the CONSUMER program compiled
     clean; splitting the gather out gives it byte-exact replicated
     inputs and an HLO module free of collectives. Gather-at-use
     semantics are unchanged — the program runs per dispatch and its
@@ -284,7 +284,7 @@ def fsdp_constrain(mesh: Mesh, *, axis: str = "data"):
 def device_tree_bytes(tree) -> int:
     """Bytes ``tree``'s leaves occupy on ONE device (per leaf: the
     lowest-id device holding a shard of it) — the per-chip static-state
-    accounting behind the FSDP bench's ``fsdp_peak_param_opt_bytes_ratio``.
+    accounting behind the ``fedtpu_fsdp_static_state_bytes`` gauge.
     Exact (addressable-shard nbytes, not an estimate) and backend-
     independent: it works on CPU virtual devices where
     ``device.memory_stats()`` is unavailable. A replicated leaf counts

@@ -204,8 +204,8 @@ def blockwise_attention_local(
     online-softmax recurrence, ppermute hops removed. Numerically it is
     ``ring_attention`` on an ``n_chunks``-device mesh (the recurrence and
     chunk order are identical; only the transport differs), so it serves
-    as (a) the single-chip benchmark proxy for the ring path's per-chunk
-    math (BENCH_MODE=ring) and (b) a parity anchor against the dot path.
+    as (a) a single-chip stand-in for the ring path's per-chunk math and
+    (b) a parity anchor against the dot path (tests/test_attention.py).
     Deterministic only — the dropout story lives in the sharded path."""
     b_sz, h, lq, d = q.shape
     lk = k.shape[2]

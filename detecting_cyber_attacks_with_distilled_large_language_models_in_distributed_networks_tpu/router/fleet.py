@@ -9,7 +9,7 @@ swap); a fleet can do strictly better: take ONE replica out of the pick
 set, wait out its in-flight requests, swap it, readmit it, move to the
 next. During the whole sweep N-1 replicas keep serving, so a promotion
 — however slow the params load — is a zero-drop event, which is the
-property the bench pins (``router_rolling_reload_dropped == 0``).
+property tests/test_router.py pins (zero rejects across a reload).
 
 The manager follows the registry's serving pointer exactly like
 serving/reload.RegistryWatcher, with the fleet-shaped differences: ONE
@@ -278,7 +278,7 @@ class ServingFleet:
         the only pick-set member to zero on a single-replica fleet (the
         swap is atomic anyway — draining the whole pick set would CAUSE
         the drops rolling reload exists to prevent). Returns per-replica
-        timings for the caller's logs/bench."""
+        timings for the caller's logs."""
         sweep: list[dict] = []
         solo = len(self.replicas) == 1
         for rep in self.replicas:

@@ -26,7 +26,7 @@ Layers (each its own module, composable and unit-testable):
 * :mod:`.server`   — the accept loop / scorer thread wiring + telemetry
   (per-request queue wait, batch size, model round; p50/p95/p99 on the
   metrics-JSONL channel).
-* :mod:`.client`   — SDK + load generator shared by tests and bench.py.
+* :mod:`.client`   — SDK + load generator (tests drive services with it).
 """
 
 from .batcher import MicroBatcher, ScoreRequest

@@ -1,4 +1,4 @@
-"""Scoring-service SDK + load generator (shared by tests and bench.py).
+"""Scoring-service SDK + load generator (tests drive services with it).
 
 Three client shapes over the same wire:
 
@@ -18,8 +18,8 @@ Three client shapes over the same wire:
 
 :func:`run_load` drives a service with any of them (closed-loop threads,
 optional pipelining depth, optional open-loop pacing at a target QPS)
-and reports client-observed throughput and latency percentiles — the
-numbers bench.py publishes.
+and reports client-observed throughput and latency percentiles (the
+benchmark's own generator is ``benchmark/loadgen.py``).
 """
 
 from __future__ import annotations
@@ -623,7 +623,7 @@ def probe_scores(
 def load_arrival_trace(path: str) -> list[float]:
     """Read a recorded inter-arrival trace: one non-negative gap (in
     seconds) per line, blank lines and ``#`` comments skipped. The
-    bench fixtures ship a tiny bursty trace in this format."""
+    test fixtures ship a tiny bursty trace in this format."""
     gaps: list[float] = []
     with open(path) as f:
         for raw in f:

@@ -27,8 +27,8 @@ inlined collectives shift XLA's fusion and drift the probs by 1 ulp,
 breaking the crc contract below), so full-size weights exist only
 transiently during a forward and every bucket program compiles the SAME
 collective-free module the replicated engine runs — served probabilities
-from a sharded replica are bit-identical to a replicated one's (bench
-``serve_fsdp_crc_exact``). ``swap`` re-places onto the SAME
+from a sharded replica are bit-identical to a replicated one's
+(tests/test_serving_fsdp.py). ``swap`` re-places onto the SAME
 shape-deterministic layout (``fsdp_spec`` is a pure function of
 (shape, n_shards)), so a rolling hot-reload reuses every warm bucket
 program — the ledger's 0-recompile guarantee holds across reloads.

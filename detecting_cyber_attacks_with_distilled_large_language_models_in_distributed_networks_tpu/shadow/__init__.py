@@ -3,7 +3,7 @@
 The registry ladder's ``shadow`` state finally carries traffic: the
 router duplicates a deterministic sample of live scoring requests onto
 the candidate artifact (:mod:`.mirror` — fire-and-forget on a bounded
-queue, bench-asserted zero added serving p99), the serving/shadow
+queue, off the serving path), the serving/shadow
 probability pairs accumulate into flip-rate + PSI disagreement evidence
 (:mod:`.compare` — atomic paired JSONL + status file), and promotion is
 gated on that LIVE evidence (:mod:`.gate` — under-threshold

@@ -24,7 +24,7 @@ able to tell the mirror exists:
   decode all live on the mirror's own worker/reader threads. A **dead
   shadow replica degrades to pass-through**: dials fail quietly on a
   monotonic backoff, every affected pair is abandoned, and the serving
-  tier's p99 is bench-asserted unchanged (``shadow_added_p99_ms``).
+  tier keeps answering (tests/test_shadow.py's dead-shadow case).
 
 The mirror is model-free like the router: it re-addresses the already-
 encoded request frame (serving/protocol.py ``rewrite_id``) to its pair

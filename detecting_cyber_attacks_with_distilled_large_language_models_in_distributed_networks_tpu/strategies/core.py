@@ -4,7 +4,7 @@ The comparative study (arXiv:2509.17836) shows plain FedAvg degrading
 hard on non-IID cybersecurity partitions; TurboSVM-FL (arXiv:2401.12012)
 shows aggregation-side boosting recovering lazy-client fleets. This
 module is the registry both the TCP round engine (comm/server.py) and
-the scenario/bench replay gates draw from.
+the scenario replay gates draw from.
 
 Contract
 --------

@@ -167,7 +167,7 @@ class FsdpMeshTrainer(MeshTrainer):
     USE inside a remat region tagged so the backward RE-GATHERS instead
     of retaining full-size weights; gradients reduce-scatter back onto
     the shards and Adam updates run shard-local. Per-chip static bytes
-    scale ~1/N (bench-asserted, ``fsdp_peak_param_opt_bytes_ratio``).
+    scale ~1/N (tests/test_mesh_fsdp.py holds the ratio to 0.6 at N=2).
 
     Contracts carried over from the replicated mesh:
 
@@ -278,8 +278,8 @@ class FsdpMeshTrainer(MeshTrainer):
     def _note_static_bytes(self, state: TrainState) -> None:
         """Per-chip static-state accounting gauge
         (``fedtpu_fsdp_static_state_bytes``): exact addressable-shard
-        bytes of params + optimizer state on one device — the number the
-        FSDP bench's peak ratio is built from, exported so a live client
+        bytes of params + optimizer state on one device — the number
+        tests/test_mesh_fsdp.py's ratio is built from, exported so a live client
         shows its sharding actually engaged."""
         from ..obs.metrics import default_registry
 

@@ -535,7 +535,7 @@ class FederatedTrainer:
         a stacked ``[C, ...]`` input buffer can never alias its per-client
         output slices (each is 1/C the bytes), so a declared donation is
         structurally unusable — XLA copies anyway and warns "Some donated
-        buffers were not usable" on every packed bench/fit (VERDICT r5
+        buffers were not usable" on every packed fit (VERDICT r5
         weak #2). The eager-free contract the donation was buying (the
         packed fit must not pin the stacked originals alongside the
         per-client copies; Python references in caller frames keep the
@@ -581,8 +581,8 @@ class FederatedTrainer:
         for why this is a delete, not a donation). Every leaf is this
         client's OWN fresh buffer — the packed step donates its cstate,
         so a buffer shared across clients (state.step) would be dead by
-        client 1's first dispatch. Shared by the fit loop and bench.py's
-        product-step timer."""
+        client 1's first dispatch. The fit loop is its one caller in
+        the program."""
         pcs, ocs = self._unstack_fn(state.params, state.opt_state)
         for leaf in jax.tree.leaves((state.params, state.opt_state)):
             if isinstance(leaf, jax.Array):
@@ -600,10 +600,10 @@ class FederatedTrainer:
     def _packed_eligible(self) -> bool:
         """The client-packing fast path applies when every logical client
         lives on ONE device (single-process, single-device mesh — logical
-        replicas packed per row): there the stacked vmapped step's
-        batched-weight GEMMs run ~42% MFU vs ~57% for the identical math
-        dispatched as independent per-client steps (PARITY.md r5
-        decomposition). Multi-device meshes shard the clients axis and
+        replicas packed per row): there the identical math is dispatched
+        as independent per-client steps, without the stacked vmapped
+        step's batched-weight GEMMs (`mfu` 49.3%, `distilbert-fed-round-c8`,
+        ledger PR 29). Multi-device meshes shard the clients axis and
         run the per-shard lockstep step (fedsteps ``_step_body``), which
         still vmaps the clients of one mesh row."""
         return (

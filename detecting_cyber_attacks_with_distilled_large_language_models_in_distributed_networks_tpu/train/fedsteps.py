@@ -395,10 +395,11 @@ def build_federated_steps(
         On a single-device mesh the stacked vmapped program pays for its
         layout: every GEMM carries a client batch dim and each step
         re-slices/re-stacks nothing but still runs batched-weight
-        kernels. Measured on the v5e chip (PARITY.md r5 decomposition):
-        the stacked-vmap product step runs 42.3% MFU vs 57.2% for the
-        SAME math dispatched as independent per-client engine steps —
-        the fit loop unstacks once per fit, steps each client's state
+        kernels, where the SAME math dispatched as independent
+        per-client engine steps does not. On the chip this program runs
+        at `mfu` 49.3% (cell `distilbert-fed-round-c8`, ledger PR 29);
+        the stacked step on one chip is in no cell (PERF.md). The fit
+        loop unstacks once per fit, steps each client's state
         through this program, and restacks at the end. Semantically
         identical to the vmapped step (same per-client rng fold, same
         lockstep counter, same Adam); bit-level trajectory parity holds

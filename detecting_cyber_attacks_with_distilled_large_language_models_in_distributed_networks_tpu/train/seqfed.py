@@ -148,9 +148,7 @@ class FedSeqTrainer(FederatedTrainer):
     def _trace_attrs(self) -> dict:
         """Obs span attributes: the 3-axis product path's layout — seq
         shard count and ring chunk size — so a merged timeline can
-        attribute fedseq rounds to their ring configuration (the
-        fedseq-MFU-residual instrument rides the same identity in
-        bench.py's decomposition fields)."""
+        attribute fedseq rounds to their ring configuration."""
         return {
             "path": "fedseq",
             "clients": self.C,

@@ -1,7 +1,7 @@
 """Where JAX's persistent compilation cache lives — decided once, from outside.
 
-Every entry point that compiles (the ``fedtpu`` CLI, ``bench.py``,
-``chip_smoke.py``) calls :func:`place_compile_cache` before its first
+Every entry point that compiles (the ``fedtpu`` CLI, ``chip_smoke.py``,
+``benchmark/run.py``) calls :func:`place_compile_cache` before its first
 compilation. The directory is part of the cache key's environment, so it
 must not move between processes or runs:
 

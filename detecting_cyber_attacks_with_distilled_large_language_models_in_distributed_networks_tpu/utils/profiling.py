@@ -4,8 +4,8 @@ The reference's entire profiling story is timestamped ``print`` bracketing
 plus tqdm rates (reference client1.py:85,92,97,115 and the golden terminal
 logs, SURVEY.md §5) — there is no FLOPs or utilization accounting anywhere.
 Here the model's step cost is computed analytically from the config, so any
-timed step yields MFU against the local chip's peak (the BASELINE.json
-north-star metric: ≥40% MFU on DistilBERT), and ``trace`` wraps
+timed step yields MFU against the local chip's peak (the benchmark keeps
+its own copy of this arithmetic, PERF.md §7), and ``trace`` wraps
 ``jax.profiler`` for real TPU timelines (xprof/tensorboard).
 """
 

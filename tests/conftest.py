@@ -2,7 +2,7 @@
 
 The TPU analogue of a fake backend (SURVEY.md §4): multi-client federation is
 validated on virtual CPU devices; the chip is reached only by chip_smoke.py
-and bench.py. The tests force the CPU whatever the host offers.
+and benchmark/run.py. The tests force the CPU whatever the host offers.
 """
 
 import os
@@ -13,7 +13,7 @@ import sys
 # after them. The lane compiles from nothing, every run, and never reads
 # an executable some earlier run left behind: the persistent cache is
 # switched off in the environment, which jax reads at import and the
-# children (multihost workers, bench children) inherit.
+# children (multihost workers, CLI children) inherit.
 os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "0"
 
 import jax  # noqa: E402

@@ -476,33 +476,6 @@ def test_obs_metric_once_kind_suffix_and_module_checks(tmp_path):
     assert "'depth' registered from multiple modules" in messages
 
 
-def test_bench_headline_asserted_fields_must_be_produced(tmp_path):
-    root = _mini_tree(
-        tmp_path,
-        {
-            "bench.py": """
-                def check(rec):
-                    missing = [
-                        k
-                        for k in ("produced_headline", "ghost_headline")
-                        if k not in rec
-                    ]
-                    return missing
-
-                def build():
-                    rec = {"produced_headline": 1.0}
-                    return rec
-            """
-        },
-    )
-    # bench.py must sit at the scanned root, not inside the package dir.
-    os.rename(
-        os.path.join(root, "pkgx", "bench.py"), os.path.join(root, "bench.py")
-    )
-    found = _findings(root, ["bench-headline"])
-    assert len(found) == 1 and "ghost_headline" in found[0].message
-
-
 # ----------------------------------------------------- baseline semantics
 def test_baseline_suppresses_and_reports_stale(tmp_path):
     root = _mini_tree(
@@ -652,18 +625,15 @@ def test_baseline_entry_without_reason_rejected(tmp_path):
 # ------------------------------------------------- seeded-mutation self-test
 @pytest.fixture()
 def repo_copy(tmp_path):
-    """The real package + bench.py + baseline copied to a temp root —
-    the mutation tests break ONE invariant each and expect `fedtpu
-    check` to exit nonzero on the copy."""
+    """The real package + baseline copied to a temp root — the mutation
+    tests break ONE invariant each and expect `fedtpu check` to exit
+    nonzero on the copy."""
     dst = tmp_path / "copy"
     dst.mkdir()
     shutil.copytree(
         os.path.join(REPO_ROOT, PKG_NAME),
         dst / PKG_NAME,
         ignore=shutil.ignore_patterns("__pycache__"),
-    )
-    shutil.copy(
-        os.path.join(REPO_ROOT, "bench.py"), dst / "bench.py"
     )
     shutil.copy(
         os.path.join(REPO_ROOT, "ANALYSIS_BASELINE.json"),
@@ -801,23 +771,6 @@ def test_mutation_missing_stream_direction_fails(repo_copy):
     result = run_check(str(repo_copy))
     assert result.exit_code == 1
     assert any(f.rule == "wire-stream-direction" for f in result.new)
-
-
-def test_mutation_ghost_headline_field_fails(repo_copy):
-    path = os.path.join(repo_copy, "bench.py")
-    src = open(path).read()
-    anchor = '"fleet_rounds_per_hour",'
-    assert anchor in src
-    src = src.replace(
-        anchor, anchor + ' "ghost_headline_field_s",', 1
-    )
-    open(path, "w").write(src)
-    result = run_check(str(repo_copy))
-    assert result.exit_code == 1
-    assert any(
-        f.rule == "bench-headline" and "ghost_headline_field_s" in f.message
-        for f in result.new
-    )
 
 
 # -------------------------------------------------------- repo self-scan

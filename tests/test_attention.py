@@ -105,7 +105,7 @@ def test_ring_matches_dot(rng, eight_devices):
 
 
 def test_blockwise_local_matches_dot_and_ring(rng, eight_devices):
-    """blockwise_attention_local (the BENCH_MODE=ring kernel: ring
+    """blockwise_attention_local (the single-chip ring stand-in: ring
     schedule minus transport) matches the dot path and the real sharded
     ring bit-for-bit-close on the same inputs."""
     from jax.sharding import Mesh

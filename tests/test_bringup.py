@@ -88,7 +88,7 @@ def _files(root):
 
 def test_test_lane_children_leave_the_checkout_cache_alone(tmp_path, monkeypatch, capsys):
     """An in-process main() publishes <checkout>/.jax_cache through the
-    environment; a child started afterwards (a multihost worker, a bench
+    environment; a child started afterwards (a multihost worker, a CLI
     child) inherits it. conftest switched the cache off through the
     environment too, so that child compiles and writes nothing there."""
     import jax

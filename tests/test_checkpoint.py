@@ -158,7 +158,7 @@ def test_warm_start_incompatible_checkpoint_degrades_to_fresh(tmp_path, rng):
 
 def test_prng_impl_is_plumbed():
     """TrainConfig.prng_impl selects the dropout-key generator (rbg default
-    — the cheap TPU impl bench.py measures — threefry on request)."""
+    — the cheap TPU impl every cell runs — threefry on request)."""
     for impl in ("rbg", "threefry2x32"):
         tr = Trainer(ModelConfig.tiny(), TrainConfig(seed=0, prng_impl=impl))
         st = tr.init_state(seed=0)

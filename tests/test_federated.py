@@ -474,7 +474,7 @@ def test_packed_unstack_emits_no_donation_warning(tok, eight_devices):
     boundary used to declare ``donate_argnums`` on the stacked->per-client
     split, but a [C, ...] buffer can never alias its 1/C-sized output
     slices, so XLA copied anyway and warned "Some donated buffers were
-    not usable" on every fed2/fedseq bench record. The donation is gone
+    not usable" on every packed fit. The donation is gone
     (an explicit post-split delete keeps the eager-free contract); the
     whole unstack -> packed-step -> restack round trip must now be
     warning-clean, and the stacked source buffers must still be consumed."""

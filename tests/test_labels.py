@@ -67,6 +67,7 @@ from detecting_cyber_attacks_with_distilled_large_language_models_in_distributed
 from detecting_cyber_attacks_with_distilled_large_language_models_in_distributed_networks_tpu.shadow.compare import (
     PAIR_SCHEMA,
     ShadowCompare,
+    evaluate_status,
 )
 from detecting_cyber_attacks_with_distilled_large_language_models_in_distributed_networks_tpu.shadow.gate import (
     pairs_path,
@@ -239,6 +240,52 @@ def test_label_gate_rules_on_primary_pairs_only(tmp_path):
     assert ok, verdict["reason"]
     assert verdict["joined"] == 40 and verdict["total"] == 40
     assert verdict["candidate_error"] == 0.0
+
+
+def test_label_gate_rejects_what_the_flip_rate_gate_passes(tmp_path):
+    """The pincer the supervised rung exists for, over ONE body of
+    evidence: a candidate that flips 6 of 400 pairs (1.5%, under the
+    unsupervised 2% budget, clean PSI) passes `evaluate_status`, and
+    every flip turns a right answer into a wrong one, so the label gate
+    measures the regression on the joined truth and refuses. The same
+    pairs against a journal that labels 8 of the 400 fail closed on the
+    coverage floor: a verdict over 2% of the flows is not a verdict."""
+    root, aid, n = str(tmp_path), "cand-pincer", 400
+    truth = np.arange(n) % 2
+    serving = np.where(truth == 1, 0.9, 0.1)
+    cand = serving.copy()
+    cand[1:12:2] = 0.08  # six attack flows answered benign
+    compare = ShadowCompare(threshold=0.5, pairs_jsonl=pairs_path(root, aid))
+    for i in range(n):
+        compare.register_rid(i, f"r{i}")
+        compare.note_serving(i, float(serving[i]))
+        compare.note_shadow(i, float(cand[i]))
+    ok, why = evaluate_status(
+        compare.snapshot(), min_pairs=100, max_flip_rate=0.02,
+        psi_threshold=0.25,
+    )
+    assert ok, why
+
+    store = LabelStore(journal_path(root))
+    for i in range(300):  # delayed labels are partial: 75% here
+        store.ingest(f"r{i}", int(truth[i]), ts=float(i))
+    ok, verdict = LabelGate(
+        root, min_joined=64, coverage_floor=0.05, max_regression=0.0
+    ).evaluate(aid)
+    assert not ok and "regression" in verdict["reason"]
+    assert verdict["joined"] == 300
+    assert verdict["serving_error"] == 0.0
+    assert verdict["candidate_error"] == pytest.approx(6 / 300)
+
+    sparse = str(tmp_path / "sparse.jsonl")
+    thin = LabelStore(sparse)
+    for i in range(8):
+        thin.ingest(f"r{i}", int(truth[i]), ts=float(i))
+    ok, verdict = LabelGate(
+        root, journal=sparse, min_joined=4, coverage_floor=0.05,
+        max_regression=0.0,
+    ).evaluate(aid)
+    assert not ok and "coverage" in verdict["reason"]
 
 
 # ------------------------------------------------- label-aware drift plane

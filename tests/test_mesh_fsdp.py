@@ -212,8 +212,8 @@ def test_fsdp_static_state_shards_at_rest(tok, eight_devices):
 
 
 def test_fsdp_backward_regathers_instead_of_retaining(tok, eight_devices):
-    """The peak-memory MECHANISM (invisible to the bench, which measures
-    at-rest bytes outside the step): the rematted FSDP loss saves NO
+    """The peak-memory MECHANISM (invisible to the at-rest byte count,
+    which is taken outside the step): the rematted FSDP loss saves NO
     gathered full-size weight as a residual — every saved value is a
     region argument (the shards at rest) or an activation — so the
     backward RE-GATHERS. Built exactly as make_fsdp_train_step builds
@@ -283,7 +283,7 @@ def jnp_like(arr):
 
 # ----------------------------------------------------------- wire boundary
 def test_fsdp_gather_scatter_round_trip_crc_exact(tok, eight_devices):
-    """The wire-exchange gather contract (the bench's fsdp_crc_exact):
+    """The wire-exchange gather contract:
     host-gather -> adopt (scatter onto shards, fresh sharded Adam) ->
     host-gather is byte- and crc-exact, so secure-agg/DP masking sees
     the identical flat vector a single-device client would produce."""

@@ -168,8 +168,8 @@ def test_timeline_attributes_round_wall(live_round):
 
 
 def test_server_phase_seconds_accounting(live_round):
-    """The always-on comm/compute breakdown (bench.py's comm_phase_*
-    headline source): wait/agg/reply are all populated and wait dominates
+    """The always-on comm/compute breakdown (the timeline's and
+    /metrics' source): wait/agg/reply are all populated and wait dominates
     a round whose wall is the clients' local phases."""
     phases = live_round["server"].phase_seconds
     assert set(phases) == {"wait", "agg", "reply"}

@@ -8,8 +8,8 @@ Contracts pinned here:
 * Least-in-flight routing spreads live traffic across healthy replicas;
   drained or ejected replicas leave the pick set and readmit cleanly.
 * A registry promotion against a running fleet rolling-reloads every
-  replica under load with ZERO dropped requests (the bench's
-  ``router_rolling_reload_dropped == 0`` contract, test-scale), emits
+  replica under load with ZERO dropped requests (the zero-downtime
+  deploy contract, test-scale), emits
   ``replica-drain`` spans, and records per-replica reload events on the
   registry's audit trail.
 * The pipelined and async clients match replies to requests by id —

@@ -713,6 +713,112 @@ class TestSentinelComposition:
         link_path_had_content = os.path.getsize(verdicts) > 0
         assert link_path_had_content
 
+    def test_live_serving_chain_end_to_end(self, tmp_path):
+        """Every other prober test injects its ``probe_fn``. Here the
+        canaries ride the real SDK, wire and scorer against the real
+        registry pointer, and the drift rung joins the journal to the
+        scored-JSONL the SERVER wrote: clean ticks fire nothing, a
+        promotion (pointer and engine together) re-keys in silence, a
+        stale replica fires a mismatch per canary, and labels opposite
+        to the live answers fire the drift verdict and poke the
+        controller's link."""
+        import time
+
+        from detecting_cyber_attacks_with_distilled_large_language_models_in_distributed_networks_tpu.config import (
+            ModelConfig,
+            TrainConfig,
+        )
+        from detecting_cyber_attacks_with_distilled_large_language_models_in_distributed_networks_tpu.data import (
+            default_tokenizer,
+            make_synthetic,
+        )
+        from detecting_cyber_attacks_with_distilled_large_language_models_in_distributed_networks_tpu.data.datasets import (
+            get_dataset,
+        )
+        from detecting_cyber_attacks_with_distilled_large_language_models_in_distributed_networks_tpu.serving import (
+            ScoreEngine,
+            ScoringServer,
+        )
+        from detecting_cyber_attacks_with_distilled_large_language_models_in_distributed_networks_tpu.serving.client import (
+            probe_scores,
+        )
+        from detecting_cyber_attacks_with_distilled_large_language_models_in_distributed_networks_tpu.train.engine import (
+            Trainer,
+        )
+
+        tok = default_tokenizer()
+        model_cfg = ModelConfig.tiny(vocab_size=len(tok.vocab))
+        trainer = Trainer(model_cfg, TrainConfig(), pad_id=tok.pad_id)
+        params = [trainer.init_state(seed=s).params for s in range(3)]
+        registry = ModelRegistry(str(tmp_path / "registry"))
+
+        def promote(i):
+            aid = registry.add(
+                params[i], round_index=i + 1, model_config=model_cfg
+            )
+            registry.promote(aid, to="serving")
+
+        promote(0)
+        scored, journal, verdicts = (
+            str(tmp_path / n)
+            for n in ("scored.jsonl", "journal.jsonl", "verdicts.jsonl")
+        )
+        for p in (scored, journal):
+            open(p, "w").close()
+        spec = get_dataset("cicids2017")
+        engine = ScoreEngine(
+            model_cfg, params[0], pad_id=tok.pad_id, buckets=(1, 8), round_id=1
+        )
+        flows = load_canary_flows(FIXTURE, preset="cicids2017")
+        with ScoringServer(
+            engine, tok, spec=spec, scored_jsonl=scored, idle_tick_s=0.01
+        ) as server:
+            link = SentinelLink(verdicts)
+            s = Sentinel(
+                prober=CanaryProber(
+                    flows, "127.0.0.1", server.port, registry=registry
+                ),
+                tail=JournalTail(
+                    scored,
+                    journal,
+                    monitor=ErrorRateMonitor(
+                        reference_error=0.05, margin=0.2, min_joined=32
+                    ),
+                    verdicts_jsonl=verdicts,
+                ),
+            )
+            for _ in range(3):
+                report = s.tick()
+                assert report["canary"]["probes"] == len(flows)
+                assert report["canary"]["incidents"] == []
+            promote(1)
+            engine.swap(params[1], round_id=2)
+            assert s.tick()["canary"]["incidents"] == []
+            assert s.canary_flips == 0 and s.drift_fires == 0
+            promote(2)  # the registry advances, the replica does not
+            stale = s.tick()["canary"]
+            assert stale["mismatches"] == len(flows)
+            assert {i["kind"] for i in stale["incidents"]} == {"pointer-mismatch"}
+            engine.swap(params[2], round_id=3)
+            assert s.tick()["canary"]["incidents"] == []
+            texts = spec.render_texts(make_synthetic("cicids2017", 48, seed=1))
+            replies = probe_scores("127.0.0.1", server.port, texts)
+            _write_lines(
+                journal,
+                [
+                    {
+                        "schema": "fedtpu-label-v1",
+                        "rid": str(reply["id"]),
+                        "label": 1 - int(reply["prediction"]),
+                        "ts": time.time(),
+                    }
+                    for reply, _latency in replies
+                ],
+            )
+            assert s.tick()["drift"]["verdict"] is not None
+            assert s.drift_fires == 1
+            assert link.poll()["method"] == "error_rate"
+
 
 # -------------------------------------------------------- custom trend fields
 class TestCustomTrendFields:

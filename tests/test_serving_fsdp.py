@@ -97,7 +97,7 @@ def test_sharded_probs_bit_identical_to_replicated(engines, setup):
 def test_sharded_static_bytes_are_split_per_chip(engines):
     """Shard-at-rest accounting: the sharded engine's params occupy
     ~1/N of the replicated engine's bytes on any one chip (<= 0.6 at
-    N=2 — the bench gate's shape; replicated leaves keep full size)."""
+    N=2; replicated leaves keep full size)."""
     rep, shard = engines
     rep_bytes = device_tree_bytes(rep.snapshot()[0])
     shard_bytes = device_tree_bytes(shard.snapshot()[0])
@@ -148,6 +148,50 @@ def test_sharded_swap_reuses_warm_programs(setup):
     assert eng.ledger.recompiles() == []
     assert all(v == 1 for v in eng.compile_counts.values())
     # The gather program compiled exactly once too (its own ledger site).
+    assert eng.ledger.compile_counts("serving.gather") == {("gather",): 1}
+
+
+def test_sharded_swaps_under_load_keep_warm(setup):
+    """The same guarantee with traffic on the engine: a scorer thread
+    hammers two warm buckets while three swaps land. No score fails,
+    every reply names a round that was served, and nothing retraces."""
+    import threading
+    import time
+
+    import jax
+
+    tok, model_cfg, trainer, params, mesh = setup
+    eng = ScoreEngine(
+        model_cfg, params, pad_id=tok.pad_id, buckets=BUCKETS, mesh=mesh
+    )
+    eng.warmup()
+    ids, mask = _ragged_batch(model_cfg, 8)
+    stop, rounds, errors = threading.Event(), set(), []
+
+    def hammer():
+        try:
+            while not stop.is_set():
+                rounds.add(eng.score(ids, mask)[3])
+                rounds.add(eng.score(ids[:1], mask[:1])[3])
+        except Exception as e:  # the finding, not a crash of the thread
+            errors.append(e)
+
+    scorer = threading.Thread(target=hammer, daemon=True)
+    scorer.start()
+    bumped = jax.tree.map(lambda a: np.asarray(a) + np.float32(1e-3), params)
+    try:
+        for rid in (1, 2, 3):
+            eng.swap(bumped if rid % 2 else params, round_id=rid)
+            deadline = time.monotonic() + 60.0
+            while rid not in rounds and scorer.is_alive():
+                assert time.monotonic() < deadline, "no score landed on the swap"
+                time.sleep(0.005)
+    finally:
+        stop.set()
+        scorer.join(timeout=60.0)
+    assert not scorer.is_alive() and errors == []
+    assert rounds <= {0, 1, 2, 3} and {1, 2, 3} <= rounds
+    assert eng.ledger.recompiles() == []
     assert eng.ledger.compile_counts("serving.gather") == {("gather",): 1}
 
 
