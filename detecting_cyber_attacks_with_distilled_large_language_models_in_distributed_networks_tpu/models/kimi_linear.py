@@ -3,9 +3,11 @@
 The second model class beside ``models/distilbert.py`` (``KimiLinearConfig``,
 ``models.build_classifier``): a pre-norm decoder whose mixer is, by layer,
 Kimi Delta Attention (a gated delta-rule linear attention with a short causal
-convolution, ``ops/kda.py``: chunks of 64 tokens, their pair matrices and
-substitution in XLA, the recurrence over the chunks in two Pallas kernels that
-keep the state on the chip, interpreted off the TPU) or full latent attention
+convolution, ``ops/kda.py``: chunks of 64 tokens; where nobody asks for a
+gradient one Pallas kernel does a chunk's pair matrices, substitution and
+recurrence in VMEM, and the gradient's own forward builds the pair matrices
+and solves in XLA around two Pallas kernels that keep the state on the chip;
+all interpreted off the TPU) or full latent attention
 without positions (MLA,
 ``ops/causal_attention.py``), and whose FFN is a dense SwiGLU or a sparse
 mixture of SwiGLU experts with a sigmoid router and a shared expert

@@ -40,7 +40,9 @@ from detecting_cyber_attacks_with_distilled_large_language_models_in_distributed
 )
 from detecting_cyber_attacks_with_distilled_large_language_models_in_distributed_networks_tpu.ops.kda import (
     CHUNK,
+    HEADS,
     _chunk_recurrence,
+    _kda_chunked,
     kda_chunked,
     kda_recurrent,
 )
@@ -114,9 +116,10 @@ def _kda_inputs(L, seed=0, B=2, H=2, d=16, decay=2.0):
     ids=["L128", "L150", "L47", "L200", "one-chunk", "padded-tail", "L160", "L256", "forgets-within-a-chunk"],
 )
 def test_chunked_kda_is_the_token_recurrence(L, decay):
-    """Outputs and all five gradients through the recurrence's kernels (under
-    the interpreter here) over one to four chunks, lengths that are no
-    multiple of the chunk, a decay (e^-100 a chunk) that a whole-chunk
+    """The outputs of the one kernel that runs where nobody differentiates
+    (``kda_fwd``) and all five gradients through the gradient path's kernels
+    (all under the interpreter here) over one to four chunks, lengths that are
+    no multiple of the chunk, a decay (e^-100 a chunk) that a whole-chunk
     factorisation would overflow on, and one (e^-150) under which a chunk's
     last tokens see nothing of the state it started from."""
     x = _kda_inputs(L, decay=decay)
@@ -146,6 +149,42 @@ def test_chunked_kda_in_bf16_is_near_its_float32():
         (1.5e-2, 1.5e-2, 1.5e-2, 6e-2, 1.5e-2),
     ):
         assert 1e-4 < _rel(a, b) < limit, _rel(a, b)
+
+
+@pytest.mark.parametrize(
+    "L, H",
+    [(160, 3), (100, 2), (64, 2), (192, 2 * HEADS)],
+    ids=["a-head-a-step", "padded-tail", "one-chunk", "two-steps-of-8-heads"],
+)
+def test_the_undifferentiated_forward_is_the_gradient_paths_forward(L, H):
+    """``kda_chunked`` where nobody asks for a gradient (one launch of
+    ``kda_fwd``: pair matrices, inverse and recurrence in the kernel) against
+    the forward the gradient is taken of (``_kda_chunked``: XLA's pair matrices
+    and substitution, ``kda_chunks_fwd``): the same numbers in float32, and with
+    bfloat16 products within the bounds the gradient path's are held to."""
+    x = _kda_inputs(L, H=H, seed=3)
+    want = _kda_chunked(*x, jnp.float32)
+    got = kda_chunked(*x)
+    assert got.shape == want.shape == (2, H, L, 16) and got.dtype == jnp.float32
+    assert float(jnp.abs(got - want).max()) < 2e-6
+    err = _rel(kda_chunked(*x, dtype=jnp.bfloat16), want)
+    assert 1e-4 < err < 2e-2, err
+    assert _rel(kda_chunked(*x, dtype=jnp.bfloat16), _kda_chunked(*x, jnp.bfloat16)) < 2e-2
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+def test_the_gradient_is_the_gradient_paths_row_by_row(dtype):
+    """``jax.grad`` through ``kda_chunked`` is ``jax.grad`` through
+    ``_kda_chunked`` of each row alone: the ``bwd`` rule is that code, only
+    mapped over the rows, and the kernel's ``O`` has no part in it."""
+    x = _kda_inputs(150, B=3, seed=5)
+    cot = jnp.asarray(np.random.default_rng(6).normal(size=(3, 2, 150, 16)), jnp.float32)
+    got = jax.grad(lambda *a: (kda_chunked(*a, dtype=dtype) * cot).sum(), argnums=(0, 1, 2, 3, 4))(*x)
+    for r in range(3):
+        row = tuple(a[r : r + 1] for a in x)
+        want = jax.grad(lambda *a: (_kda_chunked(*a, dtype) * cot[r : r + 1]).sum(), argnums=(0, 1, 2, 3, 4))(*row)  # noqa: B023
+        for a, b in zip(got, want):
+            assert a[r : r + 1].shape == b.shape and _rel(a[r : r + 1], b) < 1e-6
 
 
 def _plain_recurrence(W, U0, Q, Bqk, K, decay):
@@ -412,28 +451,45 @@ def test_trainer_fit_evaluate_and_checkpoint_round_trip(tmp_path, tiny_params):
     assert int(back.step) == 6
 
 
-def test_the_train_step_runs_the_recurrence_in_its_two_kernels(tiny_params):
+@pytest.mark.parametrize("program", ["train_step", "eval_step"])
+def test_the_train_step_runs_the_recurrence_in_its_two_kernels(tiny_params, program):
     """Did the mechanism engage: ``engine.train_step`` for the tiny
     configuration (two chunks a row, four rows) holds, under every KDA layer's
-    scope ``kda/chunks``, the forward kernel (once as run and once a
-    recomputation: per layer and per row) and the reverse one, by their names;
-    and the only loop left under that scope is the map over the batch's rows:
-    none over the chunks."""
+    scope ``kda/chunks``, the kernel of the undifferentiated forward twice (the
+    pass and the per-layer recomputation, each one launch for the batch, under
+    ``chunks/fwd``) and the gradient path's two kernels once each, by their
+    names; the only loop under that scope is the gradient path's map over the
+    batch's rows, none over the chunks and none around ``kda_fwd``.
+    ``engine.eval_step`` holds one launch of ``kda_fwd`` a layer and nothing of
+    the XLA half: no substitution, no scatter, no loop."""
     cfg = TINY.replace(max_len=2 * CHUNK, remat=True)
     ids, mask = _rows(cfg, [128, 100, 80, 70])
     batch = {"input_ids": ids, "attention_mask": mask, "labels": np.array([0, 1, 0, 1], np.int32)}
     trainer = Trainer(cfg, TrainConfig(log_every=0), pad_id=0)
     state = trainer.init_state(seed=0, params=jax.tree.map(jnp.copy, tiny_params))
-    jaxpr = jax.make_jaxpr(trainer.train_step.__wrapped__)(state, batch)
+    if program == "train_step":
+        jaxpr = jax.make_jaxpr(trainer.train_step.__wrapped__)(state, batch)
+    else:
+        jaxpr = jax.make_jaxpr(trainer.eval_step.__wrapped__)(state.params, batch, np.ones(len(ids), bool))
 
-    eqns = [(path, eqn) for path, _, eqn in _eqns(jaxpr.jaxpr) if "kda/chunks" in path]
-    kernels = collections.Counter(eqn.params["name"] for _, eqn in eqns if eqn.primitive.name == "pallas_call")
+    # (path, the primitives around it, equation), outside the kernels' own bodies
+    eqns = [x for x in _eqns(jaxpr.jaxpr) if "kda/chunks" in x[0] and "pallas_call" not in x[1]]
+    launches = [(path, eqn.params["name"]) for path, _, eqn in eqns if eqn.primitive.name == "pallas_call"]
+    kernels = collections.Counter(name for _, name in launches)
     kda_layers = [i for i in range(cfg.n_layers) if cfg.mixer(i) == "kda"]
-    assert kda_layers and kernels == {"kda_chunks_fwd": 3 * len(kda_layers), "kda_chunks_bwd": len(kda_layers)}, kernels
+    n = len(kda_layers)
+    grad = program == "train_step"
+    assert n and kernels == ({"kda_fwd": 2 * n, "kda_chunks_fwd": n, "kda_chunks_bwd": n} if grad else {"kda_fwd": n}), kernels
     for layer in kda_layers:
-        assert any(f"layer_{layer}/kda/kda/chunks" in path for path, eqn in eqns if eqn.primitive.name == "pallas_call")
-    loops = [eqn.params.get("length") for _, eqn in eqns if eqn.primitive.name in ("scan", "while")]
-    assert loops and set(loops) == {len(ids)}, loops  # the rows' lax.map, forward and transposed
+        assert any(f"layer_{layer}/kda/kda/chunks" in path for path, _ in launches)
+    assert all(("/chunks/fwd/" in path) == (name == "kda_fwd") for path, name in launches), launches
+    loops = [(path, outer, eqn.params.get("length")) for path, outer, eqn in eqns if eqn.primitive.name in ("scan", "while")]
+    # the rows' lax.map of the gradient path, forward and transposed; nothing loops around kda_fwd
+    assert {length for _, _, length in loops} == ({len(ids)} if grad else set()), loops
+    assert not any("/chunks/fwd" in path for path, _, _ in loops)
+    assert all("scan" not in outer and "while" not in outer for path, outer, eqn in eqns if eqn.primitive.name == "pallas_call" and eqn.params["name"] == "kda_fwd")
+    xla_half = collections.Counter(eqn.primitive.name for _, _, eqn in eqns if eqn.primitive.name in ("triangular_solve", "scatter"))
+    assert bool(xla_half) == grad, xla_half
 
 
 def test_overflow_is_counted_and_said_loudly_by_fit_and_by_evaluate(monkeypatch):

@@ -44,3 +44,21 @@ def test_the_kda_recurrence_compiles_for_the_v5e_at_the_cells_widths(one_chip, m
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == (2 if grad else 1)
     assert "kda_chunks_bwd" in text if grad else "kda_chunks_fwd" in text
+
+
+def test_the_undifferentiated_kda_forward_compiles_for_the_v5e_at_the_cells_widths(one_chip, monkeypatch):
+    """A step's batch of ``kimilinear-window-fit-l4k`` as the mixer passes it:
+    4 rows, 32 heads of 128, 64 chunks of 64 tokens; ``q``, ``k`` and the
+    log-decay float32, ``v`` in the products' bfloat16. The whole of a chunk
+    (pair matrices, inverse, recurrence) is one Mosaic kernel, so what it asks
+    of VMEM and of the tiling is refused here, not in the cell."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    B, H, L, d = 4, 32, 64 * kda.CHUNK, 128
+    arg = lambda dtype, *shape: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    args = (arg(f32, B, H, L, d), arg(f32, B, H, L, d), arg(bf16, B, H, L, d), arg(f32, B, H, L, d), arg(f32, B, H, L))
+    compiled = jax.jit(lambda *a: kda.kda_chunked(*a, dtype=bf16)).lower(*args).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1 and "kda_fwd" in text
+    assert "triangular" not in text and "while" not in text  # nothing of the XLA half, no loop over the rows
+    assert compiled.memory_analysis().temp_size_in_bytes == 0  # the operands are read in place
