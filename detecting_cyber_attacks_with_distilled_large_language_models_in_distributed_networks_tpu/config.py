@@ -218,6 +218,11 @@ class KimiLinearConfig:
     def is_moe(self, layer: int) -> bool:
         return layer >= self.first_dense_layers
 
+    @property
+    def shared_dim(self) -> int:
+        """Width of the shared experts, which run as one SwiGLU."""
+        return self.n_shared_experts * self.expert_dim
+
     def replace(self, **kw: Any) -> "KimiLinearConfig":
         return dataclasses.replace(self, **kw)
 
@@ -249,12 +254,145 @@ class KimiLinearConfig:
         return cls(**{**defaults, **kw})
 
 
+#: One period of Laguna's layer pattern: a full-attention layer, then three
+#: with a sliding window, and the query heads of each.
+_LAGUNA_PERIOD = (("full", 48), ("sliding", 64), ("sliding", 64), ("sliding", 64))
+
+
+@dataclass(frozen=True)
+class LagunaConfig:
+    """Pre-norm decoder whose attention is, by layer, full or cut to a
+    sliding window, with rotary positions of two kinds, grouped key/value
+    heads under two counts of query heads, a per-head output gate and a
+    sparse expert FFN, and the paper's classification head on each row's
+    LAST REAL token (``models/laguna.py``).
+
+    Defaults are the published ``config.json`` of poolside/Laguna-XS.2: 40
+    RMSNorm layers of width 2048, ``head_dim`` 128, 8 key/value heads; layer
+    ``i``'s attention is ``layer_types[i]`` (``"full"`` or ``"sliding"``,
+    window ``sliding_window``) with ``heads_per_layer[i]`` query heads (48
+    where full, 64 where sliding); sliding layers rotate every dimension of a
+    head at ``sliding_rope_theta``, full layers the first
+    ``full_rotary_share`` of them at ``full_rope_theta`` under YaRN
+    (``ops/rope.py``); the FFN of layer ``i`` is ``ffn_types[i]``: a dense
+    SwiGLU of ``hidden_dim`` or ``n_experts`` routed SwiGLU experts of
+    ``expert_dim`` (sigmoid router, top ``experts_per_token`` by score,
+    renormalised, times ``routed_scale``) plus one shared expert of
+    ``shared_dim``.
+
+    ``experts_held`` / ``expert_offset``: the experts THIS chip holds, as
+    :class:`KimiLinearConfig` has them. Frozen and hashable, for the same
+    reason.
+    """
+
+    family: str = "laguna"
+    vocab_size: int = 100352
+    max_len: int = 8192
+    dim: int = 2048
+    layer_types: tuple[str, ...] = tuple(kind for kind, _ in _LAGUNA_PERIOD) * 10
+    heads_per_layer: tuple[int, ...] = tuple(heads for _, heads in _LAGUNA_PERIOD) * 10
+    ffn_types: tuple[str, ...] = ("dense",) + ("sparse",) * 39
+    n_kv_heads: int = 8
+    head_dim: int = 128
+    sliding_window: int = 512
+    # Rotary positions of the sliding layers (the default kind)
+    sliding_rope_theta: float = 10000.0
+    sliding_rotary_share: float = 1.0
+    # and of the full layers (YaRN; a factor of 1 is the default kind)
+    full_rope_theta: float = 500000.0
+    full_rotary_share: float = 0.5
+    full_rope_factor: float = 64.0
+    full_rope_original_len: int = 4096
+    full_rope_beta_fast: float = 64.0
+    full_rope_beta_slow: float = 1.0
+    full_rope_attention_factor: float = 1.4158883083359672
+    # FFN
+    hidden_dim: int = 8192
+    expert_dim: int = 512
+    shared_dim: int = 512
+    n_experts: int = 256
+    experts_per_token: int = 8
+    routed_scale: float = 2.5
+    experts_held: int = 256
+    expert_offset: int = 0
+    rms_norm_eps: float = 1e-6
+    initializer_range: float = 0.02
+    n_classes: int = 2
+    pad_token_id: int = 0
+    compute_dtype: str = "bfloat16"
+    param_dtype: str = "float32"
+    #: Per-layer recomputation, as :class:`KimiLinearConfig` has it.
+    remat: bool = False
+
+    def __post_init__(self) -> None:
+        n = len(self.layer_types)
+        if n < 1 or not len(self.heads_per_layer) == len(self.ffn_types) == n:
+            raise ValueError(
+                f"layer_types ({n}), heads_per_layer ({len(self.heads_per_layer)}) and "
+                f"ffn_types ({len(self.ffn_types)}) must name the same layers, at least one"
+            )
+        if set(self.layer_types) - {"full", "sliding"} or set(self.ffn_types) - {"dense", "sparse"}:
+            raise ValueError("layer_types holds full|sliding and ffn_types dense|sparse")
+        if any(h % self.n_kv_heads for h in self.heads_per_layer):
+            raise ValueError(
+                f"heads_per_layer={self.heads_per_layer}: every count must be a multiple of "
+                f"n_kv_heads={self.n_kv_heads}"
+            )
+        if not 0 < self.experts_held <= self.n_experts - self.expert_offset:
+            raise ValueError(
+                f"experts_held={self.experts_held} at expert_offset="
+                f"{self.expert_offset} is not a share of n_experts={self.n_experts}"
+            )
+        if self.experts_per_token > self.n_experts:
+            raise ValueError("experts_per_token exceeds n_experts")
+
+    @property
+    def n_layers(self) -> int:
+        return len(self.layer_types)
+
+    def is_moe(self, layer: int) -> bool:
+        return self.ffn_types[layer] == "sparse"
+
+    def replace(self, **kw: Any) -> "LagunaConfig":
+        return dataclasses.replace(self, **kw)
+
+    @classmethod
+    def ep8_cut(cls, **kw: Any) -> "LagunaConfig":
+        """One chip's share of a deployment in which 8 chips share each
+        layer: layers 0-4 (the leading dense layer and the whole period after
+        it), 32 of the 256 experts, an eighth of the vocabulary; every width
+        as published (~666 M parameters)."""
+        kw.setdefault("layer_types", ("full", "sliding", "sliding", "sliding", "full"))
+        kw.setdefault("heads_per_layer", (48, 64, 64, 64, 48))
+        kw.setdefault("ffn_types", ("dense", "sparse", "sparse", "sparse", "sparse"))
+        kw.setdefault("experts_held", 32)
+        kw.setdefault("vocab_size", 12544)
+        kw.setdefault("remat", True)
+        return cls(**kw)
+
+    @classmethod
+    def tiny(cls, **kw: Any) -> "LagunaConfig":
+        """Small config for tests / CI on CPU, fp32: three layers hold every
+        kind of part (full + dense FFN, sliding + experts, full + experts),
+        with both head counts and a window shorter than a row."""
+        defaults = dict(
+            vocab_size=256, max_len=96, dim=32,
+            layer_types=("full", "sliding", "full"), heads_per_layer=(4, 6, 4),
+            ffn_types=("dense", "sparse", "sparse"), n_kv_heads=2, head_dim=8,
+            sliding_window=24, full_rope_original_len=32, full_rope_factor=4.0,
+            full_rope_attention_factor=1.1386294361119891, hidden_dim=64,
+            expert_dim=24, shared_dim=24, n_experts=16, experts_per_token=4,
+            experts_held=4, compute_dtype="float32",
+        )
+        return cls(**{**defaults, **kw})
+
+
 #: THE registry of the model families beside the BERT encoder: ``family``
 #: value -> configuration type (a ``ModelConfig`` has no ``family`` key).
 #: ``ExperimentConfig.from_dict`` picks a section's type by it, and
 #: ``models.family_module`` the module ``models/<family>.py`` that holds the
 #: family's ``Classifier`` and ``forward_flops``.
-MODEL_CONFIG_TYPES: dict[str, type] = {"kimi_linear": KimiLinearConfig}
+MODEL_CONFIG_TYPES: dict[str, type] = {"kimi_linear": KimiLinearConfig, "laguna": LagunaConfig}
 
 
 @dataclass(frozen=True)
@@ -1060,7 +1198,7 @@ class MeshConfig:
 
 @dataclass(frozen=True)
 class ExperimentConfig:
-    model: ModelConfig | KimiLinearConfig = field(default_factory=ModelConfig)
+    model: ModelConfig | KimiLinearConfig | LagunaConfig = field(default_factory=ModelConfig)
     data: DataConfig = field(default_factory=DataConfig)
     train: TrainConfig = field(default_factory=TrainConfig)
     fed: FedConfig = field(default_factory=FedConfig)

@@ -49,28 +49,9 @@ from ..config import KimiLinearConfig
 from ..ops.causal_attention import causal_attention
 from ..ops.kda import CHUNK as KDA_CHUNK
 from ..ops.kda import kda_chunked
-from ..ops.moe import ROUTE_CHOICE, expert_capacity, held_experts_ffn, route_topk
-from .routing import ROUTE
-
-
-def _dense(cfg: KimiLinearConfig, features: int, name: str) -> nn.Dense:
-    return nn.Dense(
-        features,
-        use_bias=False,
-        dtype=jnp.dtype(cfg.compute_dtype),
-        param_dtype=jnp.dtype(cfg.param_dtype),
-        kernel_init=nn.initializers.normal(cfg.initializer_range),
-        name=name,
-    )
-
-
-def _rms(cfg: KimiLinearConfig, name: str) -> nn.RMSNorm:
-    return nn.RMSNorm(
-        epsilon=cfg.rms_norm_eps,
-        dtype=jnp.dtype(cfg.compute_dtype),
-        param_dtype=jnp.dtype(cfg.param_dtype),
-        name=name,
-    )
+from .blocks import SparseMoE, SwiGLU, decoder, last_real_token_head
+from .blocks import dense as _dense
+from .blocks import rms as _rms
 
 
 def _conv_init(key, shape, dtype):
@@ -162,62 +143,6 @@ class MLAMixer(nn.Module):
         return _dense(cfg, cfg.dim, "o_proj")(o.transpose(0, 2, 1, 3).reshape(B, L, H * dv))
 
 
-class SwiGLU(nn.Module):
-    cfg: KimiLinearConfig
-    width: int
-
-    @nn.compact
-    def __call__(self, x):
-        cfg = self.cfg
-        h = jax.nn.silu(_dense(cfg, self.width, "gate_proj")(x)) * _dense(cfg, self.width, "up_proj")(x)
-        return _dense(cfg, cfg.dim, "down_proj")(h)
-
-
-class SparseMoE(nn.Module):
-    """``Shared(x) + sum over the chosen experts this chip holds of w_e
-    Expert_e(x)``; the router scores all ``n_experts`` in float32."""
-
-    cfg: KimiLinearConfig
-
-    @nn.compact
-    def __call__(self, x, attention_mask):
-        cfg = self.cfg
-        B, L, D = x.shape
-        pd = jnp.dtype(cfg.param_dtype)
-        init = nn.initializers.normal(cfg.initializer_range)
-        held, F = cfg.experts_held, cfg.expert_dim
-        flat = x.reshape(B * L, D)
-        with jax.named_scope("moe/router"):
-            w_router = self.param("router", init, (D, cfg.n_experts), pd)
-            # A buffer, not a weight: it steers the selection only, gets no
-            # gradient, and is zero at the seed.
-            select_bias = self.param("select_bias", nn.initializers.zeros, (cfg.n_experts,), pd)
-            scores = jax.nn.sigmoid(
-                jnp.dot(
-                    flat.astype(jnp.float32), w_router.astype(jnp.float32),
-                    precision=jax.lax.Precision.HIGHEST,
-                )
-            )
-            idx, w = route_topk(scores, select_bias, cfg.experts_per_token, cfg.routed_scale)
-        self.sow("intermediates", "chosen", idx)
-        with jax.named_scope("moe/experts"):
-            y, slots, overflow = held_experts_ffn(
-                flat, idx, w, attention_mask.reshape(B * L) > 0,
-                self.param("experts_gate", init, (held, D, F), pd),
-                self.param("experts_up", init, (held, D, F), pd),
-                self.param("experts_down", init, (held, F, D), pd),
-                offset=cfg.expert_offset,
-                capacity=expert_capacity(B * L, cfg.experts_per_token, cfg.n_experts, held),
-                dtype=jnp.dtype(cfg.compute_dtype),
-            )
-        add = lambda a, b: a + b  # noqa: E731
-        self.sow(ROUTE, "slots", slots, reduce_fn=add, init_fn=lambda: jnp.zeros_like(slots))
-        self.sow(ROUTE, "overflow", overflow, reduce_fn=add, init_fn=lambda: jnp.zeros_like(overflow))
-        with jax.named_scope("moe/shared"):
-            shared = SwiGLU(cfg, cfg.n_shared_experts * F, name="shared")(x)
-        return shared + y.reshape(B, L, D).astype(x.dtype)
-
-
 class KimiBlock(nn.Module):
     cfg: KimiLinearConfig
     layer: int
@@ -246,22 +171,7 @@ class KimiLinearEncoder(nn.Module):
 
     @nn.compact
     def __call__(self, input_ids, attention_mask, deterministic: bool = True):
-        cfg = self.cfg
-        x = nn.Embed(
-            cfg.vocab_size,
-            cfg.dim,
-            dtype=jnp.dtype(cfg.compute_dtype),
-            param_dtype=jnp.dtype(cfg.param_dtype),
-            embedding_init=nn.initializers.normal(cfg.initializer_range),
-            name="word_embeddings",
-        )(input_ids)
-        block = KimiBlock
-        if cfg.remat:
-            keep = jax.checkpoint_policies.save_only_these_names(ROUTE_CHOICE)
-            block = nn.remat(KimiBlock, policy=keep)
-        for i in range(cfg.n_layers):
-            x = block(cfg, i, name=f"layer_{i}")(x, attention_mask)
-        return _rms(cfg, "final_norm")(x)
+        return decoder(self.cfg, KimiBlock, input_ids, attention_mask)
 
 
 class KimiLinearClassifier(nn.Module):
@@ -276,15 +186,7 @@ class KimiLinearClassifier(nn.Module):
     def __call__(self, input_ids, attention_mask, deterministic: bool = True):
         cfg = self.cfg
         hidden = KimiLinearEncoder(cfg, name="encoder")(input_ids, attention_mask, deterministic)
-        last = jnp.maximum(attention_mask.sum(-1).astype(jnp.int32) - 1, 0)
-        pooled = jnp.take_along_axis(hidden, last[:, None, None], axis=1)[:, 0, :]
-        return nn.Dense(
-            cfg.n_classes,
-            dtype=jnp.float32,  # head + loss in fp32
-            param_dtype=jnp.dtype(cfg.param_dtype),
-            kernel_init=nn.initializers.normal(cfg.initializer_range),
-            name="classifier",
-        )(pooled.astype(jnp.float32))
+        return last_real_token_head(cfg, hidden, attention_mask)
 
 
 #: What ``models.family_module`` hands out of this module.

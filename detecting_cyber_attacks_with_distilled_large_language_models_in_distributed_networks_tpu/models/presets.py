@@ -14,13 +14,22 @@ table: the CLI hands every preset its tokenizer's size (148 ids for the
 domain WordPiece), and sizing ``distilbert`` by it used to build a 43 M
 model under the name of a 66 M one. Only the ``tiny`` presets, which stand
 for no published model, are sized by the tokenizer.
+
+| preset | class | what it is |
+| --- | --- | --- |
+| ``tiny`` / ``distilbert`` / ``bert`` / ``bert-large`` | ``models/distilbert.py`` | the BERT encoder ladder; published weights load through ``models/hf_convert.py`` |
+| ``kimi-linear-tiny`` / ``kimi-linear-ep32`` | ``models/kimi_linear.py`` | KDA + NoPE latent attention + experts; ``ep32``: one of 32 chips' share of Kimi-Linear-48B-A3B, windows of 28 flows to 4,096 tokens |
+| ``laguna-xs2-tiny`` / ``laguna-xs2-ep8`` | ``models/laguna.py`` | window-512 and full attention with rotary positions, grouped heads, a gate a head, experts; ``ep8``: one of 8 chips' share of Laguna-XS.2, windows of 56 flows to 8,192 tokens |
+
+Neither decoder class has a converter from its published tensor names yet:
+their weights are random from the seed.
 """
 
 from __future__ import annotations
 
 from typing import Any, Callable
 
-from ..config import KimiLinearConfig, ModelConfig
+from ..config import KimiLinearConfig, LagunaConfig, ModelConfig
 
 #: name -> config factory. Ordered small -> large so help strings
 #: and error messages read as the scale ladder.
@@ -31,16 +40,20 @@ PRESETS: dict[str, Callable[..., Any]] = {
     "bert-large": ModelConfig.bert_large,
     "kimi-linear-tiny": KimiLinearConfig.tiny,
     "kimi-linear-ep32": KimiLinearConfig.ep32_cut,
+    "laguna-xs2-tiny": LagunaConfig.tiny,
+    "laguna-xs2-ep8": LagunaConfig.ep8_cut,
 }
 
 #: Presets that stand for no published model: their table is the tokenizer's.
-TOKENIZER_SIZED = ("tiny", "kimi-linear-tiny")
+TOKENIZER_SIZED = ("tiny", "kimi-linear-tiny", "laguna-xs2-tiny")
 
 #: DataConfig fields a preset's rows need (``cli/common.py::resolve_config``):
 #: a long-context preset reads windows of consecutive flows, not single flows.
 PRESET_DATA: dict[str, dict[str, Any]] = {
     "kimi-linear-tiny": {"window_flows": 2},
     "kimi-linear-ep32": {"window_flows": 28},
+    "laguna-xs2-tiny": {"window_flows": 2},
+    "laguna-xs2-ep8": {"window_flows": 56},
 }
 
 

@@ -1,7 +1,8 @@
 """Tier-1 hook for the benchmark's model-family seam (PR 27 could not add a
 file under ``tests/``): the cases of ``benchmark/selftest/test_families.py``
-run here as they are, plus cases for the second family the benchmark now has
-(``kimi_linear``) through the same seam, and the new cell's CPU rehearsal.
+run here as they are, plus cases for the second and the third family the
+benchmark now has (``kimi_linear``, ``laguna``) through the same seam, and
+their cells' CPU rehearsals.
 
 Two of the selftest's cases quote a table of the BERT configurations only
 (``QUOTED``; ``family == "bert_encoder"`` for every configuration): they are
@@ -22,11 +23,13 @@ from benchmark.selftest import test_families as _cases
 from benchmark.selftest.test_families import *  # noqa: F401,F403  (the cases and their fixture)
 
 from benchmark import families, harness
-from benchmark.families import kimi_linear
+from benchmark.families import kimi_linear, laguna
 
 ROOT = _cases.ROOT
 KIMI = "kimi-linear-48b-a3b-ep32"
 CELL = "kimilinear-window-fit-l4k"
+LAGUNA = "laguna-xs2-ep8"
+LAGUNA_CELL = "laguna-window-fit-l8k"
 
 
 @pytest.mark.parametrize("name", [n for n in _cases.CONFIGS if n in _cases.QUOTED])
@@ -35,7 +38,7 @@ def test_counts_are_the_quoted(name):
 
 
 def test_every_configuration_names_a_family_that_is_there():
-    assert KIMI in _cases.CONFIGS
+    assert KIMI in _cases.CONFIGS and LAGUNA in _cases.CONFIGS
     for name in _cases.CONFIGS:
         conf = harness.load_json("configs", f"{name}.json")
         assert os.path.isfile(os.path.join(ROOT, "benchmark", "families", f"{conf['family']}.py"))
@@ -91,17 +94,23 @@ def test_kimi_tiny_is_the_tiny_preset():
     assert {cfg.mixer(i) for i in range(cfg.n_layers)} == {"kda", "mla"}
 
 
-def _kimi_context(monkeypatch, name, **overrides):
+def _family_context(monkeypatch, family, config, name, **overrides):
+    """A context whose family is ``family`` with ``overrides`` in place, under
+    the name ``name`` beside it, with the weights and 8 tokenised flows."""
     mod = types.ModuleType(f"benchmark.families.{name}")
-    mod.__dict__.update({k: v for k, v in vars(kimi_linear).items() if not k.startswith("__")})
+    mod.__dict__.update({k: v for k, v in vars(family).items() if not k.startswith("__")})
     mod.__dict__.update(overrides)
     monkeypatch.setitem(sys.modules, mod.__name__, mod)
-    conf = {**harness.load_json("configs", f"{KIMI}.json"), "family": name}
+    conf = {**harness.load_json("configs", f"{config}.json"), "family": name}
     ctx = _cases.context(conf)
     tok = harness.pkg("data").default_tokenizer()
     _, split = harness.tokenised_flows(ctx, 8, ctx.seed, tok)
     params = harness.init_params_on_device(ctx.family, ctx.model_config(), ctx.seed, "threefry2x32")
     return ctx, params, split
+
+
+def _kimi_context(monkeypatch, name, **overrides):
+    return _family_context(monkeypatch, kimi_linear, KIMI, name, **overrides)
 
 
 def test_kimi_program_agrees_with_its_reference_through_check_model(monkeypatch):
@@ -207,10 +216,11 @@ def test_a_cache_too_small_for_the_cell_is_left_alone():
         jax.config.update("jax_compilation_cache_max_size", was[1])
 
 
-def test_the_new_cells_rehearsal_ends_in_a_result_line():
+@pytest.mark.parametrize("cell", [CELL, LAGUNA_CELL])
+def test_the_new_cells_rehearsal_ends_in_a_result_line(cell):
     env = {**os.environ, "JAX_PLATFORMS": "cpu", "JAX_ENABLE_COMPILATION_CACHE": "0"}
     done = subprocess.run(
-        [sys.executable, os.path.join(ROOT, "benchmark", "run.py"), "--workload", CELL, "--rehearse-cpu"],
+        [sys.executable, os.path.join(ROOT, "benchmark", "run.py"), "--workload", cell, "--rehearse-cpu"],
         capture_output=True, text=True, timeout=600, env=env, cwd=ROOT,
     )
     assert done.returncode == 0, done.stderr[-2000:]
@@ -252,3 +262,144 @@ def test_scope_time_is_the_union_of_the_events_under_the_scope():
     # without the programs' texts (the parent's program, another driver) there is nothing to read
     ctx.rec.data.pop("scope_ops"), ctx.rec.data.pop("hlo_texts")
     assert xplane_scope_share.read(ctx, scope="moe") is None
+
+
+# ------------------------------------------------- the third family's cases
+def test_laguna_family_loads_and_counts_what_the_program_builds():
+    import jax
+
+    conf = harness.load_json("configs", f"{LAGUNA}.json")
+    family, model = families.load(conf), conf["model"]
+    assert family is laguna
+    cfg = family.model_config(model)
+    built = jax.eval_shape(lambda: family.init_params(cfg, jax.random.key(0)))
+    n = sum(int(np.prod(x.shape)) for x in jax.tree.leaves(built))
+    assert n == family.param_count(model) == conf["parameters"] == 665_937_922
+    assert set(built) == {"encoder", "classifier"}
+    assert family.train_step_bytes(model, steps=8) == 8 * 32.0 * n
+    # every width as published; the cuts are the six the file lists, and the manifest's
+    src = conf["source_config"]
+    assert (cfg.dim, cfg.hidden_dim, cfg.expert_dim, cfg.shared_dim) == (
+        src["hidden_size"], src["intermediate_size"], src["moe_intermediate_size"], src["shared_expert_intermediate_size"],
+    )
+    assert (cfg.n_kv_heads, cfg.head_dim, cfg.sliding_window) == (src["num_key_value_heads"], src["head_dim"], src["sliding_window"])
+    assert (cfg.n_experts, cfg.experts_per_token, cfg.routed_scale) == (256, src["num_experts_per_tok"], src["moe_routed_scaling_factor"])
+    full, sliding = src["rope_parameters"]["full_attention"], src["rope_parameters"]["sliding_attention"]
+    assert (cfg.full_rope_theta, cfg.full_rotary_share, cfg.full_rope_factor, cfg.full_rope_original_len) == (
+        full["rope_theta"], full["partial_rotary_factor"], full["factor"], full["original_max_position_embeddings"],
+    )
+    assert (cfg.full_rope_beta_fast, cfg.full_rope_beta_slow, cfg.full_rope_attention_factor) == (
+        full["beta_fast"], full["beta_slow"], full["attention_factor"],
+    )
+    assert (cfg.sliding_rope_theta, cfg.sliding_rotary_share) == (sliding["rope_theta"], sliding["partial_rotary_factor"])
+    kinds = {"full_attention": "full", "sliding_attention": "sliding"}
+    assert cfg.layer_types == tuple(kinds[k] for k in conf["layer_types"]) == tuple(kinds[k] for k in src["layer_types"][:5])
+    assert cfg.heads_per_layer == tuple(conf["num_attention_heads_per_layer"]) == tuple(src["num_attention_heads_per_layer"][:5])
+    assert cfg.ffn_types == tuple(conf["mlp_layer_types"]) == tuple(src["mlp_layer_types"][:5])
+    assert (cfg.n_layers, cfg.experts_held, cfg.vocab_size) == (conf["num_hidden_layers"], conf["num_experts"], conf["vocab_size"]) == (5, 32, 12544)
+    assert all(conf[k] == src[k] for k in src if k not in conf["reduced"])
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        entry = next(c for c in json.load(f)["configs"] if c["name"] == LAGUNA)
+    assert entry["reduced"] == conf["reduced"] and entry["source"] == conf["source"]
+    # the program's own preset is this configuration
+    assert harness.pkg("models").model_preset("laguna-xs2-ep8") == cfg
+
+
+def test_laguna_counts_the_band_and_the_slots_really_routed():
+    model = harness.load_json("configs", f"{LAGUNA}.json")["model"]
+    fam = laguna
+    L = 8192
+    assert fam.score_keys(model, "full", L) == L * (L + 1) / 2
+    assert fam.score_keys(model, "sliding", L) == sum(min(i + 1, 512) for i in range(L))
+    assert fam.score_keys(model, "sliding", 300) == fam.score_keys(model, "full", 300)  # a row inside its window
+    mean = fam.train_step_flops(model, 2)
+    assert 2.2e9 < mean / (2 * L) < 2.3e9  # about 2.25 GFLOP a token to train
+    base = fam.train_step_flops(model, 16, steps=8, tokens=16 * L, routed_slots_here=0)
+    more = fam.train_step_flops(model, 16, steps=8, tokens=16 * L, routed_slots_here=262144)
+    assert 0 < base < more and more - base == 3 * 262144 * 6 * 2048 * 512
+    f0, b0 = fam.scope_work(model, "moe/experts", tokens=16.0 * L, steps=8, routed_slots_here=1000)
+    f1, b1 = fam.scope_work(model, "moe/experts", tokens=16.0 * L, steps=8, routed_slots_here=2000)
+    assert 0 < f0 < f1 and 0 < b0 < b1
+    # a sliding layer's scores are counted over the band: a program that scores every key reads low
+    fw, bw = fam.scope_work(model, "attn/window/scores", tokens=16.0 * L, rows=16)
+    ff, bf = fam.scope_work(model, "attn/full/scores", tokens=16.0 * L, rows=16)
+    assert fw == 3 * 16 * fam.score_keys(model, "sliding", L) * (3 * 64) * 4 * 128
+    assert ff == 3 * 16 * fam.score_keys(model, "full", L) * (2 * 48) * 4 * 128
+    per_layer = (fw / 3) / (ff / 2)
+    assert 0.15 < per_layer < 0.17  # 496 of 4,096 keys at 64 heads against 48
+    assert bw > 0 and bf > 0 and fam.scope_work(model, "attn/window", tokens=1.0) is None
+    assert fam.scope_work(model, "attn/full/scores", tokens=16.0 * L) == (ff, bf)  # without the rows: max_len
+
+
+def test_laguna_tiny_is_the_tiny_preset():
+    model = harness.load_json("configs", f"{LAGUNA}.json")["model"]
+    cfg = laguna.model_config(laguna.tiny(model))
+    assert cfg.dim == 32 and cfg.remat is True and cfg.routed_scale == 2.5 and cfg.rms_norm_eps == 1e-6
+    assert set(cfg.layer_types) == {"full", "sliding"} and set(cfg.ffn_types) == {"dense", "sparse"}
+    assert cfg.sliding_window < cfg.max_len and len(set(cfg.heads_per_layer)) == 2
+
+
+def _laguna_context(monkeypatch, name, **overrides):
+    return _family_context(monkeypatch, laguna, LAGUNA, name, **overrides)
+
+
+def test_laguna_program_agrees_with_its_reference_through_check_model(monkeypatch):
+    ctx, params, split = _laguna_context(monkeypatch, "standin_l")
+    harness.check_model(ctx, params, split, what="laguna", key="l", bind=True, n=4)
+    assert not ctx.problems, ctx.problems
+    assert ctx.compared["l.hidden_rel"][0] < 1e-4
+    assert ctx.compared["l.hidden_rel"][1] == laguna.TOLERANCES["hidden_rel"]
+
+
+@pytest.mark.parametrize("fault", _cases.FAULTS)
+def test_a_planted_fault_in_the_laguna_program_is_not_correct(monkeypatch, fault):
+    alter, says = _cases.FAULTS[fault]
+
+    def program(model_cfg):
+        forward = laguna.program(model_cfg)
+        return lambda p, i, a: alter(*forward(p, i, a))
+
+    ctx, params, split = _laguna_context(monkeypatch, "standin_g", program=program)
+    harness.check_model(ctx, params, split, what="faulty", key="l", bind=True, n=4)
+    assert any(says in p for p in ctx.problems), (ctx.problems, ctx.compared)
+
+
+@pytest.mark.parametrize("fault", ["none", "mask-only", "no-rotation", "wrong-group"])
+def test_a_fault_in_what_laguna_adds_is_not_correct(monkeypatch, fault):
+    """Three faults in the mechanisms this family brought, each planted in
+    the program's own forward at the tiny preset: a sliding layer that
+    attends to its whole past, positions left unrotated, and query heads
+    reading the wrong key head. Each leaves shapes and finiteness alone and
+    fails the comparison with the reference, which the program as it is
+    passes on the same weights."""
+    ops = harness.pkg("ops.causal_attention")
+    model_mod = harness.pkg("models.laguna")
+    if fault == "mask-only":
+        real = ops.causal_attention
+        monkeypatch.setattr(model_mod, "causal_attention", lambda q, k, v, m, window=None: real(q, k, v, m, None))
+    elif fault == "no-rotation":
+        monkeypatch.setattr(model_mod, "apply_rope", lambda x, cos, sin: x)
+    elif fault == "wrong-group":
+        real = ops.causal_attention
+        monkeypatch.setattr(
+            model_mod, "causal_attention", lambda q, k, v, m, window=None: real(q, k[:, ::-1], v[:, ::-1], m, window)
+        )
+    laguna.program.cache_clear()
+    try:
+        ctx, params, split = _laguna_context(monkeypatch, f"standin_{fault[:2]}")
+        # At 32 dimensions and weights of 0.02 every score is near 0 and a row
+        # attends evenly wherever it may: the queries and keys are made as
+        # large as the published widths make them, so that whom a token
+        # attends to matters.
+        import jax
+
+        params = jax.tree_util.tree_map_with_path(
+            lambda path, x: x * 30.0 if any(getattr(k, "key", "") in ("q_proj", "k_proj") for k in path) else x, params
+        )
+        harness.check_model(ctx, params, split, what=fault, key="l", bind=True, n=4)
+        if fault == "none":
+            assert not ctx.problems and ctx.compared["l.hidden_rel"][0] < 1e-4, (ctx.problems, ctx.compared)
+        else:
+            assert any("hidden states differ" in p for p in ctx.problems), (ctx.problems, ctx.compared)
+    finally:
+        laguna.program.cache_clear()

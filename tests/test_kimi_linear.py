@@ -303,46 +303,50 @@ def test_no_token_is_dropped_when_every_token_goes_to_held_experts():
         assert _rel(a, b) < 1e-5
 
 
-def test_the_shares_add_up_to_the_uncut_layer(tiny_params):
-    """The guide's share test: 4 shares of 4 of the 16 experts. The routed
+@pytest.mark.parametrize("which, shares", [("kimi_linear", 4), ("laguna", 8)])
+def test_the_shares_add_up_to_the_uncut_layer(tiny_params, which, shares):
+    """The guide's share test, for both decoder classes (they share the
+    layer, ``models/blocks.py``): 4 shares of 4 of the 16 experts, and the 8
+    shares of 2 that the 8 chips of the Laguna deployment hold. The routed
     parts all the shares give, with the shared expert counted once, add up to
     what the uncut reference gives for the whole layer."""
-    whole = TINY.replace(experts_held=TINY.n_experts)
-    m = _model_dict(whole)
+    from benchmark.reference import laguna_fp32
+    from detecting_cyber_attacks_with_distilled_large_language_models_in_distributed_networks_tpu.config import LagunaConfig
+
+    cfg, reference = {"kimi_linear": (TINY, ref), "laguna": (LagunaConfig.tiny(max_len=64), laguna_fp32)}[which]
+    E, held = cfg.n_experts, cfg.n_experts // shares
+    m = {**dataclasses.asdict(cfg), "experts_held": E}
     rng = np.random.default_rng(5)
-    lp = jax.tree.map(np.asarray, tiny_params["encoder"]["layer_1"]["moe"])
-    D, F, E = TINY.dim, TINY.expert_dim, TINY.n_experts
+    params = tiny_params if cfg is TINY else init_params(build_classifier(cfg), cfg, jax.random.key(1))
+    lp = jax.tree.map(np.asarray, params["encoder"]["layer_1"]["moe"])
+    D, F = cfg.dim, cfg.expert_dim
     full = {
         **lp,
         **dict(zip(("experts_gate", "experts_up", "experts_down"), _experts(rng, E, D, F))),
     }
     x = rng.normal(size=(60, D)).astype(np.float32)
     with jax.default_matmul_precision("highest"):
-        want, _ = ref._moe(jnp.asarray(x), full, m, lambda a: a)
-        shared = ref._swiglu(jnp.asarray(x), full["shared"], lambda a: a)
+        want, _ = reference._moe(jnp.asarray(x), full, m, lambda a: a)
+        shared = reference._swiglu(jnp.asarray(x), full["shared"], lambda a: a)
     scores = jax.nn.sigmoid(x @ full["router"])
-    idx, w = route_topk(scores, full["select_bias"], TINY.experts_per_token, TINY.routed_scale)
+    idx, w = route_topk(scores, full.get("select_bias", 0.0), cfg.experts_per_token, cfg.routed_scale)
+    part_of = lambda lo: held_experts_ffn(  # noqa: E731
+        x, idx, w, np.ones(60, bool), full["experts_gate"][lo : lo + held], full["experts_up"][lo : lo + held],
+        full["experts_down"][lo : lo + held], offset=lo, capacity=60 * cfg.experts_per_token, dtype=jnp.float32,
+    )
     total, seen = shared, 0
-    for share in range(E // 4):
-        lo = 4 * share
-        y, slots, overflow = held_experts_ffn(
-            x, idx, w, np.ones(60, bool), full["experts_gate"][lo : lo + 4], full["experts_up"][lo : lo + 4],
-            full["experts_down"][lo : lo + 4], offset=lo, capacity=240, dtype=jnp.float32,
-        )
+    for share in range(shares):
+        y, slots, overflow = part_of(held * share)
         assert int(overflow) == 0
         total, seen = total + y, seen + int(slots.sum())
-    assert seen == 60 * TINY.experts_per_token  # every slot lands on exactly one share
+    assert seen == 60 * cfg.experts_per_token  # every slot lands on exactly one share
     assert float(jnp.abs(total - want).max()) < 1e-5
     # and one share alone is what the reference gives when it is given that share
-    part, _ = ref._moe(
-        jnp.asarray(x), {**full, **{k: full[k][4:8] for k in ("experts_gate", "experts_up", "experts_down")}},
-        {**m, "experts_held": 4, "expert_offset": 4}, lambda a: a,
+    part, _ = reference._moe(
+        jnp.asarray(x), {**full, **{k: full[k][held : 2 * held] for k in ("experts_gate", "experts_up", "experts_down")}},
+        {**m, "experts_held": held, "expert_offset": held}, lambda a: a,
     )
-    y, _, _ = held_experts_ffn(
-        x, idx, w, np.ones(60, bool), full["experts_gate"][4:8], full["experts_up"][4:8],
-        full["experts_down"][4:8], offset=4, capacity=240, dtype=jnp.float32,
-    )
-    assert float(jnp.abs(shared + y - part).max()) < 1e-5
+    assert float(jnp.abs(shared + part_of(held)[0] - part).max()) < 1e-5
 
 
 # ------------------------------------------- the program and the reference

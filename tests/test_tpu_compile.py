@@ -1,4 +1,5 @@
-"""The Pallas kernels of a benchmark cell's path, compiled at the cell's
+"""The kernels of a benchmark cell's path (the Pallas ones, and the blocked
+attention that is plain XLA), compiled at the cell's
 widths for the chip the cells run on, without the chip: the installed TPU
 compiler refuses here what it would refuse there (a block that does not fit
 the tiling, more VMEM than a kernel may use). It compiles, it does not run:
@@ -14,6 +15,10 @@ import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
 from detecting_cyber_attacks_with_distilled_large_language_models_in_distributed_networks_tpu.ops import kda
+from detecting_cyber_attacks_with_distilled_large_language_models_in_distributed_networks_tpu.ops.causal_attention import (
+    BLOCK,
+    causal_attention,
+)
 
 
 @pytest.fixture(scope="module")
@@ -62,3 +67,28 @@ def test_the_undifferentiated_kda_forward_compiles_for_the_v5e_at_the_cells_widt
     assert text.count('custom_call_target="tpu_custom_call"') == 1 and "kda_fwd" in text
     assert "triangular" not in text and "while" not in text  # nothing of the XLA half, no loop over the rows
     assert compiled.memory_analysis().temp_size_in_bytes == 0  # the operands are read in place
+
+
+@pytest.mark.parametrize("kind, heads, window", [("window", 64, 512), ("full", 48, None)])
+def test_the_blocked_attention_compiles_for_the_v5e_at_the_cells_widths(one_chip, kind, heads, window):
+    """A step's batch of ``laguna-window-fit-l8k`` as a layer passes it: 2
+    rows of 8,192 tokens, 64 or 48 query heads over 8 key/value heads of 128,
+    bfloat16, forward and gradient. It fits beside the cell's 8 GB of state
+    (the scores of one block, not of a row), and the sliding layer's score
+    products meet 768 keys, not the row's 8,192."""
+    import re
+
+    B, L, Hkv, d = 2, 8192, 8, 128
+    arg = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)  # noqa: E731
+    mask = jax.ShapeDtypeStruct((B, L), jnp.int32, sharding=one_chip)
+
+    def both(q, k, v, mask):
+        loss = lambda q, k, v: causal_attention(q, k, v, mask, window).astype(jnp.float32).sum()  # noqa: E731
+        return jax.value_and_grad(loss, (0, 1, 2))(q, k, v)
+
+    compiled = jax.jit(both).lower(arg(B, heads, L, d), arg(B, Hkv, L, d), arg(B, Hkv, L, d), mask).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 4e9
+    # the widest array a score product of the program writes: [B, Hkv, G * BLOCK, keys]
+    rows = heads // Hkv * BLOCK
+    keys = {int(m.group(1)) for m in re.finditer(rf"\[{B},{Hkv},{rows},(\d+)\]", compiled.as_text())} - {d}
+    assert keys and max(keys) == (BLOCK + 512 if window else L), sorted(keys)
