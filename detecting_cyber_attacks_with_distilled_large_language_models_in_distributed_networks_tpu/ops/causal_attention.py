@@ -1,14 +1,28 @@
-"""Causal softmax attention in query blocks: for heads whose query/key width
-differs from their value width (latent attention: 192 against 128), for query
-heads that share a key/value head in groups, and for a sliding window.
+"""Causal softmax attention: for heads whose query/key width differs from
+their value width (latent attention: 192 against 128), for query heads that
+share a key/value head in groups, and for a sliding window.
 
-``ops/flash_attention.py`` takes a key-position bias only and one head width,
-and the dot path would hold ``[B, H, L, L]`` scores (4.3 GB in bf16 at
-``[4, 32, 4096, 4096]``). Here the queries go in blocks of :data:`BLOCK` rows,
-so the largest array is one block's ``[B, H, BLOCK, keys]`` scores; each block
-is checkpointed, so the backward pass recomputes a block's scores instead of
-keeping every block's. Plain XLA: scores and softmax in float32, products in
-the inputs' type.
+Which path runs is decided by the shapes, by nothing a caller sets:
+
+* **no window, a length that tiles** (``ops/flash_attention.py::causal_tile``:
+  a multiple of 128 whose head fits the kernels' VMEM; the window cells' 8,192
+  and 4,096 tokens): the Pallas kernels ``flash_fwd`` / ``flash_bwd``
+  (``ops/flash_attention.py``), under the named scope :data:`KERNEL_SCOPE`. A
+  query tile's scores, softmax and product with the values stay in VMEM, key
+  tile by key tile with the running maximum and sum; key tiles after a query
+  tile are not visited; the backward pass recomputes score tiles from ``q``,
+  ``k``, the key mask and the rows' log-sum-exp. The result and the
+  log-sum-exp carry :data:`ATTENTION_RESULT` as their ``checkpoint_name``.
+* **a window, or any other length** (the tiny presets of the CPU tests): the
+  XLA query blocks below. (A window of 512 in the kernels measured nothing of
+  the Laguna cell's step for 0.8 GB more of temporaries: PERF.md, PR 33.)
+
+The XLA blocks: the dot path would hold ``[B, H, L, L]`` scores (4.3 GB in
+bf16 at ``[4, 32, 4096, 4096]``). Here the queries go in blocks of
+:data:`BLOCK` rows, so the largest array is one block's ``[B, H, BLOCK,
+keys]`` scores; each block is checkpointed, so the backward pass recomputes a
+block's scores instead of keeping every block's. Plain XLA: scores and softmax
+in float32, products in the inputs' type.
 
 Which keys a block meets is cut in the program, not only masked:
 
@@ -25,10 +39,10 @@ Which keys a block meets is cut in the program, not only masked:
   them run as ONE ``lax.map``.
 
 Grouped heads: ``k`` and ``v`` may have fewer heads than ``q``; query head
-``h`` reads key head ``h // (H / Hkv)``. The group's query heads are folded
-into the block's rows (``[B, Hkv, G * BLOCK, d]`` against ``[B, Hkv, keys,
-d]``), so no key is repeated in memory and the products are as many times
-taller.
+``h`` reads key head ``h // (H / Hkv)``. The XLA blocks fold the group's query
+heads into the block's rows (``[B, Hkv, G * BLOCK, d]`` against ``[B, Hkv,
+keys, d]``), the kernels divide the head index in the key block's index map;
+neither repeats a key in memory.
 """
 
 from __future__ import annotations
@@ -37,18 +51,24 @@ import jax
 import jax.numpy as jnp
 
 from .attention import NEG_INF
+from .flash_attention import causal_flash_attention, causal_tile
 
 #: Query rows a block: an implementation size (the scores of a block of a
 #: 4,096-token row at batch 4 and 32 heads are 0.5 GB in float32).
 BLOCK = 256
 #: Blocks a ``lax.map``: they share one key length, their group's end.
 GROUP = 4
-#: The name a caller may give an attention's result (``checkpoint_name``). A
-#: stack that recomputes each layer in the backward pass and keeps the values
-#: under this name (``models/blocks.py::decoder``) does not run a layer's
-#: blocks a second time only to have their result again: the blocks' own
-#: checkpoints need the layer's q, k and v, not their result.
+#: The name of an attention's result (``checkpoint_name``): the kernel path
+#: gives it to its result and its rows' log-sum-exp itself, a caller may give
+#: it to the blocks' result. A stack that recomputes each layer in the backward
+#: pass and keeps the values under this name (``models/blocks.py::decoder``)
+#: does not run a layer's attention a second time only to have them again: the
+#: blocks' own checkpoints need the layer's q, k and v, not their result, and
+#: the backward kernel needs the result and the log-sum-exp.
 ATTENTION_RESULT = "attention_result"
+#: The ``jax.named_scope`` the Pallas kernels run under, whoever calls: the
+#: benchmark's ``attn_kernel_share`` reads the device time under it.
+KERNEL_SCOPE = "causal_flash"
 
 
 def causal_attention(q, k, v, key_mask, window: int | None = None):
@@ -56,14 +76,19 @@ def causal_attention(q, k, v, key_mask, window: int | None = None):
     query and, with ``window``, fewer than ``window`` positions before it.
     ``q``: ``[B, H, L, dqk]``; ``k``: ``[B, Hkv, L, dqk]``; ``v``: ``[B, Hkv,
     L, dv]`` (``Hkv`` divides ``H``); ``key_mask``: ``[B, L]``, 1 on real
-    tokens. Returns ``[B, H, L, dv]`` in ``q``'s type. Any ``L``: the last
-    group is the shorter one, and its last block is padded with query rows
-    that are cut off again."""
+    tokens. Returns ``[B, H, L, dv]`` in ``q``'s type. Any ``L``: one that
+    tiles takes the kernels (no window); of the blocks the last group is the
+    shorter one, and its last block is padded with query rows that are cut off
+    again."""
     B, H, L, _ = q.shape
     Hkv = k.shape[1]
     G = H // Hkv
-    scale = q.shape[-1] ** -0.5
     pad_bias = (1.0 - key_mask.astype(jnp.float32)) * NEG_INF  # [B, L]
+    tile = None if window is not None else causal_tile(L, q.shape[3], v.shape[3], q.dtype.itemsize)
+    if tile is not None:
+        with jax.named_scope(KERNEL_SCOPE):
+            return causal_flash_attention(q, k, v, pad_bias, tile, ATTENTION_RESULT)
+    scale = q.shape[-1] ** -0.5
     # A windowed row's block, and the keys it meets before its own rows', in whole blocks.
     block_w = min(BLOCK, L)
     before = None if window is None else -(-(window - 1) // block_w) * block_w

@@ -141,6 +141,40 @@ def test_grouped_heads_are_explicitly_repeated_keys(H, Hkv, window):
         assert a.shape == b.shape == (2, Hkv, L, a.shape[-1]) and _rel(a, b) < 1e-5
 
 
+@pytest.mark.parametrize("mask_kind", ["right-padded", "holes"])
+@pytest.mark.parametrize(
+    "L, H, Hkv, dqk, dv",
+    [(384, 4, 4, 24, 16), (384, 6, 1, 16, 16), (384, 8, 1, 16, 16), (1024, 4, 2, 16, 8), (BLOCK * GROUP + 76, 6, 1, 16, 16)],
+    ids=["one-a-key-two-widths", "6-a-key", "8-a-key", "two-tiles-of-512", "no-tile-takes-the-blocks"],
+)
+def test_the_causal_kernels_are_the_plain_softmax(L, H, Hkv, dqk, dv, mask_kind):
+    """A length that tiles takes the Pallas kernels (interpreted here): three
+    tiles of 128 or two of 512, so that a query tile has key tiles it skips, a
+    masked diagonal one and full ones before it; one group with ``dqk != dv``,
+    six and eight query heads over one shared key head, a right-padded and a
+    non-contiguous key mask; result over the real tokens and all three
+    gradients. A length that does not tile takes the XLA blocks and agrees."""
+    rng = np.random.default_rng(7)
+    normal = lambda *shape: rng.normal(size=shape).astype(np.float32)  # noqa: E731
+    q, k, v = normal(2, H, L, dqk), normal(2, Hkv, L, dqk), normal(2, Hkv, L, dv)
+    if mask_kind == "holes":
+        mask = (rng.random((2, L)) > 0.3).astype(np.int32)
+    else:
+        mask = (np.arange(L)[None, :] < np.array([L, L - 21])[:, None]).astype(np.int32)
+    w = mask[:, None, :, None]
+    took_the_kernels = "pallas_call" in str(jax.make_jaxpr(lambda *a: causal_attention(*a, mask))(q, k, v))
+    assert took_the_kernels == (L % 128 == 0)
+    got = causal_attention(q, k, v, mask)
+    assert got.shape == (2, H, L, dv)
+    assert float(jnp.abs((got - _plain_attention(q, k, v, mask)) * w).max()) < 1e-5
+    f = lambda fn: (lambda *a: ((fn(*a) * w) ** 2).sum())  # noqa: E731
+    for a, b in zip(
+        jax.grad(f(lambda *a: causal_attention(*a, mask)), (0, 1, 2))(q, k, v),
+        jax.grad(f(lambda *a: _plain_attention(*a, mask)), (0, 1, 2))(q, k, v),
+    ):
+        assert a.shape == b.shape and _rel(a, b) < 1e-5
+
+
 def _causal_attention_of_pr_31(q, k, v, key_mask):
     """``ops/causal_attention.py::causal_attention`` as the latent attention
     called it before it took grouped heads and a window (PR 31's tree)."""
@@ -177,7 +211,11 @@ def _causal_attention_of_pr_31(q, k, v, key_mask):
 def test_the_latent_attentions_call_lowers_to_the_program_it_lowered_to(grad):
     """One group, 192-wide keys and 128-wide values, no window, a length with
     a padded tail: the lowered text of the edited function is, character for
-    character, that of the function the Kimi cell was measured with."""
+    character, that of the function the Kimi cell was measured with until
+    PR 33. Since then the cell's 4,096 tokens tile and take the Pallas
+    kernels; this length does not, so this is the guard of the fallback: the
+    XLA blocks a length that does not tile takes are those that were
+    measured."""
     B, H, L = 2, 4, BLOCK * GROUP + 300
     bf16 = jnp.bfloat16
     args = (
@@ -347,6 +385,57 @@ def test_program_in_bf16_is_within_the_familys_limits(tiny_params):
     tol = family.TOLERANCES
     assert 1e-4 < err < tol["hidden_rel"], err
     assert float(jnp.abs(logits - want_z).max()) / family.logit_scale(tiny_params, np.asarray(want_z)) < tol["logit_rel"]
+
+
+@pytest.mark.parametrize("program", ["train_step", "eval_step"])
+@pytest.mark.parametrize("which", ["laguna", "kimi_linear"])
+def test_a_causal_layer_launches_one_forward_and_one_backward_kernel(which, program):
+    """Did the mechanism engage, and only once: at a length that tiles (128)
+    ``engine.train_step`` holds, for every full-attention layer of the Laguna
+    class (a sliding layer none) and for the Kimi class's latent attention,
+    ONE ``flash_fwd`` and ONE ``flash_bwd`` under the scope ``causal_flash``
+    (under ``attn/full/scores`` and ``mla``, where the benchmark's shares
+    look). The backward kernel
+    is inside the layer's recomputation, the forward one is not: the result
+    and the rows' log-sum-exp are kept by name across it
+    (``ATTENTION_RESULT``), so no layer runs its forward kernel twice.
+    ``engine.eval_step`` holds the forward kernel alone."""
+    import collections
+
+    from detecting_cyber_attacks_with_distilled_large_language_models_in_distributed_networks_tpu.config import (
+        KimiLinearConfig,
+    )
+
+    if which == "laguna":
+        cfg = LagunaConfig.tiny(max_len=128).replace(remat=True)
+        under = {
+            i: f"layer_{i}/attn/full/scores/causal_flash/" for i, kind in enumerate(cfg.layer_types) if kind == "full"
+        }
+        assert set(cfg.layer_types) == {"full", "sliding"}
+    else:
+        cfg = KimiLinearConfig.tiny(max_len=128).replace(remat=True)
+        under = {i: f"layer_{i}/mla/mla/causal_flash/" for i in range(cfg.n_layers) if cfg.mixer(i) == "mla"}
+    ids, mask = _rows(cfg, [128, 100, 80, 70])
+    batch = {"input_ids": ids, "attention_mask": mask, "labels": np.array([0, 1, 0, 1], np.int32)}
+    trainer = Trainer(cfg, TrainConfig(log_every=0), pad_id=0)
+    state = trainer.init_state(seed=0, params=init_params(build_classifier(cfg), cfg, jax.random.key(1)))
+    if program == "train_step":
+        jaxpr = jax.make_jaxpr(trainer.train_step.__wrapped__)(state, batch)
+    else:
+        jaxpr = jax.make_jaxpr(trainer.eval_step.__wrapped__)(state.params, batch, np.ones(len(ids), bool))
+    launches = [
+        (eqn.params["name"], path, "remat2" in outer)
+        for path, outer, eqn in _eqns(jaxpr.jaxpr)
+        if eqn.primitive.name == "pallas_call" and eqn.params["name"].startswith("flash_")
+    ]
+    n = len(under)
+    grad = program == "train_step"
+    kernels = collections.Counter(name for name, _, _ in launches)
+    assert n and kernels == ({"flash_fwd": n, "flash_bwd": n} if grad else {"flash_fwd": n}), kernels
+    for scope in under.values():
+        assert sum(scope in path for _, path, _ in launches) == (2 if grad else 1), (scope, launches)
+    if grad:
+        assert all(recomputed == (name == "flash_bwd") for name, _, recomputed in launches), launches
 
 
 def test_remat_changes_no_number_and_keeps_the_routers_choice(tiny_params):

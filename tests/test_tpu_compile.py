@@ -1,5 +1,5 @@
 """The kernels of a benchmark cell's path (the Pallas ones, and the blocked
-attention that is plain XLA), compiled at the cell's
+sliding-window attention that is plain XLA), compiled at the cell's
 widths for the chip the cells run on, without the chip: the installed TPU
 compiler refuses here what it would refuse there (a block that does not fit
 the tiling, more VMEM than a kernel may use). It compiles, it does not run:
@@ -69,16 +69,33 @@ def test_the_undifferentiated_kda_forward_compiles_for_the_v5e_at_the_cells_widt
     assert compiled.memory_analysis().temp_size_in_bytes == 0  # the operands are read in place
 
 
-@pytest.mark.parametrize("kind, heads, window", [("window", 64, 512), ("full", 48, None)])
-def test_the_blocked_attention_compiles_for_the_v5e_at_the_cells_widths(one_chip, kind, heads, window):
-    """A step's batch of ``laguna-window-fit-l8k`` as a layer passes it: 2
-    rows of 8,192 tokens, 64 or 48 query heads over 8 key/value heads of 128,
-    bfloat16, forward and gradient. It fits beside the cell's 8 GB of state
-    (the scores of one block, not of a row), and the sliding layer's score
-    products meet 768 keys, not the row's 8,192."""
+@pytest.mark.parametrize(
+    "kind, B, heads, Hkv, L, dqk, dv, window",
+    [
+        ("window", 2, 64, 8, 8192, 128, 128, 512),
+        ("full", 2, 48, 8, 8192, 128, 128, None),
+        ("full", 2, 64, 8, 8192, 128, 128, None),
+        ("full", 4, 32, 32, 4096, 192, 128, None),
+    ],
+    ids=["window", "full-48-over-8", "full-64-over-8", "latent-192-128"],
+)
+def test_the_blocked_attention_compiles_for_the_v5e_at_the_cells_widths(
+    one_chip, monkeypatch, kind, B, heads, Hkv, L, dqk, dv, window
+):
+    """A step's batch of ``laguna-window-fit-l8k`` as a layer passes it (2
+    rows of 8,192 tokens, 64 or 48 query heads over 8 key/value heads of 128)
+    and of ``kimilinear-window-fit-l4k``'s latent attention (4 rows of 4,096
+    tokens, 32 heads, 192-wide keys and 128-wide values), bfloat16, forward
+    and gradient. A sliding layer is XLA blocks whose score products meet 768
+    keys, not the row's 8,192, and fits beside the cell's 8 GB of state. A
+    full layer and the latent attention are two Mosaic kernels, ``flash_fwd``
+    and ONE ``flash_bwd``: what they ask of VMEM (a head's q, k, v, dO and
+    three float32 accumulators) and of the tiling is refused here, not in the
+    cell, and nothing of the XLA blocks is left in the program: no block of
+    float32 scores, none of their loops."""
     import re
 
-    B, L, Hkv, d = 2, 8192, 8, 128
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the wrapper asks whether to interpret
     arg = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)  # noqa: E731
     mask = jax.ShapeDtypeStruct((B, L), jnp.int32, sharding=one_chip)
 
@@ -86,9 +103,17 @@ def test_the_blocked_attention_compiles_for_the_v5e_at_the_cells_widths(one_chip
         loss = lambda q, k, v: causal_attention(q, k, v, mask, window).astype(jnp.float32).sum()  # noqa: E731
         return jax.value_and_grad(loss, (0, 1, 2))(q, k, v)
 
-    compiled = jax.jit(both).lower(arg(B, heads, L, d), arg(B, Hkv, L, d), arg(B, Hkv, L, d), mask).compile()
-    assert compiled.memory_analysis().temp_size_in_bytes < 4e9
-    # the widest array a score product of the program writes: [B, Hkv, G * BLOCK, keys]
+    compiled = jax.jit(both).lower(arg(B, heads, L, dqk), arg(B, Hkv, L, dqk), arg(B, Hkv, L, dv), mask).compile()
+    text = compiled.as_text()
+    # the widest array a score product of the XLA blocks writes: [B, Hkv, G * BLOCK, keys]
     rows = heads // Hkv * BLOCK
-    keys = {int(m.group(1)) for m in re.finditer(rf"\[{B},{Hkv},{rows},(\d+)\]", compiled.as_text())} - {d}
-    assert keys and max(keys) == (BLOCK + 512 if window else L), sorted(keys)
+    keys = {int(m.group(1)) for m in re.finditer(rf"\[{B},{Hkv},{rows},(\d+)\]", text)} - {dqk, dv}
+    if kind == "window":
+        assert compiled.memory_analysis().temp_size_in_bytes < 4e9
+        assert keys and max(keys) == BLOCK + 512, sorted(keys)
+        return
+    assert text.count('custom_call_target="tpu_custom_call"') == 2 and "flash_fwd" in text and "flash_bwd" in text
+    assert not keys and "while" not in text, sorted(keys)
+    # delta, the log-sum-exp and the key bias; of the latent attention also the copies a compile of the
+    # function alone makes of its 192-wide parameters (the XLA blocks of PR 32: 2.0-2.5 GB, compiled here)
+    assert compiled.memory_analysis().temp_size_in_bytes < (0.4e9 if dqk == dv else 1.2e9)
