@@ -136,7 +136,8 @@ def _add_common(p: argparse.ArgumentParser) -> None:
     p.add_argument("--config", help="JSON config file (ExperimentConfig.to_dict shape)")
     p.add_argument(
         "--preset", default="tiny",
-        help="tiny|distilbert|bert|bert-large|kimi-linear-tiny|kimi-linear-ep32|laguna-xs2-tiny|laguna-xs2-ep8"
+        help="tiny|distilbert|bert|bert-large|kimi-linear-tiny|kimi-linear-ep32|laguna-xs2-tiny|laguna-xs2-ep8|"
+        "qwen3-next-tiny|qwen3-next-ep16"
     )
     p.add_argument(
         "--gelu",

@@ -387,12 +387,128 @@ class LagunaConfig:
         return cls(**{**defaults, **kw})
 
 
+@dataclass(frozen=True)
+class Qwen3NextConfig:
+    """Pre-norm decoder whose mixer is, by layer, a Gated DeltaNet (the delta
+    rule with ONE decay a value head and token, a short convolution over q, k
+    and v together, key heads grouped under twice as many value heads, a
+    normed output gated by ``silu(z)``) or a gated softmax attention (grouped
+    heads of 256 with query/key norms, a quarter of each head rotated, an
+    element-wise sigmoid gate), every layer with a sparse expert FFN (softmax
+    router, the top ``experts_per_token`` renormalised, a shared expert with a
+    gate of its own), and the paper's classification head on each row's LAST
+    REAL token (``models/qwen3_next.py``).
+
+    Defaults are the published ``config.json`` of
+    Qwen/Qwen3-Next-80B-A3B-Instruct: 48 layers of width 2048 under the
+    family's zero-centred RMSNorm (``x / rms(x) * (1 + w)``); layer ``i`` is
+    gated attention where ``(i + 1) % full_attention_interval == 0`` and
+    Gated DeltaNet elsewhere.
+
+    ``experts_held`` / ``expert_offset``: the experts THIS chip holds, as
+    :class:`KimiLinearConfig` has them. Frozen and hashable, for the same
+    reason.
+    """
+
+    family: str = "qwen3_next"
+    vocab_size: int = 151936
+    max_len: int = 16384
+    dim: int = 2048
+    n_layers: int = 48
+    full_attention_interval: int = 4
+    # Gated DeltaNet
+    linear_key_heads: int = 16
+    linear_value_heads: int = 32
+    linear_key_dim: int = 128
+    linear_value_dim: int = 128
+    conv_kernel: int = 4
+    # Gated attention
+    n_heads: int = 16
+    n_kv_heads: int = 2
+    head_dim: int = 256
+    rotary_share: float = 0.25
+    rope_theta: float = 10000000.0
+    # FFN
+    expert_dim: int = 512
+    shared_dim: int = 512
+    n_experts: int = 512
+    experts_per_token: int = 10
+    experts_held: int = 512
+    expert_offset: int = 0
+    rms_norm_eps: float = 1e-6
+    initializer_range: float = 0.02
+    n_classes: int = 2
+    pad_token_id: int = 0
+    compute_dtype: str = "bfloat16"
+    param_dtype: str = "float32"
+    #: Per-layer recomputation, as :class:`KimiLinearConfig` has it.
+    remat: bool = False
+
+    def __post_init__(self) -> None:
+        if self.n_layers < 1:
+            raise ValueError(f"n_layers={self.n_layers} must be >= 1")
+        if self.linear_value_heads % self.linear_key_heads or self.n_heads % self.n_kv_heads:
+            raise ValueError(
+                f"linear_value_heads={self.linear_value_heads} must be a multiple of linear_key_heads="
+                f"{self.linear_key_heads}, and n_heads={self.n_heads} of n_kv_heads={self.n_kv_heads}"
+            )
+        if not 0 < self.experts_held <= self.n_experts - self.expert_offset:
+            raise ValueError(
+                f"experts_held={self.experts_held} at expert_offset="
+                f"{self.expert_offset} is not a share of n_experts={self.n_experts}"
+            )
+        if self.experts_per_token > self.n_experts:
+            raise ValueError("experts_per_token exceeds n_experts")
+
+    def mixer(self, layer: int) -> str:
+        """``"full"`` or ``"linear"`` for the 0-indexed ``layer``."""
+        return "full" if (layer + 1) % self.full_attention_interval == 0 else "linear"
+
+    def is_moe(self, layer: int) -> bool:
+        return True  # decoder_sparse_step 1, no mlp_only_layers
+
+    @property
+    def routed_scale(self) -> float:
+        """The chosen experts' renormalised weights are used as they are."""
+        return 1.0
+
+    def replace(self, **kw: Any) -> "Qwen3NextConfig":
+        return dataclasses.replace(self, **kw)
+
+    @classmethod
+    def ep16_cut(cls, **kw: Any) -> "Qwen3NextConfig":
+        """One chip's share of a deployment in which 16 chips share each
+        layer: layers 0-3 (one whole period: linear, linear, linear, full),
+        32 of the 512 experts, an eighth of the vocabulary; every width as
+        published (~587 M parameters)."""
+        kw.setdefault("n_layers", 4)
+        kw.setdefault("experts_held", 32)
+        kw.setdefault("vocab_size", 18992)
+        kw.setdefault("remat", True)
+        return cls(**kw)
+
+    @classmethod
+    def tiny(cls, **kw: Any) -> "Qwen3NextConfig":
+        """Small config for tests / CI on CPU, fp32: one period (three Gated
+        DeltaNet layers and a gated attention layer), key heads grouped under
+        twice as many value heads, a quarter of each head rotated."""
+        defaults = dict(
+            vocab_size=256, max_len=96, dim=32, n_layers=4, linear_key_heads=2,
+            linear_value_heads=4, linear_key_dim=16, linear_value_dim=16, n_heads=4,
+            n_kv_heads=2, head_dim=16, expert_dim=24, shared_dim=24, n_experts=16,
+            experts_per_token=4, experts_held=4, compute_dtype="float32",
+        )
+        return cls(**{**defaults, **kw})
+
+
 #: THE registry of the model families beside the BERT encoder: ``family``
 #: value -> configuration type (a ``ModelConfig`` has no ``family`` key).
 #: ``ExperimentConfig.from_dict`` picks a section's type by it, and
 #: ``models.family_module`` the module ``models/<family>.py`` that holds the
 #: family's ``Classifier`` and ``forward_flops``.
-MODEL_CONFIG_TYPES: dict[str, type] = {"kimi_linear": KimiLinearConfig, "laguna": LagunaConfig}
+MODEL_CONFIG_TYPES: dict[str, type] = {
+    "kimi_linear": KimiLinearConfig, "laguna": LagunaConfig, "qwen3_next": Qwen3NextConfig,
+}
 
 
 @dataclass(frozen=True)
@@ -1198,7 +1314,7 @@ class MeshConfig:
 
 @dataclass(frozen=True)
 class ExperimentConfig:
-    model: ModelConfig | KimiLinearConfig | LagunaConfig = field(default_factory=ModelConfig)
+    model: ModelConfig | KimiLinearConfig | LagunaConfig | Qwen3NextConfig = field(default_factory=ModelConfig)
     data: DataConfig = field(default_factory=DataConfig)
     train: TrainConfig = field(default_factory=TrainConfig)
     fed: FedConfig = field(default_factory=FedConfig)

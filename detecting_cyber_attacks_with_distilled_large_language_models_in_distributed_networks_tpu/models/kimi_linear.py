@@ -39,8 +39,6 @@ Design notes (TPU):
 
 from __future__ import annotations
 
-import math
-
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
@@ -49,37 +47,14 @@ from ..config import KimiLinearConfig
 from ..ops.causal_attention import causal_attention
 from ..ops.kda import CHUNK as KDA_CHUNK
 from ..ops.kda import kda_chunked
-from .blocks import SparseMoE, SwiGLU, decoder, last_real_token_head
+from .blocks import SparseMoE, SwiGLU, causal_conv, conv_init, decoder, dt_bias_init, last_real_token_head
 from .blocks import dense as _dense
 from .blocks import rms as _rms
-
-
-def _conv_init(key, shape, dtype):
-    """Depthwise kernel ``[K, channels]``: uniform in +-1/sqrt(K), the
-    family's (torch Conv1d's) default for a fan-in of K."""
-    bound = 1.0 / math.sqrt(shape[0])
-    return jax.random.uniform(key, shape, dtype, -bound, bound)
 
 
 def _a_log_init(key, shape, dtype):
     """log of a decay rate uniform in [1, 16], one a head."""
     return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
-
-
-def _dt_bias_init(key, shape, dtype):
-    """Inverse softplus of a step ``dt`` log-uniform in [1e-3, 1e-1]."""
-    dt = jnp.exp(jax.random.uniform(key, shape, dtype, math.log(1e-3), math.log(1e-1)))
-    return dt + jnp.log(-jnp.expm1(-dt))
-
-
-def causal_conv(x, kernel):
-    """Depthwise causal convolution over time: ``y_t = sum_j kernel[j] *
-    x_{t-K+1+j}`` with zeros before the row's start. ``x``: ``[B, L, C]``;
-    ``kernel``: ``[K, C]``."""
-    K = kernel.shape[0]
-    L = x.shape[1]
-    xp = jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0)))
-    return sum(xp[:, j : j + L] * kernel[j].astype(x.dtype) for j in range(K))
 
 
 class KDAMixer(nn.Module):
@@ -96,7 +71,7 @@ class KDAMixer(nn.Module):
             return t.reshape(B, L, H, d).transpose(0, 2, 1, 3)
 
         def conv_proj(name):
-            kernel = self.param(f"{name}_conv", _conv_init, (cfg.conv_kernel, H * d), pd)
+            kernel = self.param(f"{name}_conv", conv_init, (cfg.conv_kernel, H * d), pd)
             return heads(jax.nn.silu(causal_conv(_dense(cfg, H * d, f"{name}_proj")(x), kernel)))
 
         q, k, v = conv_proj("q"), conv_proj("k"), conv_proj("v")
@@ -107,7 +82,7 @@ class KDAMixer(nn.Module):
 
         q, k = l2(q) * d**-0.5, l2(k)
         a_log = self.param("A_log", _a_log_init, (H,), pd)
-        dt_bias = self.param("dt_bias", _dt_bias_init, (H * d,), pd)
+        dt_bias = self.param("dt_bias", dt_bias_init, (H * d,), pd)
         f = _dense(cfg, H * d, "f_b_proj")(_dense(cfg, cfg.gate_rank, "f_a_proj")(x))
         g = -jnp.exp(a_log.astype(jnp.float32))[None, :, None, None] * heads(
             jax.nn.softplus(f.astype(jnp.float32) + dt_bias.astype(jnp.float32))

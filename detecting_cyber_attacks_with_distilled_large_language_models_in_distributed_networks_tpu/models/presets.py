@@ -20,8 +20,9 @@ for no published model, are sized by the tokenizer.
 | ``tiny`` / ``distilbert`` / ``bert`` / ``bert-large`` | ``models/distilbert.py`` | the BERT encoder ladder; published weights load through ``models/hf_convert.py`` |
 | ``kimi-linear-tiny`` / ``kimi-linear-ep32`` | ``models/kimi_linear.py`` | KDA + NoPE latent attention + experts; ``ep32``: one of 32 chips' share of Kimi-Linear-48B-A3B, windows of 28 flows to 4,096 tokens |
 | ``laguna-xs2-tiny`` / ``laguna-xs2-ep8`` | ``models/laguna.py`` | window-512 and full attention with rotary positions, grouped heads, a gate a head, experts; ``ep8``: one of 8 chips' share of Laguna-XS.2, windows of 56 flows to 8,192 tokens |
+| ``qwen3-next-tiny`` / ``qwen3-next-ep16`` | ``models/qwen3_next.py`` | Gated DeltaNet and gated 256-wide grouped attention three to one, softmax-routed experts with a gated shared expert; ``ep16``: one of 16 chips' share of Qwen3-Next-80B-A3B, windows of 112 flows to 16,384 tokens |
 
-Neither decoder class has a converter from its published tensor names yet:
+None of the decoder classes has a converter from its published tensor names yet:
 their weights are random from the seed.
 """
 
@@ -29,7 +30,7 @@ from __future__ import annotations
 
 from typing import Any, Callable
 
-from ..config import KimiLinearConfig, LagunaConfig, ModelConfig
+from ..config import KimiLinearConfig, LagunaConfig, ModelConfig, Qwen3NextConfig
 
 #: name -> config factory. Ordered small -> large so help strings
 #: and error messages read as the scale ladder.
@@ -42,10 +43,12 @@ PRESETS: dict[str, Callable[..., Any]] = {
     "kimi-linear-ep32": KimiLinearConfig.ep32_cut,
     "laguna-xs2-tiny": LagunaConfig.tiny,
     "laguna-xs2-ep8": LagunaConfig.ep8_cut,
+    "qwen3-next-tiny": Qwen3NextConfig.tiny,
+    "qwen3-next-ep16": Qwen3NextConfig.ep16_cut,
 }
 
 #: Presets that stand for no published model: their table is the tokenizer's.
-TOKENIZER_SIZED = ("tiny", "kimi-linear-tiny", "laguna-xs2-tiny")
+TOKENIZER_SIZED = ("tiny", "kimi-linear-tiny", "laguna-xs2-tiny", "qwen3-next-tiny")
 
 #: DataConfig fields a preset's rows need (``cli/common.py::resolve_config``):
 #: a long-context preset reads windows of consecutive flows, not single flows.
@@ -54,6 +57,8 @@ PRESET_DATA: dict[str, dict[str, Any]] = {
     "kimi-linear-ep32": {"window_flows": 28},
     "laguna-xs2-tiny": {"window_flows": 2},
     "laguna-xs2-ep8": {"window_flows": 56},
+    "qwen3-next-tiny": {"window_flows": 2},
+    "qwen3-next-ep16": {"window_flows": 112},
 }
 
 

@@ -5,16 +5,19 @@ share a key/value head in groups, and for a sliding window.
 Which path runs is decided by the shapes, by nothing a caller sets:
 
 * **no window, a length that tiles** (``ops/flash_attention.py::causal_tile``:
-  a multiple of 128 whose head fits the kernels' VMEM; the window cells' 8,192
-  and 4,096 tokens): the Pallas kernels ``flash_fwd`` / ``flash_bwd``
+  a multiple of 128 whose head fits the kernels' VMEM, which hold a whole
+  head's row: 128-wide heads at 8,192 tokens and 192 / 128-wide ones at 4,096,
+  two of the window cells' shapes): the Pallas kernels ``flash_fwd`` / ``flash_bwd``
   (``ops/flash_attention.py``), under the named scope :data:`KERNEL_SCOPE`. A
   query tile's scores, softmax and product with the values stay in VMEM, key
   tile by key tile with the running maximum and sum; key tiles after a query
   tile are not visited; the backward pass recomputes score tiles from ``q``,
   ``k``, the key mask and the rows' log-sum-exp. The result and the
   log-sum-exp carry :data:`ATTENTION_RESULT` as their ``checkpoint_name``.
-* **a window, or any other length** (the tiny presets of the CPU tests): the
-  XLA query blocks below. (A window of 512 in the kernels measured nothing of
+* **a window, a head too wide or a row too long for that VMEM** (a 256-wide
+  head passes the budget from 8,192 tokens on: the third window cell's gated
+  attention at 16,384), **or any other length** (the tiny presets of the CPU
+  tests): the XLA query blocks below. (A window of 512 in the kernels measured nothing of
   the Laguna cell's step for 0.8 GB more of temporaries: PERF.md, PR 33.)
 
 The XLA blocks: the dot path would hold ``[B, H, L, L]`` scores (4.3 GB in

@@ -99,6 +99,11 @@ HEADS = 8
 ABREAST = 2
 #: Largest exponent of a diagonal block's inverse decay (float32 holds e^88).
 MAX_BLOCK_DECAY = 80.0
+#: Tokens times heads that one step of the backward pass's ``lax.map`` takes:
+#: a row of 4,096 tokens with its 32 heads (1.6 GB of intermediates at 128 /
+#: 128). A longer row goes through in groups of heads, which are independent
+#: chains: a row of 16,384 tokens whole would hold 6.4 GB.
+BWD_TOKEN_HEADS = 4096 * 32
 
 
 def kda_recurrent(q, k, v, g, beta):
@@ -189,8 +194,13 @@ def _kda_bwd(dtype, inputs, dO):
 
     # Row by row: the backward pass holds one row's intermediates (two dozen
     # arrays of q's size in float32, and the chunks' starting states), not
-    # the batch's.
-    return jax.lax.map(one_row, (*inputs, dO))
+    # the batch's; and of a row longer than BWD_TOKEN_HEADS allows, one group
+    # of heads' (the largest count that divides the heads and fits).
+    B, H, L, _ = inputs[0].shape
+    heads = max(h for h in range(1, H + 1) if H % h == 0 and (h == 1 or h * L <= BWD_TOKEN_HEADS))
+    groups = lambda x: x.reshape(B * (H // heads), heads, *x.shape[2:])  # noqa: E731 (as it is where a row goes whole)
+    grads = jax.lax.map(one_row, tuple(groups(x) for x in (*inputs, dO)))
+    return tuple(g.reshape(x.shape) for g, x in zip(grads, inputs))
 
 
 _kda.defvjp(_kda_fwd, _kda_bwd)

@@ -57,7 +57,7 @@ def expert_capacity(tokens: int, k: int, n_experts: int, held: int) -> int:
 
 def route_topk(scores, select_bias, k: int, scale: float):
     """The chosen experts and their weights. ``scores``: ``[T, E]`` float32
-    (the sigmoid of the router's logits); the top ``k`` by ``scores +
+    (the sigmoid of the router's logits, or their softmax); the top ``k`` by ``scores +
     select_bias`` are chosen, their weights are the plain scores divided by
     their sum, times ``scale``. Returns ``(idx [T, k] int32, w [T, k])``."""
     _, idx = jax.lax.top_k(scores + jax.lax.stop_gradient(select_bias), k)

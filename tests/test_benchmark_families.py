@@ -1,8 +1,8 @@
 """Tier-1 hook for the benchmark's model-family seam (PR 27 could not add a
 file under ``tests/``): the cases of ``benchmark/selftest/test_families.py``
-run here as they are, plus cases for the second and the third family the
-benchmark now has (``kimi_linear``, ``laguna``) through the same seam, and
-their cells' CPU rehearsals.
+run here as they are, plus cases for the second, the third and the fourth
+family the benchmark now has (``kimi_linear``, ``laguna``, ``qwen3_next``)
+through the same seam, and their cells' CPU rehearsals.
 
 Two of the selftest's cases quote a table of the BERT configurations only
 (``QUOTED``; ``family == "bert_encoder"`` for every configuration): they are
@@ -23,13 +23,15 @@ from benchmark.selftest import test_families as _cases
 from benchmark.selftest.test_families import *  # noqa: F401,F403  (the cases and their fixture)
 
 from benchmark import families, harness
-from benchmark.families import kimi_linear, laguna
+from benchmark.families import kimi_linear, laguna, qwen3_next
 
 ROOT = _cases.ROOT
 KIMI = "kimi-linear-48b-a3b-ep32"
 CELL = "kimilinear-window-fit-l4k"
 LAGUNA = "laguna-xs2-ep8"
 LAGUNA_CELL = "laguna-window-fit-l8k"
+QWEN = "qwen3-next-80b-a3b-ep16"
+QWEN_CELL = "qwen3next-window-fit-l16k"
 
 
 @pytest.mark.parametrize("name", [n for n in _cases.CONFIGS if n in _cases.QUOTED])
@@ -38,7 +40,7 @@ def test_counts_are_the_quoted(name):
 
 
 def test_every_configuration_names_a_family_that_is_there():
-    assert KIMI in _cases.CONFIGS and LAGUNA in _cases.CONFIGS
+    assert KIMI in _cases.CONFIGS and LAGUNA in _cases.CONFIGS and QWEN in _cases.CONFIGS
     for name in _cases.CONFIGS:
         conf = harness.load_json("configs", f"{name}.json")
         assert os.path.isfile(os.path.join(ROOT, "benchmark", "families", f"{conf['family']}.py"))
@@ -216,7 +218,7 @@ def test_a_cache_too_small_for_the_cell_is_left_alone():
         jax.config.update("jax_compilation_cache_max_size", was[1])
 
 
-@pytest.mark.parametrize("cell", [CELL, LAGUNA_CELL])
+@pytest.mark.parametrize("cell", [CELL, LAGUNA_CELL, QWEN_CELL])
 def test_the_new_cells_rehearsal_ends_in_a_result_line(cell):
     env = {**os.environ, "JAX_PLATFORMS": "cpu", "JAX_ENABLE_COMPILATION_CACHE": "0"}
     done = subprocess.run(
@@ -403,3 +405,163 @@ def test_a_fault_in_what_laguna_adds_is_not_correct(monkeypatch, fault):
             assert any("hidden states differ" in p for p in ctx.problems), (ctx.problems, ctx.compared)
     finally:
         laguna.program.cache_clear()
+
+
+# ------------------------------------------------ the fourth family's cases
+def test_qwen3_next_family_loads_and_counts_what_the_program_builds():
+    import jax
+
+    conf = harness.load_json("configs", f"{QWEN}.json")
+    family, model = families.load(conf), conf["model"]
+    assert family is qwen3_next
+    cfg = family.model_config(model)
+    built = jax.eval_shape(lambda: family.init_params(cfg, jax.random.key(0)))
+    n = sum(int(np.prod(x.shape)) for x in jax.tree.leaves(built))
+    assert n == family.param_count(model) == conf["parameters"] == 586_775_618
+    # the issue's sum: three linear layers, the attention layer, the table, the final norm, the head
+    assert n == 3 * 138_582_208 + 132_127_232 + 38_895_616 + 2_048 + 4_098
+    assert set(built) == {"encoder", "classifier"}
+    assert family.train_step_bytes(model, steps=4) == 4 * 32.0 * n
+    # every width as published; the cuts are the three the file lists, and the manifest's
+    src = conf["source_config"]
+    assert (cfg.dim, cfg.expert_dim, cfg.shared_dim) == (src["hidden_size"], src["moe_intermediate_size"], src["shared_expert_intermediate_size"])
+    assert (cfg.n_heads, cfg.n_kv_heads, cfg.head_dim) == (src["num_attention_heads"], src["num_key_value_heads"], src["head_dim"])
+    assert (cfg.linear_key_heads, cfg.linear_value_heads, cfg.linear_key_dim, cfg.linear_value_dim, cfg.conv_kernel) == (
+        src["linear_num_key_heads"], src["linear_num_value_heads"], src["linear_key_head_dim"], src["linear_value_head_dim"],
+        src["linear_conv_kernel_dim"],
+    )
+    assert (cfg.rotary_share, cfg.rope_theta, cfg.rms_norm_eps) == (src["partial_rotary_factor"], src["rope_theta"], src["rms_norm_eps"])
+    assert (cfg.n_experts, cfg.experts_per_token, cfg.full_attention_interval) == (512, src["num_experts_per_tok"], src["full_attention_interval"])
+    assert (cfg.n_layers, cfg.experts_held, cfg.vocab_size) == (conf["num_hidden_layers"], conf["num_experts"], conf["vocab_size"]) == (4, 32, 18992)
+    assert [cfg.mixer(i) for i in range(4)] == ["linear", "linear", "linear", "full"]
+    assert conf["reduced"] == ["num_hidden_layers", "num_experts", "vocab_size"] and set(conf["reduced_how"]) == set(conf["reduced"])
+    assert all(conf[k] == src[k] for k in src if k not in conf["reduced"])
+    assert conf["published"] == {k: src[k] for k in conf["reduced"]}
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        entry = next(c for c in json.load(f)["configs"] if c["name"] == QWEN)
+    assert entry["reduced"] == conf["reduced"] and entry["source"] == conf["source"]
+    # the program's own preset is this configuration
+    assert harness.pkg("models").model_preset("qwen3-next-ep16") == cfg
+    assert family.KDA_CHUNK == harness.pkg("ops.kda").CHUNK
+
+
+def test_qwen3_next_counts_the_published_mathematics_and_the_slots_really_routed():
+    model = harness.load_json("configs", f"{QWEN}.json")["model"]
+    fam = qwen3_next
+    L = 16384
+    mean = fam.train_step_flops(model, 1)
+    assert 1.35e9 < mean / L < 1.37e9  # about 1.36 GFLOP a token to train (22.3 TFLOP a step)
+    base = fam.train_step_flops(model, 4, steps=4, tokens=4 * L, routed_slots_here=0)
+    more = fam.train_step_flops(model, 4, steps=4, tokens=4 * L, routed_slots_here=163840)
+    assert 0 < base < more and more - base == 3 * 163840 * 6 * 2048 * 512
+    assert fam.mean_slots(model, 4 * L) == 163840  # 10,240 rows a layer and step
+    f0, b0 = fam.scope_work(model, "moe/experts", tokens=4.0 * L, steps=4, routed_slots_here=1000)
+    f1, b1 = fam.scope_work(model, "moe/experts", tokens=4.0 * L, steps=4, routed_slots_here=2000)
+    assert 0 < f0 < f1 and 0 < b0 < b1
+    # the delta rule is the scalar-gate rule's: the channel-wise rule's products, a head's ONE decay in the bytes
+    f, b = fam.scope_work(model, "gdn/chunks", tokens=4.0 * L)
+    assert f == 3 * 3 * 4 * L * 32 * (64 * 5 * 128 + 6 * 128 * 128)
+    kimi = harness.load_json("configs", f"{KIMI}.json")["model"]
+    k_f, k_b = kimi_linear.scope_work(kimi, "kda/chunks", tokens=4.0 * L)  # the same heads and widths, in 4 layers
+    assert f / 3 == k_f / 4
+    assert b == 3 * 3 * 4 * L * (2 * 16 * 128 * 4 + 32 * 128 * 2 + 2 * 32 * 4 + 32 * 128 * 4)
+    assert b / 3 < 0.6 * k_b / 4
+    # the attention: query i against i + 1 keys, whatever blocks a program rounds them to
+    fs, bs = fam.scope_work(model, "attn/gated/scores", tokens=4.0 * L, rows=4)
+    assert fs == 3 * 4 * (L * (L + 1) / 2) * 16 * 4 * 256 and bs == 3 * 4 * L * (2 * 16 + 2 * 2) * 256 * 2
+    assert fam.scope_work(model, "attn/gated/scores", tokens=4.0 * L) == (fs, bs)  # without the rows: max_len
+    assert fam.scope_work(model, "attn/gated", tokens=1.0) is None and fam.scope_work(model, "kda/chunks", tokens=1.0) is None
+
+
+def test_qwen3_next_tiny_is_the_tiny_preset():
+    model = harness.load_json("configs", f"{QWEN}.json")["model"]
+    cfg = qwen3_next.model_config(qwen3_next.tiny(model))
+    assert cfg.dim == 32 and cfg.remat is True and cfg.rms_norm_eps == 1e-6 and cfg.routed_scale == 1.0
+    assert {cfg.mixer(i) for i in range(cfg.n_layers)} == {"linear", "full"}
+    assert cfg.linear_value_heads == 2 * cfg.linear_key_heads and cfg.n_heads > cfg.n_kv_heads
+
+
+def _qwen_context(monkeypatch, name, **overrides):
+    return _family_context(monkeypatch, qwen3_next, QWEN, name, **overrides)
+
+
+def test_qwen3_next_program_agrees_with_its_reference_through_check_model(monkeypatch):
+    ctx, params, split = _qwen_context(monkeypatch, "standin_q")
+    harness.check_model(ctx, params, split, what="qwen3_next", key="q", bind=True, n=4)
+    assert not ctx.problems, ctx.problems
+    assert ctx.compared["q.hidden_rel"][0] < 1e-4
+    assert ctx.compared["q.hidden_rel"][1] == qwen3_next.TOLERANCES["hidden_rel"]
+
+
+@pytest.mark.parametrize("fault", _cases.FAULTS)
+def test_a_planted_fault_in_the_qwen3_next_program_is_not_correct(monkeypatch, fault):
+    alter, says = _cases.FAULTS[fault]
+
+    def program(model_cfg):
+        forward = qwen3_next.program(model_cfg)
+        return lambda p, i, a: alter(*forward(p, i, a))
+
+    ctx, params, split = _qwen_context(monkeypatch, "standin_r", program=program)
+    harness.check_model(ctx, params, split, what="faulty", key="q", bind=True, n=4)
+    assert any(says in p for p in ctx.problems), (ctx.problems, ctx.compared)
+
+
+@pytest.mark.parametrize("fault", ["none", "no-decay", "wrong-key-head", "no-rotation", "plain-norm", "sigmoid-router", "ungated-shared"])
+def test_a_fault_in_what_qwen3_next_adds_is_not_correct(monkeypatch, fault):
+    """Six faults in the mechanisms this family brought, each planted in the
+    program's own forward at the tiny preset: a delta rule that never forgets,
+    value heads reading the wrong key head, positions left unrotated, the
+    other classes' norm (a weight that multiplies, read as 1 + w = 1), a
+    sigmoid for the router's softmax, a shared expert without its gate. Each
+    leaves shapes and finiteness alone and fails the comparison with the
+    reference, which the program as it is passes on the same weights."""
+    import jax
+    import jax.numpy as jnp
+
+    # The weights first, from the program as it is (a fault that drops a leaf leaves the tree whole).
+    ctx, params, split = _qwen_context(monkeypatch, f"standin_{fault[:4]}")
+    model_mod = harness.pkg("models.qwen3_next")
+    blocks = harness.pkg("models.blocks")
+    if fault == "no-decay":
+        real = model_mod.kda_chunked
+        monkeypatch.setattr(model_mod, "kda_chunked", lambda q, k, v, g, beta, **kw: real(q, k, v, jnp.zeros_like(g), beta, **kw))
+    elif fault == "wrong-key-head":
+        real = model_mod.kda_chunked
+        monkeypatch.setattr(model_mod, "kda_chunked", lambda q, k, v, g, beta, **kw: real(q, k[:, ::-1], v, g, beta, **kw))
+    elif fault == "no-rotation":
+        monkeypatch.setattr(model_mod, "apply_rope", lambda x, cos, sin: x)
+    elif fault == "plain-norm":
+        real = blocks.rms
+        monkeypatch.setattr(model_mod, "rms", lambda cfg, name, zero_centred=False: real(cfg, name, False))
+    elif fault in ("sigmoid-router", "ungated-shared"):
+        real = model_mod.SparseMoE
+        change = {"score": "sigmoid"} if fault == "sigmoid-router" else {"shared_gate": False}
+        monkeypatch.setattr(model_mod, "SparseMoE", lambda cfg, **kw: real(cfg, **{**kw, **change}))
+    for cached in (qwen3_next.program, qwen3_next.routing):
+        cached.cache_clear()
+    try:
+        # At 32 dimensions and weights of 0.02 every score is near 0, a row
+        # attends evenly and every gate is a half: the queries, keys and gates
+        # are made as large as the published widths make them, and the norms'
+        # weights moved off 0, so that each mechanism matters.
+        def widen(path, x):
+            names = [getattr(k, "key", "") for k in path]
+            if any(n in ("q_proj", "k_proj", "shared_gate", "router") for n in names):
+                return x * 30.0
+            if "experts_down" in names:  # the routed part as large beside the residual stream as 10 experts of 512 make it
+                return x * 8.0
+            if "ba_proj" in names:  # a decay as the published width gives it: the kernels' blocks hold a mean of 5 a token
+                return x * 8.0
+            if names[-1] == "scale" and "final_norm" not in names:
+                return x + 0.5
+            return x
+
+        params = jax.tree_util.tree_map_with_path(widen, params)
+        harness.check_model(ctx, params, split, what=fault, key="q", bind=True, n=4)
+        if fault == "none":
+            assert not ctx.problems and ctx.compared["q.hidden_rel"][0] < 1e-4, (ctx.problems, ctx.compared)
+        else:
+            assert any("hidden states differ" in p for p in ctx.problems), (ctx.problems, ctx.compared)
+    finally:
+        for cached in (qwen3_next.program, qwen3_next.routing):
+            cached.cache_clear()

@@ -76,8 +76,9 @@ def test_the_undifferentiated_kda_forward_compiles_for_the_v5e_at_the_cells_widt
         ("full", 2, 48, 8, 8192, 128, 128, None),
         ("full", 2, 64, 8, 8192, 128, 128, None),
         ("full", 4, 32, 32, 4096, 192, 128, None),
+        ("blocks", 1, 16, 2, 16384, 256, 256, None),
     ],
-    ids=["window", "full-48-over-8", "full-64-over-8", "latent-192-128"],
+    ids=["window", "full-48-over-8", "full-64-over-8", "latent-192-128", "gated-256-at-16k"],
 )
 def test_the_blocked_attention_compiles_for_the_v5e_at_the_cells_widths(
     one_chip, monkeypatch, kind, B, heads, Hkv, L, dqk, dv, window
@@ -92,7 +93,11 @@ def test_the_blocked_attention_compiles_for_the_v5e_at_the_cells_widths(
     and ONE ``flash_bwd``: what they ask of VMEM (a head's q, k, v, dO and
     three float32 accumulators) and of the tiling is refused here, not in the
     cell, and nothing of the XLA blocks is left in the program: no block of
-    float32 scores, none of their loops."""
+    float32 scores, none of their loops. ``qwen3next-window-fit-l16k``'s gated
+    attention (1 row of 16,384 tokens, 16 query heads over 2 key/value heads of
+    256) passes the kernels' VMEM budget and is the XLA blocks: the group's 8
+    heads folded into a block's 2,048 rows, against the keys up to the group's
+    end, 268 MB of float32 scores a block."""
     import re
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the wrapper asks whether to interpret
@@ -111,6 +116,13 @@ def test_the_blocked_attention_compiles_for_the_v5e_at_the_cells_widths(
     if kind == "window":
         assert compiled.memory_analysis().temp_size_in_bytes < 4e9
         assert keys and max(keys) == BLOCK + 512, sorted(keys)
+        return
+    if kind == "blocks":
+        assert "tpu_custom_call" not in text and "while" in text
+        # a single row: the compiler drops the batch's axis of 1 from a block's scores
+        keys = {int(m.group(1)) for m in re.finditer(rf"f32\[{Hkv},{rows},(\d+)\]", text)} - {dqk, dv}
+        assert keys and max(keys) == L and rows == 2048, sorted(keys)
+        assert compiled.memory_analysis().temp_size_in_bytes < 3e9
         return
     assert text.count('custom_call_target="tpu_custom_call"') == 2 and "flash_fwd" in text and "flash_bwd" in text
     assert not keys and "while" not in text, sorted(keys)
