@@ -3,12 +3,11 @@
 The second model class beside ``models/distilbert.py`` (``KimiLinearConfig``,
 ``models.build_classifier``): a pre-norm decoder whose mixer is, by layer,
 Kimi Delta Attention (a gated delta-rule linear attention with a short causal
-convolution, ``ops/kda.py``: chunks of 64 tokens; where nobody asks for a
-gradient one Pallas kernel does a chunk's pair matrices, substitution and
-recurrence in VMEM, and the gradient's own forward builds the pair matrices
-and solves in XLA around two Pallas kernels that keep the state on the chip;
-all interpreted off the TPU) or full latent attention
-without positions (MLA,
+convolution, ``ops/kda.py``: chunks of 64 tokens; one Pallas kernel does a
+chunk's pair matrices, inverse and recurrence in VMEM, one launch for the
+batch, and where a gradient is asked for a second one walks the chunks
+backwards and transposes all of it in VMEM; both interpreted off the TPU) or
+full latent attention without positions (MLA,
 ``ops/causal_attention.py``), and whose FFN is a dense SwiGLU or a sparse
 mixture of SwiGLU experts with a sigmoid router and a shared expert
 (``ops/moe.py``), of which this chip holds ``cfg.experts_held``. Block:

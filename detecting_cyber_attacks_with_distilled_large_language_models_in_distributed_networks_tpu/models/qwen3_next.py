@@ -20,8 +20,9 @@ with a softmax router and a gate on the shared expert. Block:
 
 The recurrence is ``ops/kda.py``'s with the decay constant over a head's
 channels: ``g`` is broadcast to ``[B, Hv, L, dk]`` and ``kda_chunked`` runs
-its three Pallas kernels as they are (the cell's shapes are the Kimi cell's:
-32 heads of 128 / 128). The broadcast is not work: a scalar-gate entry that
+its two Pallas kernels as they are, ``kda_fwd`` and under ``jax.grad``
+``kda_bwd``, each one launch for the row (the cell's shapes are the Kimi
+cell's: 32 heads of 128 / 128). The broadcast is not work: a scalar-gate entry that
 skips the per-channel pair factors is queued (ROADMAP.md, Queue 2 B).
 
 *Gated attention* (``H`` query heads on ``Hkv`` key/value heads of ``d``):

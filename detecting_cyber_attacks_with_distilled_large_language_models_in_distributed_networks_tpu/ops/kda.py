@@ -24,62 +24,66 @@ sides solved, so what passes from chunk to chunk is matrix products only:
 
     U = U0_c - W_c S        O_c = Q_c S + B_c U        S <- decay_c * S + K_c^T U
 
-What runs where. Three Pallas kernels, each over ``(rows, heads / HEADS,
-chunks)`` with the chunks last and in order, the (transposed) states of
-:data:`HEADS` heads in VMEM scratch (a state never goes through HBM between two
-chunks) and the operands read in place from ``[B, H, N, C, .]``; off the TPU all
-three run in Pallas' interpreter. Which of them a call of
-:func:`kda_chunked` runs depends on one thing, which the code observes through
-a ``jax.custom_vjp``: whether JAX asks it for a gradient.
+What runs where. Two Pallas kernels, each ONE launch for the whole batch over
+``(rows, heads / HEADS, chunks)`` with the chunks last and in order, the
+(transposed) states of :data:`HEADS` heads, or their cotangents, in VMEM scratch
+(a state never goes through HBM between two chunks) and the operands read in
+place from ``[B, H, N, C, .]``; off the TPU both run in Pallas' interpreter.
+Which of them a call of :func:`kda_chunked` runs depends on one thing, which the
+code observes through a ``jax.custom_vjp``: whether JAX asks it for a gradient.
 
-* Nobody does (a forward pass whose intermediates nothing keeps: a training
-  step's first pass, ``nn.remat``'s recomputation, evaluation): ``kda_fwd``,
-  one launch for the whole batch, under the scope ``chunks/fwd``. It reads
-  ``q``, ``k``, ``v``, the log-decay and ``beta`` and does everything above for
-  a chunk in VMEM: the running sum ``G`` (a float32 product with the triangle
-  of ones), the pair matrices, the system's inverse by float32 products at full
-  precision (the 16 x 16 diagonal blocks' ``(I + N)^-1 = (I - N)(I + N^2)(I +
-  N^4)(I + N^8)``, then merged upwards twice), ``W`` and ``U0``, the three
-  lines. It writes ``O`` only: nothing of a chunk's ``A``, ``B``, ``W``, ``U0``
-  or ``e^G`` goes through HBM.
-* Somebody does: the ``fwd`` rule is the same launch and keeps the five inputs
-  (a checkpoint by hand); the ``bwd`` rule maps over the batch's rows the
-  gradient of :func:`_kda_chunked`, which builds the pair matrices and solves
-  the system by substitution in plain XLA and runs the three lines in
-  ``kda_chunks_fwd`` (which writes beside ``O`` the state each chunk STARTED
-  from: ``[N, dk, dv]`` float32 a row and head, alive inside that row's
-  backward pass) and their transpose in ``kda_chunks_bwd`` (the chunks
-  backwards, the state's cotangent in VMEM, ``U`` made again from the saved
-  state), joined by a ``custom_vjp`` of their own. Row after row, so that the
-  backward pass holds one row's intermediates, not the batch's.
+* Nobody does (evaluation, any forward pass outside ``jax.grad``): ``kda_fwd``,
+  under the scope ``chunks/fwd``.
+  It reads ``q``, ``k``, ``v``, the log-decay and ``beta`` and does everything
+  above for a chunk in VMEM: the running sum ``G`` (a float32 product with the
+  triangle of ones), the pair matrices, the system's inverse ``T`` by float32
+  products at full precision (the 16 x 16 diagonal blocks' ``(I + N)^-1 = (I -
+  N)(I + N^2)(I + N^4)(I + N^8)``, then merged upwards twice), ``W`` and
+  ``U0``, the three lines. It writes ``O`` only: nothing of a chunk's ``A``,
+  ``B``, ``W``, ``U0`` or ``e^G`` goes through HBM.
+* Somebody does: the ``fwd`` rule is the same launch, which then also writes
+  the state each chunk STARTED from (``[dv, dk]`` float32 a chunk and head) and
+  ``T`` (``[C, C]``), and keeps them with the five inputs, alive until that
+  layer's ``bwd`` rule has run. (Under ``nn.remat`` both of a step's forward
+  passes are this launch: JAX takes a checkpointed block's first pass from the
+  rule too and drops the residuals after it. A kernel cannot leave a result
+  unwritten, so they are written and freed; the launch is bound by its
+  products and takes the same time either way.) The
+  ``bwd`` rule is one launch of ``kda_bwd``, under the scope ``chunks/bwd``: the
+  chunks backwards, the state's cotangent in scratch. For a chunk it builds the
+  pair matrices again with the forward's own code (:func:`_chunk_pairs`), makes
+  ``U = T R`` again from the saved state and inverse (``R = Diag(b) (V - (K *
+  e^G) S)``), and transposes, in VMEM: the three lines; the system, with ONE
+  product with the inverse and no chain through its construction (``dR = T^T
+  dU``, ``d(Diag(b) A) = -strict_tril(dR U^T)``); the pair products, by the same
+  blocks of 16 rows and the same two factors (``x * dx`` of a factor is its
+  exponent's cotangent, and where the clip below binds nothing passes through);
+  the running sum (a float32 product with the upper triangle of ones). It
+  writes the five gradients in place, in the inputs' types. No XLA operation of
+  the gradient stands beside the two kernels, and nothing loops over rows or
+  heads.
 
 ``e^{G_t - G_s}`` cannot be split into ``e^{G_t}`` times ``e^{-G_s}`` over a
 whole chunk: a fast head forgets by ``e^{-100}`` in 64 tokens and the second
-factor overflows. ``A`` and ``B`` are therefore built from 16 x 16 blocks,
-and no factor's exponent is positive except a diagonal block's clipped one.
-In XLA (:func:`_pair_matrices`): a block below the diagonal from three factors
-that are all at most 1 (the rows' decay since their block's first token, the
-decay between the two blocks, the columns' decay up to their block's last
-token), a block on the diagonal from the rows' factor and the columns'
-inverse factor, whose exponent is at most the block's own decay and is
-clipped at :data:`MAX_BLOCK_DECAY` (never reached while a channel forgets less
-than ``e^{-80}`` in 16 tokens, a mean log-decay of 5 a token; the published
-initialisation gives at most about 2). In ``kda_fwd``: a block of rows' factor
-``x_t e^{G_t - F}`` (``F`` the running sum at the block's first token) against
-every column's ``k_s e^{F - G_s}``, whose exponent is not positive for a
-column of an earlier block and is the same clipped one for a column of the
-block itself (later columns are masked): the same two cases, one product a
-block of rows.
+factor overflows. ``A`` and ``B`` are therefore built from blocks of 16 rows,
+and no factor's exponent is positive except a diagonal block's clipped one: a
+block of rows' factor ``x_t e^{G_t - F}`` (``F`` the running sum at the block's
+first token) against every column's ``k_s e^{F - G_s}``, whose exponent is not
+positive for a column of an earlier block and, for a column of the block itself,
+is at most the block's own decay and is clipped at :data:`MAX_BLOCK_DECAY`
+(never reached while a channel forgets less than ``e^{-80}`` in 16 tokens, a
+mean log-decay of 5 a token; the published initialisation gives at most about
+2); later columns are masked. One product a block of rows.
 """
 
 from __future__ import annotations
 
 import functools
 import math
+from types import SimpleNamespace
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -89,21 +93,16 @@ from jax.experimental.pallas import tpu as pltpu
 CHUNK = 64
 #: Rows and columns of the blocks the in-chunk matrices are built from.
 BLOCK = 16
-#: Heads a grid step of the recurrence's kernels: an implementation size too
-#: (the grid's fixed cost a step is shared by that many independent chains).
+#: Heads a grid step of the two kernels: an implementation size too (the
+#: grid's fixed cost a step is shared by that many independent chains).
 HEADS = 8
-#: Heads of a grid step that ``kda_fwd`` computes side by side: a head is one
-#: chain of dependent products, two chains fill each other's waits (the v5e's
-#: compiler schedules 12.3 thousand bundles a grid step for 15.3 one by one;
-#: four side by side spill four times the registers for 11.7).
+#: Heads of a grid step that a kernel computes side by side: a head is one
+#: chain of dependent products, two chains fill each other's waits (``kda_fwd``:
+#: the v5e's compiler schedules 12.3 thousand bundles a grid step for 15.3 one
+#: by one; four side by side spill four times the registers for 11.7).
 ABREAST = 2
 #: Largest exponent of a diagonal block's inverse decay (float32 holds e^88).
 MAX_BLOCK_DECAY = 80.0
-#: Tokens times heads that one step of the backward pass's ``lax.map`` takes:
-#: a row of 4,096 tokens with its 32 heads (1.6 GB of intermediates at 128 /
-#: 128). A longer row goes through in groups of heads, which are independent
-#: chains: a row of 16,384 tokens whole would hold 6.4 GB.
-BWD_TOKEN_HEADS = 4096 * 32
 
 
 def kda_recurrent(q, k, v, g, beta):
@@ -125,85 +124,29 @@ def kda_recurrent(q, k, v, g, beta):
     return jnp.moveaxis(o, 0, 2)
 
 
-def _pair_matrices(x, k, G, dtype):
-    """``M[t, s] = sum_c x_tc k_sc e^{G_tc - G_sc}`` for ``t >= s`` inside
-    each chunk (entries above the diagonal are not meaningful: the callers
-    mask them). ``x``: ``[X, ..., C, dk]`` (a leading axis of row operands
-    that share ``k`` and ``G``), ``k``, ``G``: ``[..., C, dk]``. Returns
-    ``[X, ..., C, C]`` float32."""
-    C, dk = k.shape[-2:]
-    n = C // BLOCK
-    lead = k.shape[:-2]
-    blk = lambda a: a.reshape(a.shape[:-2] + (n, BLOCK, dk))  # noqa: E731
-    xb, kb, Gb = blk(x), blk(k), blk(G)
-    first, last = Gb[..., 0, :], Gb[..., -1, :]  # [..., n, dk]
-    rows = xb * jnp.exp(Gb - first[..., None, :])  # decay since the block's first token
-    # On the diagonal: rows' factor times the columns' inverse factor.
-    cols_inv = kb * jnp.exp(jnp.minimum(first[..., None, :] - Gb, MAX_BLOCK_DECAY))
-    diag = jnp.einsum(
-        "...tc,...sc->...ts", rows.astype(dtype), cols_inv.astype(dtype),
-        preferred_element_type=jnp.float32,
-    )  # [X, ..., n, BLOCK, BLOCK]
-    out = jnp.zeros(x.shape[:1] + lead + (n, n, BLOCK, BLOCK), jnp.float32)
-    idx = np.arange(n)
-    out = out.at[..., idx, idx, :, :].set(diag)
-    if n > 1:
-        ii, jj = np.tril_indices(n, -1)
-        cols = kb * jnp.exp(last[..., None, :] - Gb)  # decay up to the block's last token
-        between = jnp.exp(first[..., ii, :] - last[..., jj, :])  # [..., P, dk], at most 1
-        below = jnp.einsum(
-            "...ptc,...psc->...pts",
-            (rows[..., ii, :, :] * between[..., None, :]).astype(dtype),
-            cols[..., jj, :, :].astype(dtype),
-            preferred_element_type=jnp.float32,
-        )
-        out = out.at[..., ii, jj, :, :].set(below)
-    # [.., n_i, n_j, t, s] -> [.., (n_i t), (n_j s)]
-    out = jnp.swapaxes(out, -3, -2)
-    return out.reshape(x.shape[:1] + lead + (C, C))
-
-
 def kda_chunked(q, k, v, g, beta, *, dtype=jnp.float32):
     """The recurrence's result from chunks of :data:`CHUNK` tokens. Shapes as
     :func:`kda_recurrent`; ``g`` and ``beta`` float32. ``dtype`` is the type
-    the matrix products read (their sums, the decays, the substitution and
-    the state are float32). Any length: the tail is padded with tokens that
-    write nothing (``beta = 0``, ``g = 0``). Where nobody asks for a gradient
-    the whole batch is one launch of ``kda_fwd``; where somebody does, that
-    launch gives the result and the gradient is :func:`_kda_chunked`'s, row
-    after row (see the module's docstring). Returns float32 ``[B, H, L, dv]``."""
+    the matrix products read (their sums, the decays, the system's inverse
+    and the state are float32). Any length: the tail is padded with tokens
+    that write nothing (``beta = 0``, ``g = 0``). The whole batch is one
+    launch of ``kda_fwd``, and where somebody asks for a gradient one of
+    ``kda_bwd`` beside it (see the module's docstring). Returns float32
+    ``[B, H, L, dv]``."""
     with jax.named_scope("chunks"):
         return _kda(q, k, v, g, beta, jnp.dtype(dtype))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
 def _kda(q, k, v, g, beta, dtype):
-    return _kda_forward(q, k, v, g, beta, dtype)
+    return _kda_forward(q, k, v, g, beta, dtype, residuals=False)[0]
 
 
 def _kda_fwd(q, k, v, g, beta, dtype):
-    # The residuals are the five inputs: this rule is a checkpoint by hand.
-    return _kda_forward(q, k, v, g, beta, dtype), (q, k, v, g, beta)
-
-
-def _kda_bwd(dtype, inputs, dO):
-    def one_row(x):
-        *row, dO_row = x
-        _, vjp = jax.vjp(lambda *a: _kda_chunked(*(t[None] for t in a), dtype)[0], *row)
-        return vjp(dO_row)
-
-    # Row by row: the backward pass holds one row's intermediates (two dozen
-    # arrays of q's size in float32, and the chunks' starting states), not
-    # the batch's; and of a row longer than BWD_TOKEN_HEADS allows, one group
-    # of heads' (the largest count that divides the heads and fits).
-    B, H, L, _ = inputs[0].shape
-    heads = max(h for h in range(1, H + 1) if H % h == 0 and (h == 1 or h * L <= BWD_TOKEN_HEADS))
-    groups = lambda x: x.reshape(B * (H // heads), heads, *x.shape[2:])  # noqa: E731 (as it is where a row goes whole)
-    grads = jax.lax.map(one_row, tuple(groups(x) for x in (*inputs, dO)))
-    return tuple(g.reshape(x.shape) for g, x in zip(grads, inputs))
-
-
-_kda.defvjp(_kda_fwd, _kda_bwd)
+    # The residuals: the five inputs (a checkpoint by hand), the state each
+    # chunk started from and each chunk's inverse.
+    O, *saved = _kda_forward(q, k, v, g, beta, dtype, residuals=True)
+    return O, (q, k, v, g, beta, *saved)
 
 
 def _chunks(q, k, v, g, beta):
@@ -217,40 +160,10 @@ def _chunks(q, k, v, g, beta):
     return tuple(x.reshape(B, H, (L + pad) // CHUNK, CHUNK, *x.shape[3:]) for x in (q, k, v, g, beta))
 
 
-def _kda_chunked(q, k, v, g, beta, dtype):
-    """The gradient path's forward: pair matrices and substitution in XLA,
-    the recurrence over chunks in ``kda_chunks_fwd`` (``kda_chunks_bwd`` is
-    its transpose)."""
-    chunk = CHUNK
-    B, H, L, _ = q.shape
-    q, k, v, g, beta = (x.astype(jnp.float32) for x in _chunks(q, k, v, g, beta))
-    G = jnp.cumsum(g, axis=3)  # inclusive, from the chunk's start
-    pairs = _pair_matrices(jnp.stack([k, q]), k, G, dtype)
-    tri = np.tril(np.ones((chunk, chunk), np.float32))
-    A = pairs[0] * (tri - np.eye(chunk, dtype=np.float32))
-    Bqk = pairs[1] * tri
-    b = beta[..., None]
-    eG = jnp.exp(G)
-    # (I + Diag(b) A) [W | U0] = Diag(b) [K e^G | V]
-    rhs = jnp.concatenate([b * k * eG, b * v], axis=-1)
-    sol = jax.scipy.linalg.solve_triangular(
-        b * A + jnp.eye(chunk, dtype=jnp.float32), rhs, lower=True, unit_diagonal=True
-    )
-    G_end = G[..., -1:, :]
-    O = _chunk_recurrence(
-        sol, (q * eG).astype(dtype), Bqk.astype(dtype), (k * jnp.exp(G_end - G)).astype(dtype), jnp.exp(G_end)
-    )
-    return O.reshape(B, H, -1, O.shape[-1])[:, :, :L]
-
-
-# ------------------------------------------------- the recurrence over chunks
-# All three kernels work on the TRANSPOSED state ``St = S^T`` (``[dv, dk]``):
-# the decay then runs along the lanes and multiplies the state as the
-# ``[1, dk]`` block it arrives as. The gradient path's two read ``W`` and
-# ``U0`` as XLA has them, the two halves of the substitution's solution
-# ``[W | U0]`` (float32; ``W`` is rounded to the products' type where it is
-# read), and the reverse kernel writes their gradients in the same form: no
-# slice, cast or concatenation of the solution stands beside a kernel.
+# ------------------------------------------------------------- the two kernels
+# Both work on the TRANSPOSED state ``St = S^T`` (``[dv, dk]``): the decay then
+# runs along the lanes and multiplies the state as the ``[1, dk]`` block it
+# arrives as.
 _NT = (((1,), (1,)), ((), ()))  # x @ y^T
 _TN = (((0,), (0,)), ((), ()))  # x^T @ y
 
@@ -259,147 +172,211 @@ def _dot(x, y, dims=(((1,), (0,)), ((), ()))):
     return jax.lax.dot_general(x, y, dims, preferred_element_type=jnp.float32)
 
 
-def _fwd_kernel(sol_ref, Q_ref, B_ref, K_ref, decay_ref, O_ref, *rest):
-    """One chunk of :data:`HEADS` heads: ``U = U0 - W S``, ``O = Q S + B U``,
-    ``S <- decay * S + K^T U``. Where a second result is asked for, the state
-    the chunk started from goes out as the reverse kernel's residual."""
-    *St0_refs, St_ref = rest  # the optional result, then the scratch
-
-    @pl.when(pl.program_id(2) == 0)
-    def _():
-        St_ref[...] = jnp.zeros_like(St_ref)
-
-    dtype, dk = Q_ref.dtype, Q_ref.shape[-1]
-    for h in range(Q_ref.shape[1]):
-        St = St_ref[h]
-        for St0_ref in St0_refs:
-            St0_ref[0, h, 0] = St
-        St_in = St.astype(dtype)
-        U = sol_ref[0, h, 0, :, dk:] - _dot(sol_ref[0, h, 0, :, :dk].astype(dtype), St_in, _NT)
-        U_in = U.astype(dtype)
-        O_ref[0, h, 0] = _dot(Q_ref[0, h, 0], St_in, _NT) + _dot(B_ref[0, h, 0], U_in)
-        St_ref[h] = decay_ref[0, h, 0] * St + _dot(U_in, K_ref[0, h, 0], _TN)
+def _dot32(x, y, dims=(((1,), (0,)), ((), ()))):
+    """A float32 product at full precision (the running sums, the system's
+    inverse and the products with it): it never reads the products' type."""
+    return jax.lax.dot_general(x, y, dims, precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32)
 
 
-def _bwd_kernel(
-    sol_ref, Q_ref, B_ref, K_ref, decay_ref, St0_ref, dO_ref,
-    dsol_ref, dQ_ref, dB_ref, dK_ref, ddecay_ref, dSt_ref,
-):
-    """The same chunk, walked from the last to the first: ``dSt_ref`` holds
-    the cotangent of the state AFTER the chunk; ``U`` is made again from the
-    saved starting state. Every product reads the forward's type, every sum
-    is float32."""
-    @pl.when(pl.program_id(2) == 0)
-    def _():
-        dSt_ref[...] = jnp.zeros_like(dSt_ref)
-
-    dtype, dk = Q_ref.dtype, Q_ref.shape[-1]
-    for h in range(Q_ref.shape[1]):
-        W, Q, K = sol_ref[0, h, 0, :, :dk].astype(dtype), Q_ref[0, h, 0], K_ref[0, h, 0]
-        St, dSt = St0_ref[0, h, 0], dSt_ref[h]
-        St_in, dSt_in, dO_in = St.astype(dtype), dSt.astype(dtype), dO_ref[0, h, 0].astype(dtype)
-        U_in = (sol_ref[0, h, 0, :, dk:] - _dot(W, St_in, _NT)).astype(dtype)
-        dU = _dot(B_ref[0, h, 0], dO_in, _TN) + _dot(K, dSt_in, _NT)
-        dU_in = dU.astype(dtype)
-        dsol_ref[0, h, 0, :, :dk] = -_dot(dU_in, St_in)
-        dsol_ref[0, h, 0, :, dk:] = dU
-        dQ_ref[0, h, 0] = _dot(dO_in, St_in).astype(dtype)
-        dB_ref[0, h, 0] = _dot(dO_in, U_in, _NT).astype(dtype)
-        dK_ref[0, h, 0] = _dot(U_in, dSt_in).astype(dtype)
-        ddecay_ref[0, h, 0] = (dSt * St).sum(0, keepdims=True)
-        dSt_ref[h] = decay_ref[0, h, 0] * dSt + _dot(dO_in, Q, _TN) - _dot(dU_in, W, _TN)
-
-
-def _dot32(x, y):
-    """A float32 product at full precision (the cumulative sum, the
-    substitution): it never reads the products' type."""
-    return jax.lax.dot_general(
-        x, y, (((1,), (0,)), ((), ())), precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32
+def _masks(C, dk):
+    """What both kernels need of a chunk's geometry, made once a grid step."""
+    f32 = jnp.float32
+    row = jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
+    shift = int(math.log2(BLOCK))
+    same = [(row >> s) == (col >> s) for s in range(shift, int(math.log2(C)) + 1)]  # blocks of 16, 32, 64
+    token = jax.lax.broadcasted_iota(jnp.int32, (C, dk), 0)
+    return SimpleNamespace(
+        row=row, col=col, eye=row == col, token=token, identity=(row == col).astype(f32),
+        tril=(row >= col).astype(f32), triu=(row <= col).astype(f32),
+        diagonal=same[0].astype(f32), merges=[(wide & ~narrow).astype(f32) for narrow, wide in zip(same, same[1:])],
+        # the largest exponent of a column's factor against block i's rows
+        limits=[jnp.where(token >> shift == i, MAX_BLOCK_DECAY, 0.0) for i in range(C // BLOCK)],
     )
 
 
-def _whole_kernel(dtype, q_ref, k_ref, v_ref, g_ref, beta_ref, O_ref, St_ref):
-    """One chunk of :data:`HEADS` heads from the layer's own operands: the
-    running sum ``G``, the pair matrices, the substitution and the three
-    lines of ``_fwd_kernel``, all in VMEM; ``O`` is the one thing written.
+def _chunk_pairs(dtype, m, q, k, g, b):
+    """What both kernels build of one chunk and head from the layer's own
+    operands: the running sum ``G`` and the pair matrices ``A`` and ``Bqk``
+    with their factors. A generator: each ``yield`` ends a stage of dependent
+    products, so that the heads computed abreast can be traced stage by stage;
+    what it returns is the chunk.
 
     The pair matrices, a block of :data:`BLOCK` rows at a time: the rows'
     factor ``x_t e^{G_t - F_i}`` (``F_i`` the block's first ``G``: at most 1)
     against ``k_s e^{F_i - G_s}``, whose exponent is not positive for a column
     of an earlier block and is the diagonal block's clipped one for a column
-    of the same; later columns are masked. The system's inverse is built by
-    float32 products: the diagonal blocks' ``(I + N)^-1 = (I - N)(I + N^2)(I +
-    N^4)(I + N^8)`` (``N^16 = 0``), then merged upwards twice, ``[[P, 0], [Q,
-    R]]^-1 = [[P^-1, 0], [-R^-1 Q P^-1, R^-1]]``."""
+    of the same; later columns are masked."""
+    C, dk = k.shape
+    G = _dot32(m.tril, g)  # inclusive, from the chunk's start
+    yield
+    first = [G[i : i + 1] for i in range(0, C, BLOCK)]
+    rows = jnp.exp(G - jnp.concatenate([jnp.broadcast_to(F, (BLOCK, dk)) for F in first], axis=0))
+    k_rows, q_rows = (k * rows).astype(dtype), (q * rows).astype(dtype)
+    A, Bqk, factors, cols = [], [], [], []
+    for i, (F, limit) in enumerate(zip(first, m.limits)):
+        factors.append(jnp.exp(jnp.minimum(F - G, limit)))
+        cols.append((k * factors[i]).astype(dtype))
+        at = slice(i * BLOCK, (i + 1) * BLOCK)
+        A.append(_dot(k_rows[at], cols[i], _NT))
+        Bqk.append(_dot(q_rows[at], cols[i], _NT))
+    return SimpleNamespace(
+        G=G, first=first, rows=rows, k_rows=k_rows, q_rows=q_rows, factors=factors, cols=cols,
+        A=jnp.where(m.row > m.col, jnp.concatenate(A, axis=0), 0.0),
+        Bqk=jnp.where(m.row >= m.col, jnp.concatenate(Bqk, axis=0), 0.0),
+        b_col=jnp.sum(jnp.where(m.eye, b, 0.0), axis=1, keepdims=True),  # b arrives along the lanes: [1, C]
+    )
+
+
+def _inverse(m, n):
+    """``(I + n)^-1`` of a strictly lower triangular ``n`` by float32 products
+    at full precision, from the diagonal blocks upwards: a block's ``(I + N)^-1
+    = (I - N)(I + N^2)(I + N^4)(I + N^8)`` (``N^16 = 0``), then merged upwards
+    twice, ``[[P, 0], [Q, R]]^-1 = [[P^-1, 0], [-R^-1 Q P^-1, R^-1]]``. A
+    generator, as :func:`_chunk_pairs`."""
+    power = m.diagonal * n
+    inv = m.identity - power
+    yield
+    for _ in range(int(math.log2(BLOCK)) - 1):
+        power = _dot32(power, power)
+        yield
+        inv = inv + _dot32(inv, power)
+    for below in m.merges:
+        yield
+        left = _dot32(inv, below * n)
+        yield
+        inv = inv - _dot32(left, inv)
+    return inv
+
+
+def _abreast(head, heads):
+    """Every head of a grid step, :data:`ABREAST` at a time: each chain in
+    turn up to its next ``yield``."""
+    for h in range(0, heads, ABREAST):
+        chains = [head(i) for i in range(h, min(h + ABREAST, heads))]
+        while chains:
+            chains = [chain for chain in chains if next(chain, False) is None]
+
+
+def _whole_kernel(dtype, q_ref, k_ref, v_ref, g_ref, beta_ref, O_ref, *rest):
+    """``kda_fwd``. One chunk of :data:`HEADS` heads from the layer's own
+    operands: the pair matrices, the system's inverse ``T``, ``[W | U0]`` and
+    the three lines ``U = U0 - W S``, ``O = Q S + B U``, ``S <- decay * S + K^T
+    U``, all in VMEM. ``O`` is written, and where two more results are asked
+    for (the ``fwd`` rule) the reverse kernel's residuals: the state the chunk
+    started from, and ``T``."""
+    *residual_refs, St_ref = rest  # the optional results, then the scratch
+
     @pl.when(pl.program_id(2) == 0)
     def _():
         St_ref[...] = jnp.zeros_like(St_ref)
 
     C, dk = q_ref.shape[-2:]
-    f32 = jnp.float32
-    row = jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
-    tril = (row >= col).astype(f32)
-    eye = row == col
-    identity = eye.astype(f32)
-    shift = int(math.log2(BLOCK))
-    same = [(row >> s) == (col >> s) for s in range(shift, int(math.log2(C)) + 1)]  # blocks of 16, 32, 64
-    merges = [(wide & ~narrow).astype(f32) for narrow, wide in zip(same, same[1:])]
-    diagonal = same[0].astype(f32)
-    # the largest exponent of a column's factor against block i's rows
-    token_block = jax.lax.broadcasted_iota(jnp.int32, (C, dk), 0) >> shift
-    limits = [jnp.where(token_block == i, MAX_BLOCK_DECAY, 0.0) for i in range(C // BLOCK)]
+    m = _masks(C, dk)
 
     def head(h):
-        # A generator: each ``yield`` ends a stage of dependent products, so
-        # that the heads computed abreast can be traced stage by stage.
-        q, k, v, b = q_ref[0, h, 0], k_ref[0, h, 0], v_ref[0, h, 0].astype(f32), beta_ref[0, h, 0]  # b: [1, C]
-        G = _dot32(tril, g_ref[0, h, 0])  # inclusive, from the chunk's start
-        yield
-        first = [G[i : i + 1] for i in range(0, C, BLOCK)]
-        rows = jnp.exp(G - jnp.concatenate([jnp.broadcast_to(F, (BLOCK, dk)) for F in first], axis=0))
-        k_rows, q_rows = (k * rows).astype(dtype), (q * rows).astype(dtype)
-        A, Bqk = [], []
-        for i, (F, limit) in enumerate(zip(first, limits)):
-            cols = (k * jnp.exp(jnp.minimum(F - G, limit))).astype(dtype)
-            at = slice(i * BLOCK, (i + 1) * BLOCK)
-            A.append(_dot(k_rows[at], cols, _NT))
-            Bqk.append(_dot(q_rows[at], cols, _NT))
-        A = jnp.where(row > col, jnp.concatenate(A, axis=0), 0.0)
-        Bqk = jnp.where(row >= col, jnp.concatenate(Bqk, axis=0), 0.0)
-        # (I + Diag(b) A)^-1, from the diagonal blocks upwards
-        n = jnp.sum(jnp.where(eye, b, 0.0), axis=1, keepdims=True) * A
-        power = diagonal * n
-        inv = identity - power
-        yield
-        for _ in range(shift - 1):
-            power = _dot32(power, power)
-            yield
-            inv = inv + _dot32(inv, power)
-        for below in merges:
-            yield
-            left = _dot32(inv, below * n)
-            yield
-            inv = inv - _dot32(left, inv)
+        q, k, v, b = q_ref[0, h, 0], k_ref[0, h, 0], v_ref[0, h, 0].astype(jnp.float32), beta_ref[0, h, 0]  # b: [1, C]
+        s = yield from _chunk_pairs(dtype, m, q, k, g_ref[0, h, 0], b)
+        T = yield from _inverse(m, s.b_col * s.A)
         yield
         # [W | U0] = (I + Diag(b) A)^-1 Diag(b) [K e^G | V]
-        inv = inv * b
-        eG = jnp.exp(G)
+        inv = T * b
+        eG = jnp.exp(s.G)
         W, U0 = _dot32(inv, k * eG), _dot32(inv, v)
         yield
-        G_end = G[C - 1 :]
+        G_end = s.G[C - 1 :]
         St = St_ref[h]
+        for ref, residual in zip(residual_refs, (St, T)):
+            ref[0, h, 0] = residual
         St_in = St.astype(dtype)
         U_in = (U0 - _dot(W.astype(dtype), St_in, _NT)).astype(dtype)
         yield
-        O_ref[0, h, 0] = _dot((q * eG).astype(dtype), St_in, _NT) + _dot(Bqk.astype(dtype), U_in)
-        St_ref[h] = jnp.exp(G_end) * St + _dot(U_in, (k * jnp.exp(G_end - G)).astype(dtype), _TN)
+        O_ref[0, h, 0] = _dot((q * eG).astype(dtype), St_in, _NT) + _dot(s.Bqk.astype(dtype), U_in)
+        St_ref[h] = jnp.exp(G_end) * St + _dot(U_in, (k * jnp.exp(G_end - s.G)).astype(dtype), _TN)
 
-    heads = q_ref.shape[1]
-    for h in range(0, heads, ABREAST):
-        abreast = [head(i) for i in range(h, min(h + ABREAST, heads))]
-        while abreast:  # each in turn up to its next ``yield``
-            abreast = [chain for chain in abreast if next(chain, False) is None]
+    _abreast(head, q_ref.shape[1])
+
+
+def _reverse_kernel(
+    dtype, q_ref, k_ref, v_ref, g_ref, beta_ref, St0_ref, T_ref, dO_ref,
+    dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, dSt_ref,
+):
+    """``kda_bwd``. The same chunk, walked from the last to the first:
+    ``dSt_ref`` holds the cotangent of the state AFTER the chunk. The pair
+    matrices are built again (:func:`_chunk_pairs`) and ``U = T R`` with ``T``
+    the saved inverse and ``R = Diag(b) (V - (K e^G) S)`` from the saved
+    starting state; then the three lines' transposes, the system's (``dR = T^T
+    dU``: one product with the inverse, ``d(Diag(b) A) = -strict_tril(dR
+    U^T)``, no chain through the inverse's construction), the pair products'
+    by the same blocks and factors, and the running sum's (a product with the
+    upper triangle of ones). Where the diagonal block's clip binds, nothing
+    passes through the exponent. Every product reads the forward's type, every
+    sum, the running sums and the two products with ``T`` are float32."""
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        dSt_ref[...] = jnp.zeros_like(dSt_ref)
+
+    C, dk = q_ref.shape[-2:]
+    m = _masks(C, dk)
+    blocks = [slice(i, i + BLOCK) for i in range(0, C, BLOCK)]
+
+    def head(h):
+        q, k, v, b = q_ref[0, h, 0], k_ref[0, h, 0], v_ref[0, h, 0].astype(jnp.float32), beta_ref[0, h, 0]  # b: [1, C]
+        s = yield from _chunk_pairs(dtype, m, q, k, g_ref[0, h, 0], b)
+        yield
+        G, b_col, T = s.G, s.b_col, T_ref[0, h, 0]
+        G_end = G[C - 1 :]
+        eG, tail, decay = jnp.exp(G), jnp.exp(G_end - G), jnp.exp(G_end)
+        Q, K, K_end = q * eG, k * eG, k * tail
+        Q_in, K_in, K_end_in = Q.astype(dtype), K.astype(dtype), K_end.astype(dtype)
+        St, dSt, dO_in = St0_ref[0, h, 0], dSt_ref[h], dO_ref[0, h, 0].astype(dtype)
+        St_in, dSt_in = St.astype(dtype), dSt.astype(dtype)
+        unwritten = v - _dot(K_in, St_in, _NT)  # V - (K e^G) S
+        U_in = _dot32(T, b_col * unwritten).astype(dtype)
+        yield
+        # O = Q S + Bqk U and S' = decay * S + K_end^T U, transposed
+        dU = _dot(s.Bqk.astype(dtype), dO_in, _TN) + _dot(K_end_in, dSt_in, _NT)
+        dQ = _dot(dO_in, St_in)
+        dBqk = jnp.where(m.row >= m.col, _dot(dO_in, U_in, _NT), 0.0)
+        dK_end = _dot(U_in, dSt_in)
+        yield
+        # (I + Diag(b) A) U = R, transposed
+        dR = _dot32(T, dU, _TN)
+        bdR = b_col * dR
+        bdR_in = bdR.astype(dtype)
+        yield
+        dM = jnp.where(m.row > m.col, -_dot(dR.astype(dtype), U_in, _NT), 0.0)
+        dA = b_col * dM
+        dbeta = jnp.sum(dR * unwritten, axis=1, keepdims=True) + jnp.sum(dM * s.A, axis=1, keepdims=True)
+        dbeta_ref[0, h, 0] = jnp.sum(jnp.where(m.eye, dbeta, 0.0), axis=0, keepdims=True)  # along the lanes again
+        dv_ref[0, h, 0] = bdR.astype(dv_ref.dtype)
+        dK = -_dot(bdR_in, St_in)
+        dSt_ref[h] = decay * dSt + _dot(dO_in, Q_in, _TN) - _dot(bdR_in, K_in, _TN)
+        dG_end = decay * (dSt * St).sum(0, keepdims=True) + (dK_end * K_end).sum(0, keepdims=True)
+        yield
+        # A and Bqk back through their blocks' two factors; x * dx of a factor is its exponent's cotangent
+        dA_in, dBqk_in = dA.astype(dtype), dBqk.astype(dtype)
+        dk = dK * eG + dK_end * tail
+        dG = dQ * Q + dK * K - dK_end * K_end + jnp.where(m.token == C - 1, dG_end, 0.0)
+        dk_rows, dq_rows = [], []
+        for i, (at, F, limit, factor, cols) in enumerate(zip(blocks, s.first, m.limits, s.factors, s.cols)):
+            dk_rows.append(_dot(dA_in[at], cols))
+            dq_rows.append(_dot(dBqk_in[at], cols))
+            dcols = _dot(dA_in[at], s.k_rows[at], _TN) + _dot(dBqk_in[at], s.q_rows[at], _TN)
+            dk = dk + dcols * factor
+            through = jnp.where(F - G <= limit, dcols * k * factor, 0.0)
+            dG = dG - through + jnp.where(m.token == i * BLOCK, through.sum(0, keepdims=True), 0.0)
+        yield
+        dk_rows, dq_rows = jnp.concatenate(dk_rows, axis=0), jnp.concatenate(dq_rows, axis=0)
+        through = (dk_rows * k + dq_rows * q) * s.rows
+        dG = dG + through
+        for i, at in enumerate(blocks):
+            dG = dG - jnp.where(m.token == i * BLOCK, through[at].sum(0, keepdims=True), 0.0)
+        dq_ref[0, h, 0] = dQ * eG + dq_rows * s.rows
+        dk_ref[0, h, 0] = dk + dk_rows * s.rows
+        dg_ref[0, h, 0] = _dot32(m.triu, dG)  # the running sum, transposed
+
+    _abreast(head, q_ref.shape[1])
 
 
 @functools.partial(jax.jit, static_argnames=("kernel", "name", "results", "state", "reverse", "interpret"))
@@ -408,9 +385,9 @@ def _recurrence_call(kernel, name, operands, results, state, reverse, interpret)
     and in order (backwards where ``reverse``). An operand or result is
     ``[B, H, N, rows, width]`` and is read or written in place, one chunk of
     :data:`HEADS` heads a grid step; ``results`` gives each result's
-    ``(rows, width, dtype)``. The transposed state, ``state = (dv, dk)`` a
-    head, is the one scratch. Jitted, so that a step's twenty-four launches
-    of three kernels are traced and lowered three times, not twenty-four."""
+    ``(rows, width, dtype)``. The transposed state (or its cotangent),
+    ``state = (dv, dk)`` a head, is the one scratch. Jitted, so that a step's
+    launches of a kernel are traced and lowered once."""
     B, H, N = operands[0].shape[:3]
     heads = math.gcd(H, HEADS)
 
@@ -443,64 +420,52 @@ def _wide(x, pad):
 # One kernel object a products' type: ``_recurrence_call`` is jitted on the
 # kernel's identity, and a new ``partial`` a call would lower it a call.
 _whole_kernel_reading = functools.cache(lambda dtype: functools.partial(_whole_kernel, dtype))
+_reverse_kernel_reading = functools.cache(lambda dtype: functools.partial(_reverse_kernel, dtype))
 
 
-def _kda_forward(q, k, v, g, beta, dtype):
-    """``O`` of the whole batch from one launch of ``kda_fwd``."""
-    L, dk, dv = q.shape[2], q.shape[3], v.shape[3]
-    pk, pv = _lanes(dk), _lanes(dv)
+def _operands(q, k, v, g, beta):
+    """The five inputs as the kernels read them: float32 but ``v``, widened to
+    whole lanes, in chunks, ``beta`` along the lanes."""
+    pk, pv = _lanes(q.shape[3]), _lanes(v.shape[3])
     f32 = lambda x: x.astype(jnp.float32)  # noqa: E731
+    q, k, v, g, beta = _chunks(_wide(f32(q), pk), _wide(f32(k), pk), _wide(v, pv), _wide(f32(g), pk), f32(beta))
+    return q, k, v, g, beta[:, :, :, None, :]
+
+
+def _kda_forward(q, k, v, g, beta, dtype, residuals):
+    """``O`` of the whole batch from one launch of ``kda_fwd``, and where
+    ``residuals`` what the reverse kernel reads beside the inputs, as the
+    kernels hold it: the state every chunk started from (``[B, H, N, dv, dk]``
+    float32, widened) and every chunk's inverse (``[B, H, N, C, C]``)."""
+    L, dv = q.shape[2], v.shape[3]
     with jax.named_scope("fwd"):
-        q, k, v, g, beta = _chunks(_wide(f32(q), pk), _wide(f32(k), pk), _wide(v, pv), _wide(f32(g), pk), f32(beta))
-        (O,) = _recurrence_call(
-            _whole_kernel_reading(dtype), "kda_fwd", (q, k, v, g, beta[:, :, :, None, :]),
-            ((CHUNK, dv + pv, jnp.float32),),
-            state=(dv + pv, dk + pk), reverse=False, interpret=jax.default_backend() != "tpu",
+        operands = _operands(q, k, v, g, beta)
+        width, state = operands[2].shape[-1], (operands[2].shape[-1], operands[0].shape[-1])
+        O, *saved = _recurrence_call(
+            _whole_kernel_reading(dtype), "kda_fwd", operands,
+            ((CHUNK, width, jnp.float32),) + ((*state, jnp.float32), (CHUNK, CHUNK, jnp.float32)) * residuals,
+            state=state, reverse=False, interpret=jax.default_backend() != "tpu",
         )
-    return O.reshape(*O.shape[:2], -1, dv + pv)[:, :, :L, :dv]
+    return O.reshape(*O.shape[:2], -1, width)[:, :, :L, :dv], *saved
 
 
-def _recurrence_fwd(sol, Q, Bqk, K, decay, states=True):
-    C, dk = Q.shape[-2:]
-    dv = sol.shape[-1] - dk
-    O, *St0 = _recurrence_call(
-        _fwd_kernel, "kda_chunks_fwd", (sol, Q, Bqk, K, decay),
-        ((C, dv, jnp.float32),) + ((dv, dk, jnp.float32),) * states,
-        state=(dv, dk), reverse=False, interpret=jax.default_backend() != "tpu",
-    )
-    return O, (sol, Q, Bqk, K, decay, *St0)
+def _kda_bwd(dtype, residuals, dO):
+    """The five gradients of the whole batch from one launch of ``kda_bwd``."""
+    *inputs, St0, T = residuals
+    L, beta = inputs[0].shape[2], inputs[4]
+    with jax.named_scope("bwd"):
+        operands = _operands(*inputs)
+        dO = _wide(dO.astype(jnp.float32), operands[2].shape[-1] - dO.shape[-1])
+        dO = jnp.pad(dO, ((0, 0), (0, 0), (0, -L % CHUNK), (0, 0))).reshape(operands[2].shape)
+        grads = _recurrence_call(
+            _reverse_kernel_reading(dtype), "kda_bwd", (*operands, St0, T, dO),
+            tuple((*x.shape[-2:], x.dtype) for x in operands),
+            state=St0.shape[-2:], reverse=True, interpret=jax.default_backend() != "tpu",
+        )
+        *grads, dbeta = grads
+        rows = lambda x: x.reshape(*x.shape[:2], -1, x.shape[-1])[:, :, :L]  # noqa: E731
+        grads = [rows(x)[..., : y.shape[3]].astype(y.dtype) for x, y in zip(grads, inputs)]
+        return (*grads, dbeta.reshape(*dbeta.shape[:2], -1)[:, :, :L].astype(beta.dtype))
 
 
-def _recurrence_bwd(res, dO):
-    sol, Q = res[:2]
-    C, dk = Q.shape[-2:]
-    return tuple(_recurrence_call(
-        _bwd_kernel, "kda_chunks_bwd", (*res, dO),
-        ((C, sol.shape[-1], jnp.float32), (C, dk, Q.dtype), (C, C, Q.dtype), (C, dk, Q.dtype), (1, dk, jnp.float32)),
-        state=(sol.shape[-1] - dk, dk), reverse=True, interpret=jax.default_backend() != "tpu",
-    ))
-
-
-@jax.custom_vjp
-def _recurrence(sol, Q, Bqk, K, decay):
-    return _recurrence_fwd(sol, Q, Bqk, K, decay, states=False)[0]
-
-
-_recurrence.defvjp(_recurrence_fwd, _recurrence_bwd)
-
-
-def _chunk_recurrence(sol, Q, Bqk, K, decay):
-    """``O`` of the three lines above for every chunk in order, the state
-    starting at 0. ``sol``: ``[B, H, N, C, dk + dv]`` float32, the
-    substitution's ``[W | U0]``; ``Q``, ``K``: ``[B, H, N, C, dk]`` and ``Bqk``:
-    ``[B, H, N, C, C]`` in the products' type; ``decay``: ``[B, H, N, 1, dk]``
-    float32. On the TPU the head widths are padded with zeros to whole lanes
-    of 128 (a padded channel holds a state of 0 and adds 0 to every
-    product)."""
-    dk = Q.shape[-1]
-    dv = sol.shape[-1] - dk
-    pk, pv = _lanes(dk), _lanes(dv)
-    if not (pk or pv):
-        return _recurrence(sol, Q, Bqk, K, decay)
-    sol = jnp.concatenate([_wide(sol[..., :dk], pk), _wide(sol[..., dk:], pv)], axis=-1)
-    return _recurrence(sol, _wide(Q, pk), Bqk, _wide(K, pk), _wide(decay, pk))[..., :dv]
+_kda.defvjp(_kda_fwd, _kda_bwd)

@@ -126,25 +126,38 @@ def test_a_broadcast_gate_through_the_kda_kernels_is_the_scalar_gate_rule(L, dec
     assert _rel(np.asarray(got, np.float64), _scalar_gate_rule(q, k, v, g, beta)) < 2e-5
 
 
-def test_a_long_rows_backward_goes_through_in_groups_of_heads(monkeypatch):
-    """A row whose tokens times heads pass ``BWD_TOKEN_HEADS`` is
-    differentiated a group of heads at a time (heads are independent chains):
-    the gradients are the whole row's, and the ``lax.map`` has a step a group."""
-    assert kda.BWD_TOKEN_HEADS == 4096 * 32  # the Kimi cell's row with its 32 heads goes whole, as PR 29 measured it
-    q, k, v, g, beta = _gdn_inputs(128, seed=9)
-    wide = np.broadcast_to(g[..., None], q.shape)
-    loss = lambda *a: (kda.kda_chunked(*a) ** 2).sum()  # noqa: E731
-    whole = jax.grad(loss, argnums=(0, 1, 2, 3, 4))(q, k, v, wide, beta)
-    monkeypatch.setattr(kda, "BWD_TOKEN_HEADS", 2 * 128)  # two of the four heads a step
-    split = jax.grad(loss, argnums=(0, 1, 2, 3, 4))(q, k, v, wide, beta)
-    for a, b in zip(split, whole):
-        assert a.shape == b.shape and _rel(a, b) < 1e-6
-    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0,)))(q, k, v, wide, beta)
-    scans = [eqn.params["length"] for _, _, eqn in _eqns(jaxpr.jaxpr) if eqn.primitive.name == "scan"]
-    assert 4 in scans  # 2 rows x 2 groups of 2 heads
-    monkeypatch.setattr(kda, "BWD_TOKEN_HEADS", 3 * 128)  # 3 does not divide 4 heads: groups of 2 again
-    again = jax.grad(loss, argnums=(0,))(q, k, v, wide, beta)
-    assert _rel(again[0], whole[0]) < 1e-6
+def _scalar_gate_grads(q, k, v, g, beta, cot):
+    """The gradients of ``sum(o * cot)`` through the scalar-gate rule, ``g``
+    ONE number a head and token: the rule as a ``lax.scan`` in float32, which
+    :func:`_scalar_gate_rule` holds to the numpy loop."""
+    def rule(q, k, v, g, beta):
+        def step(S, x):
+            q_t, k_t, v_t, g_t, b_t = x
+            S = jnp.exp(g_t)[..., None, None] * S
+            S = S + b_t[..., None, None] * k_t[..., None] * (v_t - jnp.einsum("bhkv,bhk->bhv", S, k_t))[..., None, :]
+            return S, jnp.einsum("bhkv,bhk->bhv", S, q_t)
+
+        xs = tuple(jnp.moveaxis(jnp.asarray(x), 2, 0) for x in (q, k, v, g, beta))
+        _, o = jax.lax.scan(step, jnp.zeros((*q.shape[:2], q.shape[3], v.shape[3]), jnp.float32), xs)
+        return jnp.moveaxis(o, 0, 2)
+
+    assert _rel(np.asarray(rule(q, k, v, g, beta), np.float64), _scalar_gate_rule(q, k, v, g, beta)) < 2e-5
+    return jax.grad(lambda *a: (rule(*a) * cot).sum(), argnums=(0, 1, 2, 3, 4))(q, k, v, g, beta)
+
+
+@pytest.mark.parametrize("L, decay", [(64, 2.0), (100, 0.1), (192, 3.0)], ids=["a-chunk", "a-padded-tail", "fast-decay"])
+def test_the_gradient_through_a_broadcast_gate_is_the_scalar_gate_rules(L, decay):
+    """``jax.grad`` through what the linear layer runs (``kda_fwd`` and ONE
+    launch of ``kda_bwd``, the head's decay broadcast over its channels)
+    against ``jax.grad`` through the scalar-gate rule: the head's gradient is
+    the sum of its channels', which is what the broadcast's transpose gives."""
+    q, k, v, g, beta = _gdn_inputs(L, seed=L, decay=decay)
+    cot = np.random.default_rng(L + 1).normal(size=v.shape).astype(np.float32)
+    want = _scalar_gate_grads(q, k, v, g, beta, cot)
+    through = lambda q, k, v, g, beta: kda.kda_chunked(q, k, v, jnp.broadcast_to(g[..., None], q.shape), beta)  # noqa: E731
+    got = jax.grad(lambda *a: (through(*a) * cot).sum(), argnums=(0, 1, 2, 3, 4))(q, k, v, g, beta)
+    for name, a, b, limit in zip(("dq", "dk", "dv", "dg", "dbeta"), got, want, (5e-6, 5e-6, 5e-6, 2e-5, 5e-6)):
+        assert a.shape == b.shape and _rel(a, b) < limit, (name, _rel(a, b))
 
 
 def test_grouped_key_heads_are_explicitly_repeated_keys(tiny_params):
@@ -408,7 +421,10 @@ def test_the_step_names_its_scopes_and_none_of_them_is_kda(tiny_params):
         assert any(f"/{scope}" in p for p in paths), scope
     assert not any("/kda/" in f"{p}/" for p in paths)
     kernels = {eqn.params["name"] for path, _, eqn in eqns if eqn.primitive.name == "pallas_call" and "/gdn/chunks" in path}
-    assert kernels == {"kda_fwd", "kda_chunks_fwd", "kda_chunks_bwd"}, kernels
+    assert kernels == {"kda_fwd", "kda_bwd"}, kernels
+    # one launch for the row: nothing under the scope loops, over groups of heads or over chunks
+    assert not [path for path, outer, eqn in eqns if "/gdn/chunks" in path and "pallas_call" not in outer
+                and (eqn.primitive.name in ("scan", "while") or "scan" in outer or "while" in outer)]
     # the attention's scores are the XLA blocks at this length (no kernel under the scope)
     assert not any(eqn.primitive.name == "pallas_call" for path, _, eqn in eqns if "/attn/gated" in path)
 

@@ -33,22 +33,33 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize("grad", [False, True], ids=["kda_chunks_fwd", "kda_chunks_bwd"])
-def test_the_kda_recurrence_compiles_for_the_v5e_at_the_cells_widths(one_chip, monkeypatch, grad):
-    """One row of ``kimilinear-window-fit-l4k``: 32 heads of 128, 64 chunks of
-    64 tokens, bfloat16 products. The wrapper asks the backend whether to
-    interpret; the test answers for the chip it compiles for."""
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    B, H, N, C, d = 1, 32, 64, kda.CHUNK, 128
-    arg = lambda rows, width, dtype: jax.ShapeDtypeStruct((B, H, N, rows, width), dtype, sharding=one_chip)  # noqa: E731
+@pytest.mark.parametrize("B, L", [(4, 64 * kda.CHUNK), (1, 256 * kda.CHUNK)], ids=["kimilinear-4x4096", "qwen3next-1x16384"])
+def test_the_kda_gradient_compiles_for_the_v5e_at_the_cells_batches(one_chip, monkeypatch, B, L):
+    """A step's batch of ``kimilinear-window-fit-l4k`` (4 rows of 64 chunks:
+    the kernels read ``[4, 32, 64, 64, 128]``) and of
+    ``qwen3next-window-fit-l16k`` (1 row of 256 chunks: ``[1, 32, 256, 64,
+    128]``) as the mixers pass them, 32 heads of 128 / 128, bfloat16 products,
+    under ``jax.grad``: the ``fwd`` rule's ``kda_fwd``, which also writes every
+    chunk's starting state and inverse, and ONE ``kda_bwd`` that builds a
+    chunk's pair matrices again and transposes the chunk in VMEM. What they
+    ask of VMEM and of the tiling is refused here, not in the cell; nothing
+    loops over rows or groups of heads, no substitution is left, and the only
+    temporaries are the two residuals (537 MB of states, 268 MB of inverses:
+    64 x 64 float32 in tiles of 128 lanes)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the wrapper asks whether to interpret
+    H, d = 32, 128
+    arg = lambda dtype, *shape: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
     bf16, f32 = jnp.bfloat16, jnp.float32
-    args = (arg(C, 2 * d, f32), arg(C, d, bf16), arg(C, C, bf16), arg(C, d, bf16), arg(1, d, f32))
-    fn = kda._chunk_recurrence
-    if grad:
-        fn = jax.grad(lambda *a: kda._chunk_recurrence(*a).sum(), argnums=tuple(range(5)))
-    text = jax.jit(fn).lower(*args).compile().as_text()
-    assert text.count('custom_call_target="tpu_custom_call"') == (2 if grad else 1)
-    assert "kda_chunks_bwd" in text if grad else "kda_chunks_fwd" in text
+    args = (arg(f32, B, H, L, d), arg(f32, B, H, L, d), arg(bf16, B, H, L, d), arg(f32, B, H, L, d), arg(f32, B, H, L))
+
+    def grads(q, k, v, g, beta, cot):
+        return jax.grad(lambda *a: (kda.kda_chunked(*a, dtype=bf16) * cot).sum(), argnums=(0, 1, 2, 3, 4))(q, k, v, g, beta)
+
+    compiled = jax.jit(grads).lower(*args, arg(f32, B, H, L, d)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2 and "kda_fwd" in text and "kda_bwd" in text
+    assert "triangular" not in text and "while" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.01 * B * H * (L // kda.CHUNK) * (d * d + kda.CHUNK * 128) * 4
 
 
 def test_the_undifferentiated_kda_forward_compiles_for_the_v5e_at_the_cells_widths(one_chip, monkeypatch):
