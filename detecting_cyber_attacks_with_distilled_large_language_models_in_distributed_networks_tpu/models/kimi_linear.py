@@ -22,8 +22,13 @@ A row's padding follows its real tokens, so it changes no real token's state.
 Design notes (TPU):
 * activations in ``cfg.compute_dtype``; parameters, RMS statistics, softmax,
   the router's scores, the decay gate and the recurrent state in float32;
-* ``jax.named_scope``s ``kda``, ``mla``, ``moe/router``, ``moe/experts``,
-  ``moe/shared``, ``ffn_dense`` name every operation of a part, for the trace;
+* ``jax.named_scope``s (``obs/trace.py::SCOPES``) name every operation of a
+  part, for the trace: ``kda`` around a linear layer's mixer and beneath it
+  ``proj`` (the products with a weight), ``conv``, ``prep`` (projections to
+  the kernels' operands), ``chunks`` (``ops/kda.py``: the recurrence, nothing
+  else) and ``norm_gate``; ``mla``; ``moe/router``, ``moe/experts`` (beneath it
+  ``dispatch``, ``grouped``, ``combine``: ``ops/moe.py``), ``moe/shared``;
+  ``ffn_dense``;
 * every expert layer sows, in the collection :data:`ROUTE`, the slots routed
   to each held expert and the slots its buffers could not take; a caller
   that applies the model with ``mutable=[ROUTE]`` gets them (the train step
@@ -71,7 +76,12 @@ class KDAMixer(nn.Module):
 
         def conv_proj(name):
             kernel = self.param(f"{name}_conv", conv_init, (cfg.conv_kernel, H * d), pd)
-            return heads(jax.nn.silu(causal_conv(_dense(cfg, H * d, f"{name}_proj")(x), kernel)))
+            with jax.named_scope("proj"):
+                t = _dense(cfg, H * d, f"{name}_proj")(x)
+            with jax.named_scope("conv"):
+                t = jax.nn.silu(causal_conv(t, kernel))
+            with jax.named_scope("prep"):
+                return heads(t)
 
         q, k, v = conv_proj("q"), conv_proj("k"), conv_proj("v")
 
@@ -79,20 +89,30 @@ class KDAMixer(nn.Module):
             t = t.astype(jnp.float32)
             return t * jax.lax.rsqrt((t * t).sum(-1, keepdims=True) + 1e-6)
 
-        q, k = l2(q) * d**-0.5, l2(k)
+        with jax.named_scope("prep"):
+            q, k = l2(q) * d**-0.5, l2(k)
         a_log = self.param("A_log", _a_log_init, (H,), pd)
         dt_bias = self.param("dt_bias", dt_bias_init, (H * d,), pd)
-        f = _dense(cfg, H * d, "f_b_proj")(_dense(cfg, cfg.gate_rank, "f_a_proj")(x))
-        g = -jnp.exp(a_log.astype(jnp.float32))[None, :, None, None] * heads(
-            jax.nn.softplus(f.astype(jnp.float32) + dt_bias.astype(jnp.float32))
-        )
-        beta = jax.nn.sigmoid(_dense(cfg, H, "b_proj")(x).astype(jnp.float32)).transpose(0, 2, 1)
+        with jax.named_scope("proj"):
+            f = _dense(cfg, H * d, "f_b_proj")(_dense(cfg, cfg.gate_rank, "f_a_proj")(x))
+        with jax.named_scope("prep"):
+            g = -jnp.exp(a_log.astype(jnp.float32))[None, :, None, None] * heads(
+                jax.nn.softplus(f.astype(jnp.float32) + dt_bias.astype(jnp.float32))
+            )
+        with jax.named_scope("proj"):
+            b = _dense(cfg, H, "b_proj")(x)
+        with jax.named_scope("prep"):
+            beta = jax.nn.sigmoid(b.astype(jnp.float32)).transpose(0, 2, 1)
         o = kda_chunked(q, k, v, g, beta, dtype=jnp.dtype(cfg.compute_dtype))  # [B, H, L, d] float32
         scale = self.param("o_norm", nn.initializers.ones, (d,), pd)
-        o = o * jax.lax.rsqrt((o * o).mean(-1, keepdims=True) + cfg.rms_norm_eps) * scale
-        gate = _dense(cfg, H * d, "g_b_proj")(_dense(cfg, cfg.gate_rank, "g_a_proj")(x))
-        o = o.transpose(0, 2, 1, 3).reshape(B, L, H * d) * jax.nn.sigmoid(gate.astype(jnp.float32))
-        return _dense(cfg, cfg.dim, "o_proj")(o.astype(x.dtype))
+        with jax.named_scope("norm_gate"):
+            o = o * jax.lax.rsqrt((o * o).mean(-1, keepdims=True) + cfg.rms_norm_eps) * scale
+        with jax.named_scope("proj"):
+            gate = _dense(cfg, H * d, "g_b_proj")(_dense(cfg, cfg.gate_rank, "g_a_proj")(x))
+        with jax.named_scope("norm_gate"):
+            o = o.transpose(0, 2, 1, 3).reshape(B, L, H * d) * jax.nn.sigmoid(gate.astype(jnp.float32))
+        with jax.named_scope("proj"):
+            return _dense(cfg, cfg.dim, "o_proj")(o.astype(x.dtype))
 
 
 class MLAMixer(nn.Module):

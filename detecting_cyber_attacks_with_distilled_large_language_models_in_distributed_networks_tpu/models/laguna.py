@@ -26,10 +26,13 @@ selection bias; SiLU, and no query/key norm.
 Design notes (TPU): as ``models/kimi_linear.py``'s (bf16 activations; float32
 parameters, RMS statistics, rotation, softmax, gate and router scores; the
 routing counters; per-layer recomputation that keeps the router's choice; the
-tree's top level). ``jax.named_scope``s: ``attn/window`` or ``attn/full``
-around a layer's attention, beneath each ``qkv``, ``rope``, ``scores``
-(``ops/causal_attention.py``: the blocked softmax attention, nothing else) and
-``out``; the FFN's as the Kimi class names them.
+tree's top level). ``jax.named_scope``s (``obs/trace.py::SCOPES``):
+``attn/window`` or ``attn/full`` around a layer's attention (the module's name
+and a scope inside it), beneath each ``qkv`` (the four projections and the
+gate's sigmoid), ``rope``, ``scores`` (``ops/causal_attention.py``: the causal
+attention, nothing else; its Pallas kernels under ``causal_flash``) and ``out``
+(the gate's product and the output projection); the FFN's as the Kimi class
+names them.
 """
 
 from __future__ import annotations

@@ -40,9 +40,12 @@ Design notes (TPU): as ``models/kimi_linear.py``'s (bf16 activations; float32
 parameters, norms, rotation, softmax, gates, the recurrence's decay and state,
 the router's scores; the routing counters; per-layer recomputation that keeps
 the router's choice and the attention's result; the tree's top level).
-``jax.named_scope``s: ``gdn`` around a linear layer's mixer, beneath it
-``conv``, ``chunks`` (``ops/kda.py``: the chunked recurrence, nothing else) and
-``norm_gate``; ``attn/gated`` around an attention layer's (the module's
+``jax.named_scope``s (``obs/trace.py::SCOPES``): ``gdn`` around a linear
+layer's mixer, beneath it what ``kda`` has in ``models/kimi_linear.py``:
+``proj`` (``qkvz_proj``, ``ba_proj``, ``o_proj``), ``conv``, ``prep`` (the
+head-major copies, l2 norms, repeated keys, ``beta``, the decay and its
+broadcast), ``chunks`` (``ops/kda.py``: the chunked recurrence, nothing else)
+and ``norm_gate``; ``attn/gated`` around an attention layer's (the module's
 name and a scope inside it, as ``models/laguna.py`` has them), beneath it
 ``scores`` (``ops/causal_attention.py``, nothing else); the FFN's as the other
 classes name them. Nothing here runs under a scope named ``kda``.
@@ -78,8 +81,9 @@ class GatedDeltaNet(nn.Module):
         Hk, Hv, dk, dv = cfg.linear_key_heads, cfg.linear_value_heads, cfg.linear_key_dim, cfg.linear_value_dim
         pd = jnp.dtype(cfg.param_dtype)
         qk, vz = Hk * dk, Hv * dv
-        proj = dense(cfg, 2 * qk + 2 * vz, "qkvz_proj")(x)
-        ba = dense(cfg, 2 * Hv, "ba_proj")(x).astype(jnp.float32)
+        with jax.named_scope("proj"):
+            proj = dense(cfg, 2 * qk + 2 * vz, "qkvz_proj")(x)
+            ba = dense(cfg, 2 * Hv, "ba_proj")(x).astype(jnp.float32)
         with jax.named_scope("conv"):
             kernel = self.param("conv", conv_init, (cfg.conv_kernel, 2 * qk + vz), pd)
             qkv = jax.nn.silu(causal_conv(proj[..., : 2 * qk + vz], kernel))
@@ -91,23 +95,25 @@ class GatedDeltaNet(nn.Module):
             t = t.astype(jnp.float32)
             return t * jax.lax.rsqrt((t * t).sum(-1, keepdims=True) + 1e-6)
 
-        # A key head serves Hv / Hk value heads: value head h reads key head h // (Hv / Hk).
-        q = jnp.repeat(l2(heads(qkv[..., :qk], Hk)) * dk**-0.5, Hv // Hk, axis=1)
-        k = jnp.repeat(l2(heads(qkv[..., qk : 2 * qk], Hk)), Hv // Hk, axis=1)
-        v = heads(qkv[..., 2 * qk :], Hv)
         a_log = self.param("A_log", _a_log_init, (Hv,), pd)
         dt_bias = self.param("dt_bias", dt_bias_init, (Hv,), pd)
-        beta = jax.nn.sigmoid(ba[..., :Hv]).transpose(0, 2, 1)  # [B, Hv, L]
-        g = -jnp.exp(a_log.astype(jnp.float32)) * jax.nn.softplus(ba[..., Hv:] + dt_bias.astype(jnp.float32))
-        # One decay a head and token, over every channel of the head's state.
-        g = jnp.broadcast_to(g.transpose(0, 2, 1)[..., None], (B, Hv, L, dk))
+        with jax.named_scope("prep"):
+            # A key head serves Hv / Hk value heads: value head h reads key head h // (Hv / Hk).
+            q = jnp.repeat(l2(heads(qkv[..., :qk], Hk)) * dk**-0.5, Hv // Hk, axis=1)
+            k = jnp.repeat(l2(heads(qkv[..., qk : 2 * qk], Hk)), Hv // Hk, axis=1)
+            v = heads(qkv[..., 2 * qk :], Hv)
+            beta = jax.nn.sigmoid(ba[..., :Hv]).transpose(0, 2, 1)  # [B, Hv, L]
+            g = -jnp.exp(a_log.astype(jnp.float32)) * jax.nn.softplus(ba[..., Hv:] + dt_bias.astype(jnp.float32))
+            # One decay a head and token, over every channel of the head's state.
+            g = jnp.broadcast_to(g.transpose(0, 2, 1)[..., None], (B, Hv, L, dk))
         o = kda_chunked(q, k, v, g, beta, dtype=jnp.dtype(cfg.compute_dtype))  # [B, Hv, L, dv] float32
         with jax.named_scope("norm_gate"):
             scale = self.param("o_norm", nn.initializers.ones, (dv,), pd)
             o = o * jax.lax.rsqrt((o * o).mean(-1, keepdims=True) + cfg.rms_norm_eps) * scale
             z = proj[..., 2 * qk + vz :].astype(jnp.float32)
             o = o.transpose(0, 2, 1, 3).reshape(B, L, vz) * jax.nn.silu(z)
-        return dense(cfg, cfg.dim, "o_proj")(o.astype(x.dtype))
+        with jax.named_scope("proj"):
+            return dense(cfg, cfg.dim, "o_proj")(o.astype(x.dtype))
 
 
 class GatedAttention(nn.Module):

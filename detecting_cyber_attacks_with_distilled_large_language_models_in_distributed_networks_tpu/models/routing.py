@@ -2,10 +2,12 @@
 
 A model with expert layers sows, in the variable collection :data:`ROUTE`,
 per layer, the token-slots routed to each expert this chip holds (``slots``,
-``[held]`` int32) and the slots its buffers could not take (``overflow``,
+``[held]`` int32), the slots its buffers could not take (``overflow``,
 int32; they are not in the layer's result, so a caller that needs every
-token checks it is 0). A model without expert layers sows nothing and every
-function here returns its empty value, so a caller treats both alike.
+token checks it is 0) and the rows its shared buffer offered (``rows``,
+int32: ``slots`` over it is the buffer's fill). A model without expert layers
+sows nothing and every function here returns its empty value, so a caller
+treats both alike.
 """
 
 from __future__ import annotations
@@ -19,13 +21,14 @@ ROUTE = "route"
 
 def route_totals(sown) -> dict:
     """``mutable=[ROUTE]``'s second result summed over the layers:
-    ``{"slots": [held] int32, "overflow": [] int32}``, or ``{}``."""
+    ``{"slots": [held] int32, "overflow": [] int32, "rows": [] int32}``, or
+    ``{}``."""
     layers = jax.tree.leaves(
         dict(sown).get(ROUTE, {}), is_leaf=lambda n: isinstance(n, dict) and "slots" in n
     )
     if not layers:
         return {}
-    return {key: sum(layer[key] for layer in layers) for key in ("slots", "overflow")}
+    return {key: sum(layer[key] for layer in layers) for key in ("slots", "overflow", "rows")}
 
 
 def route_zeros(cfg) -> dict | None:
@@ -35,4 +38,8 @@ def route_zeros(cfg) -> dict | None:
     held = getattr(cfg, "experts_held", None)
     if held is None:
         return None
-    return {"slots": jnp.zeros((held,), jnp.int32), "overflow": jnp.zeros((), jnp.int32)}
+    return {
+        "slots": jnp.zeros((held,), jnp.int32),
+        "overflow": jnp.zeros((), jnp.int32),
+        "rows": jnp.zeros((), jnp.int32),
+    }

@@ -10,6 +10,9 @@ Seven pieces (see each module's docstring):
   ``ANNOTATIONS``) on the ``/host:CPU`` plane of a ``jax.profiler``
   trace (``--profile-dir``, ``fedtpu obs profile --capture``), on the
   clock of the device's operations; with no session it writes nothing.
+  And the device plane's names: ``SCOPES``, the ``jax.named_scope``s the
+  models, the expert layer and the train steps open around their parts,
+  which every compiled instruction carries in its ``op_name`` path.
 * :mod:`.metrics` — in-process counters/gauges/histograms exposed over a
   stdlib-HTTP ``/metrics`` endpoint in Prometheus text format, plus the
   machine-readable ``/metrics.json`` twin.
@@ -96,6 +99,7 @@ from .timeline import (  # noqa: F401
 from .trace import (  # noqa: F401
     ANNOTATIONS,
     SCHEMA,
+    SCOPES,
     SPAN_NAMES,
     TRACE_META_KEY,
     Tracer,

@@ -300,12 +300,14 @@ def default_registry() -> MetricsRegistry:
     return _DEFAULT
 
 
-def publish_route(slots, overflow: int, *, first_expert: int = 0) -> None:
+def publish_route(slots, overflow: int, rows: int, *, first_expert: int = 0) -> None:
     """What a fit read of an expert model's routing counters
     (train/engine.py ``fit/route_read``): the token-slots routed to each
     expert this chip holds, labelled by the expert's index in the whole
-    layer, and the slots its buffers could not take (must stay 0: they are
-    not in the model's result)."""
+    layer, the slots its buffers could not take (must stay 0: they are
+    not in the model's result), and the rows those buffers offered (the
+    routed slots over them is the buffers' fill; the rest is padding the
+    expert layer's gather, grouped products and scatter-add carry)."""
     reg = default_registry()
     for i, n in enumerate(slots):
         reg.counter(
@@ -317,6 +319,10 @@ def publish_route(slots, overflow: int, *, first_expert: int = 0) -> None:
         "fedtpu_moe_overflow_slots_total",
         help="token-slots beyond a held expert's buffer (not computed)",
     ).inc(float(overflow))
+    reg.counter(
+        "fedtpu_moe_buffer_rows_total",
+        help="rows the held experts' shared buffers offered, summed over layers and launches",
+    ).inc(float(rows))
 
 
 class _Handler(BaseHTTPRequestHandler):

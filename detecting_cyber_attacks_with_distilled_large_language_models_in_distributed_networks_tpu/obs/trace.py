@@ -92,8 +92,8 @@ Timestamps are wall-clock unix seconds (``ts``) with a separately
 measured monotonic duration (``dur_s``): cross-process correlation needs
 a shared clock, phase arithmetic needs one that never steps backwards.
 
-Two planes
-----------
+Three planes
+------------
 The spans above are the OPERATOR's plane: a cross-process round timeline
 on the wall clock, one JSONL record per span, read by ``fedtpu obs
 timeline``. They cannot say what the host was doing while the DEVICE
@@ -126,6 +126,49 @@ vocabulary (the profiler-clock twin of :data:`SPAN_NAMES`)::
     agg             the round's aggregation (the agg span's twin)
     reset           the per-round optimizer re-init
     round_anchor    the round-start parameter copy (DP / FedOpt)
+
+The third plane is the DEVICE's own: what an instruction of a compiled
+program belongs to. A ``jax.named_scope`` around the lines that do a part's
+work puts its name into the ``op_name`` path of every instruction traced
+there (``jit(engine_train_step)/jvp(M)/encoder/layer_1/kda/kda/proj/...``:
+flax's module path with the program's scopes between), at no cost to the
+program: the path is instruction metadata, the executable is the one it
+was. Any profile of the device (``--profile-dir``, ``fedtpu obs profile
+--capture``, the benchmark's ``--trace 1``) and the compiled program's text
+carry it, and a reader sums device time by it
+(benchmark/reduce/scope_ops.py). JAX adds the PASS itself: a checkpointed
+block's forward runs under ``jvp(...)``, its recomputation under
+``transpose(jvp(...))/.../rematted_computation/``, its backward under
+``transpose(jvp(...))`` without. :data:`SCOPES` is this plane's vocabulary
+(path fragments; a child is listed under its parent and means the same
+under every parent that has it)::
+
+    kda, gdn        a linear (delta-rule) mixer: models/kimi_linear.py,
+                    models/qwen3_next.py; beneath either
+      proj          every product with a weight into and out of the mixer
+      conv          the short causal convolution and its activation
+      prep          projections -> the kernels' operands: the head-major
+                    copies, l2 norms, the log-decay and its broadcast,
+                    beta, the repeated keys
+      chunks        the chunked recurrence and nothing else (ops/kda.py),
+                    beneath it ``fwd`` and ``bwd``, one Pallas kernel each
+      norm_gate     the output norm, the gate's product, the copy back
+    mla             the latent attention (models/kimi_linear.py)
+    attn/window, attn/full   models/laguna.py; beneath either ``qkv``,
+                    ``rope``, ``scores``, ``out``
+    attn/gated      models/qwen3_next.py; beneath it ``scores``
+    causal_flash    the attention's Pallas kernels, whoever calls
+                    (ops/causal_attention.py)
+    moe/router, moe/experts, moe/shared   the expert layer
+                    (models/blocks.py); beneath ``moe/experts``
+                    (ops/moe.py::held_experts_ffn)
+      dispatch      token-slots -> buffer rows, and the rows gathered
+      grouped       the grouped products and the SwiGLU between them
+      combine       weights and mask on the rows, scatter-add per token
+    ffn_dense       a dense SwiGLU layer
+    optimizer       the update tail of a train step: the optimizer, the
+                    warm-up scale, the parameters' update (train/engine.py,
+                    train/fedsteps.py)
 """
 
 from __future__ import annotations
@@ -191,6 +234,45 @@ ANNOTATIONS = (
     "agg",
     "reset",
     "round_anchor",
+)
+
+#: The scope vocabulary of the device plane (see "Three planes" above), as
+#: fragments of an instruction's path: every ``jax.named_scope`` of
+#: ``models/``, ``ops/`` and ``train/`` opens one of these (``attn/`` before
+#: ``window``, ``full`` and ``gated`` is the attention module's own name in
+#: the path; the scope opened is the part after it), and a per-layer metric
+#: reads device time by such a fragment (``kda/proj``,
+#: ``moe/experts/dispatch``).
+SCOPES = (
+    "kda",
+    "gdn",
+    "mla",
+    "attn/window",
+    "attn/full",
+    "attn/gated",
+    "moe/router",
+    "moe/experts",
+    "moe/shared",
+    "ffn_dense",
+    "optimizer",
+    # beneath kda and gdn
+    "proj",
+    "conv",
+    "prep",
+    "chunks",
+    "fwd",
+    "bwd",
+    "norm_gate",
+    # beneath attn/window and attn/full (scores beneath attn/gated too)
+    "qkv",
+    "rope",
+    "scores",
+    "out",
+    "causal_flash",
+    # beneath moe/experts
+    "dispatch",
+    "grouped",
+    "combine",
 )
 
 #: Wire meta key the trace id rides under (comm/server.py reply meta,

@@ -17,7 +17,17 @@ can never overflow; a smaller capacity (a multiple of the mean total) holds
 less memory, and the slots it cannot take are COUNTED and handed back
 (``overflow``): ``Trainer`` reads the count after every epoch and every
 evaluation, publishes it and logs an error when it is not 0, and the
-benchmark's ``correct`` compares it with 0; none is dropped in silence.
+benchmark's ``correct`` compares it with 0; none is dropped in silence. The
+rows the buffer OFFERS are counted beside the slots that filled them
+(``models/blocks.py`` sows ``rows``): what is not filled is padding that the
+gather, the grouped products' operands and the scatter-add still carry.
+
+``jax.named_scope``s (``obs/trace.py::SCOPES``; the caller opens
+``moe/experts`` around :func:`held_experts_ffn`): ``dispatch`` (token-slots to
+buffer rows: the one-hot, the cumulative sums, the scatters of ``token_of`` and
+``weight_of``, and the rows' gather), ``grouped`` (the three grouped products
+and the SwiGLU between them), ``combine`` (mask and weights on the rows, and
+the scatter-add per token).
 """
 
 from __future__ import annotations
@@ -80,30 +90,33 @@ def held_experts_ffn(
     T, D = x.shape
     held = w_gate.shape[0]
     k = idx.shape[-1]
-    local = (idx - offset).reshape(T * k)
-    here = ((local >= 0) & (local < held)) & jnp.repeat(valid, k)
-    onehot = (here[:, None] & (local[:, None] == jnp.arange(held)[None, :])).astype(jnp.int32)
-    slots = onehot.sum(0)
-    # A slot's row: its expert's first row (the experts before it, summed)
-    # plus how many earlier slots named the same expert.
-    first = jnp.cumsum(slots) - slots
-    row = ((jnp.cumsum(onehot, axis=0) - 1 + first[None, :]) * onehot).sum(-1)
-    row = jnp.where(here & (row < capacity), row, capacity)  # out of range: dropped by the scatter
-    groups = jnp.clip(capacity - first, 0, slots)  # the rows of each expert that the buffer holds
-    overflow = slots.sum() - groups.sum()
-    token = jnp.repeat(jnp.arange(T, dtype=jnp.int32), k)
-    token_of = jnp.full((capacity,), T, jnp.int32).at[row].set(token, mode="drop")
-    weight_of = jnp.zeros((capacity,), jnp.float32).at[row].set(
-        w.reshape(T * k).astype(jnp.float32), mode="drop"
-    )
-    x_pad = jnp.concatenate([x.astype(dtype), jnp.zeros((1, D), dtype)], axis=0)
-    xe = x_pad[token_of]  # [capacity, D]; row T is the empty rows' zero
-    h = jax.nn.silu(jax.lax.ragged_dot(xe, w_gate.astype(dtype), groups)) * jax.lax.ragged_dot(
-        xe, w_up.astype(dtype), groups
-    )
-    ye = jax.lax.ragged_dot(h, w_down.astype(dtype), groups, preferred_element_type=jnp.float32)
-    # Rows past the last group belong to no expert: whatever the grouped
-    # product left there is not a result.
-    ye = jnp.where((jnp.arange(capacity) < groups.sum())[:, None], ye * weight_of[:, None], 0.0)
-    y = jnp.zeros((T + 1, D), jnp.float32).at[token_of].add(ye)
-    return y[:T], slots, overflow.astype(jnp.int32)
+    with jax.named_scope("dispatch"):
+        local = (idx - offset).reshape(T * k)
+        here = ((local >= 0) & (local < held)) & jnp.repeat(valid, k)
+        onehot = (here[:, None] & (local[:, None] == jnp.arange(held)[None, :])).astype(jnp.int32)
+        slots = onehot.sum(0)
+        # A slot's row: its expert's first row (the experts before it, summed)
+        # plus how many earlier slots named the same expert.
+        first = jnp.cumsum(slots) - slots
+        row = ((jnp.cumsum(onehot, axis=0) - 1 + first[None, :]) * onehot).sum(-1)
+        row = jnp.where(here & (row < capacity), row, capacity)  # out of range: dropped by the scatter
+        groups = jnp.clip(capacity - first, 0, slots)  # the rows of each expert that the buffer holds
+        overflow = slots.sum() - groups.sum()
+        token = jnp.repeat(jnp.arange(T, dtype=jnp.int32), k)
+        token_of = jnp.full((capacity,), T, jnp.int32).at[row].set(token, mode="drop")
+        weight_of = jnp.zeros((capacity,), jnp.float32).at[row].set(
+            w.reshape(T * k).astype(jnp.float32), mode="drop"
+        )
+        x_pad = jnp.concatenate([x.astype(dtype), jnp.zeros((1, D), dtype)], axis=0)
+        xe = x_pad[token_of]  # [capacity, D]; row T is the empty rows' zero
+    with jax.named_scope("grouped"):
+        h = jax.nn.silu(jax.lax.ragged_dot(xe, w_gate.astype(dtype), groups)) * jax.lax.ragged_dot(
+            xe, w_up.astype(dtype), groups
+        )
+        ye = jax.lax.ragged_dot(h, w_down.astype(dtype), groups, preferred_element_type=jnp.float32)
+    with jax.named_scope("combine"):
+        # Rows past the last group belong to no expert: whatever the grouped
+        # product left there is not a result.
+        ye = jnp.where((jnp.arange(capacity) < groups.sum())[:, None], ye * weight_of[:, None], 0.0)
+        y = jnp.zeros((T + 1, D), jnp.float32).at[token_of].add(ye)
+        return y[:T], slots, overflow.astype(jnp.int32)

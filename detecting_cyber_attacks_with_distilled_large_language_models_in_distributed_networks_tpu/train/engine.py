@@ -301,9 +301,10 @@ def make_train_step(
         # drift between them.
         if constrain is not None:
             grads = constrain(grads)
-        updates, opt_state = optimizer.update(grads, state.opt_state, state.params)
-        updates = apply_warmup(updates, state.step, warmup_steps)
-        params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = optimizer.update(grads, state.opt_state, state.params)
+            updates = apply_warmup(updates, state.step, warmup_steps)
+            params = optax.apply_updates(state.params, updates)
         if constrain is not None:
             params, opt_state = constrain(params), constrain(opt_state)
         route = state.route
@@ -800,22 +801,23 @@ class Trainer:
         with annotate("fit/route_read"):
             read = jax.device_get(state.route)
         slots = np.asarray(read["slots"], np.int64)
-        overflow = int(read["overflow"])
-        self._note_route(slots, overflow, "fit")
-        prev = self.last_route or {"slots": 0, "overflow": 0}
+        overflow, rows = int(read["overflow"]), int(read["rows"])
+        self._note_route(slots, overflow, rows, "fit")
+        prev = self.last_route or {"slots": 0, "overflow": 0, "rows": 0}
         self.last_route = {
             "slots": prev["slots"] + slots,
             "overflow": prev["overflow"] + overflow,
+            "rows": prev["rows"] + rows,
         }
         return state._replace(route=jax.tree.map(jnp.zeros_like, state.route))
 
-    def _note_route(self, slots: np.ndarray, overflow: int, where: str) -> None:
+    def _note_route(self, slots: np.ndarray, overflow: int, rows: int, where: str) -> None:
         """Publish what ``where`` (``fit`` or ``evaluate``) read of the
         routing counters, and say so loudly when the expert buffers could
         not take every slot: those slots are NOT in the model's result
         (ops/moe.py), so the epoch trained, or the evaluation judged,
         another function than the model's."""
-        publish_route(slots, overflow, first_expert=self.model_cfg.expert_offset)
+        publish_route(slots, overflow, rows, first_expert=self.model_cfg.expert_offset)
         if overflow:
             log.error(
                 f"{where}: {overflow} of {int(slots.sum())} token-slots routed to the "
@@ -873,7 +875,8 @@ class Trainer:
                     read = jax.device_get(route)
                     metrics["routed_overflow"] = int(read["overflow"])
                     self._note_route(
-                        np.asarray(read["slots"], np.int64), metrics["routed_overflow"], "evaluate"
+                        np.asarray(read["slots"], np.int64), metrics["routed_overflow"],
+                        int(read["rows"]), "evaluate",
                     )
             if collect_probs:
                 if probs_dev:

@@ -114,9 +114,10 @@ def make_packed_step(
         )(params)
         if constrain is not None:
             grads = constrain(grads)
-        updates, new_opt = optimizer.update(grads, opt_state, params)
-        updates = apply_warmup(updates, step, wsteps)
-        new_params = optax.apply_updates(params, updates)
+        with jax.named_scope("optimizer"):
+            updates, new_opt = optimizer.update(grads, opt_state, params)
+            updates = apply_warmup(updates, step, wsteps)
+            new_params = optax.apply_updates(params, updates)
         if constrain is not None:
             new_params = constrain(new_params)
             new_opt = constrain(new_opt)
@@ -230,9 +231,10 @@ def build_federated_steps(
         # The 1/shards above makes that sum the mean.
         (_, task), grads = jax.value_and_grad(objective, has_aux=True)(params)
         task = jax.lax.pmean(task, "data")
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        updates = apply_warmup(updates, step, wsteps)
-        return optax.apply_updates(params, updates), opt_state, task
+        with jax.named_scope("optimizer"):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            updates = apply_warmup(updates, step, wsteps)
+            return optax.apply_updates(params, updates), opt_state, task
 
     state_sh = FedState(csh, csh, sh.replicated, csh, sh.replicated)
     batch_sh = {"input_ids": bsh, "attention_mask": bsh, "labels": bsh}

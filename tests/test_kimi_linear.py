@@ -534,7 +534,7 @@ def test_overflow_is_counted_and_said_loudly_by_fit_and_by_evaluate(monkeypatch)
     state, _ = trainer.fit(trainer.init_state(seed=0, params=params), split, batch_size=4, epochs=1)
     routed = int(mask.sum()) * cfg.experts_per_token * len(moe_layers)
     buffer = 4 * cfg.max_len * 2 * len(moe_layers)  # rows of the step's buffers
-    assert trainer.last_route == {"slots": trainer.last_route["slots"], "overflow": routed - buffer} and routed > buffer
+    assert trainer.last_route == {"slots": trainer.last_route["slots"], "overflow": routed - buffer, "rows": buffer} and routed > buffer
     assert int(trainer.last_route["slots"].sum()) == routed
     assert len(said) == 1 and said[0].startswith(f"fit: {routed - buffer} of {routed} token-slots") and "NOT computed" in said[0]
     metrics = trainer.evaluate(state.params, split, batch_size=4, collect_probs=False)
