@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.causal_attention import ATTENTION_RESULT
-from ..ops.moe import ROUTE_CHOICE, expert_capacity, held_experts_ffn, route_topk
+from ..ops.moe import ROUTE_CHOICE, expert_rungs, held_experts_ffn, route_topk
 from .routing import ROUTE
 
 
@@ -135,22 +135,23 @@ class SparseMoE(nn.Module):
             scores = jax.nn.sigmoid(logits) if self.score == "sigmoid" else jax.nn.softmax(logits, axis=-1)
             idx, w = route_topk(scores, bias, cfg.experts_per_token, cfg.routed_scale)
         self.sow("intermediates", "chosen", idx)
-        capacity = expert_capacity(B * L, cfg.experts_per_token, cfg.n_experts, held)
+        rungs = expert_rungs(B * L, cfg.experts_per_token, cfg.n_experts, held)
         with jax.named_scope("moe/experts"):
-            y, slots, overflow = held_experts_ffn(
+            y, slots, overflow, rows = held_experts_ffn(
                 flat, idx, w, attention_mask.reshape(B * L) > 0,
                 self.param("experts_gate", init, (held, D, F), pd),
                 self.param("experts_up", init, (held, D, F), pd),
                 self.param("experts_down", init, (held, F, D), pd),
                 offset=cfg.expert_offset,
-                capacity=capacity,
+                capacity=rungs[-1],
                 dtype=jnp.dtype(cfg.compute_dtype),
+                rungs=rungs,
             )
         add = lambda a, b: a + b  # noqa: E731
         self.sow(ROUTE, "slots", slots, reduce_fn=add, init_fn=lambda: jnp.zeros_like(slots))
         self.sow(ROUTE, "overflow", overflow, reduce_fn=add, init_fn=lambda: jnp.zeros_like(overflow))
-        # The rows this call's buffer offered: ``slots`` filled some, the rest is padding.
-        self.sow(ROUTE, "rows", jnp.int32(capacity), reduce_fn=add, init_fn=lambda: jnp.zeros((), jnp.int32))
+        # The rows of its buffer this call moved: ``slots`` filled some, the rest is padding.
+        self.sow(ROUTE, "rows", rows, reduce_fn=add, init_fn=lambda: jnp.zeros((), jnp.int32))
         with jax.named_scope("moe/shared"):
             shared = SwiGLU(cfg, cfg.shared_dim, name="shared")(x)
             if self.shared_gate:

@@ -4,8 +4,10 @@ A model with expert layers sows, in the variable collection :data:`ROUTE`,
 per layer, the token-slots routed to each expert this chip holds (``slots``,
 ``[held]`` int32), the slots its buffers could not take (``overflow``,
 int32; they are not in the layer's result, so a caller that needs every
-token checks it is 0) and the rows its shared buffer offered (``rows``,
-int32: ``slots`` over it is the buffer's fill). A model without expert layers
+token checks it is 0) and the rows its shared buffer MOVED (``rows``,
+int32: the length of the prefix the call's gather, grouped operands and
+scatter-add ran over, ``ops/moe.py::expert_rungs``; ``slots`` over it is the
+fill of what was moved). A model without expert layers
 sows nothing and every function here returns its empty value, so a caller
 treats both alike.
 """

@@ -305,9 +305,11 @@ def publish_route(slots, overflow: int, rows: int, *, first_expert: int = 0) -> 
     (train/engine.py ``fit/route_read``): the token-slots routed to each
     expert this chip holds, labelled by the expert's index in the whole
     layer, the slots its buffers could not take (must stay 0: they are
-    not in the model's result), and the rows those buffers offered (the
-    routed slots over them is the buffers' fill; the rest is padding the
-    expert layer's gather, grouped products and scatter-add carry)."""
+    not in the model's result), and the rows of those buffers the layers
+    moved (a layer call moves the shortest prefix of its buffer that holds
+    its slots, ``ops/moe.py::expert_rungs``; the routed slots over the rows
+    is the fill of what was moved, the rest is padding the expert layer's
+    gather, grouped products and scatter-add carried)."""
     reg = default_registry()
     for i, n in enumerate(slots):
         reg.counter(
@@ -321,7 +323,7 @@ def publish_route(slots, overflow: int, rows: int, *, first_expert: int = 0) -> 
     ).inc(float(overflow))
     reg.counter(
         "fedtpu_moe_buffer_rows_total",
-        help="rows the held experts' shared buffers offered, summed over layers and launches",
+        help="rows of the held experts' shared buffers that the layers moved, summed over layers and launches",
     ).inc(float(rows))
 
 

@@ -47,7 +47,9 @@ from detecting_cyber_attacks_with_distilled_large_language_models_in_distributed
     kda_recurrent,
 )
 from detecting_cyber_attacks_with_distilled_large_language_models_in_distributed_networks_tpu.ops.moe import (
+    ROUTE_CHOICE,
     expert_capacity,
+    expert_rungs,
     held_experts_ffn,
     route_topk,
 )
@@ -299,23 +301,135 @@ def test_no_token_is_dropped_when_every_token_goes_to_held_experts():
     cap = expert_capacity(T, k, 16, held)  # 4 x the mean total of 20 rows
     assert cap == T * k  # never more than a token's slots that can be held
     assert expert_capacity(16384, 8, 256, 8) == 16384 and expert_capacity(T, k, 64, held) == 24
-    y, slots, overflow = held_experts_ffn(x, idx, w, valid, wg, wu, wd, offset=0, capacity=cap, dtype=jnp.float32)
+    y, slots, overflow, _ = held_experts_ffn(x, idx, w, valid, wg, wu, wd, offset=0, capacity=cap, dtype=jnp.float32)
     assert int(overflow) == 0 and slots.tolist() == [0, T, T, 0]
     assert float(jnp.abs(y - dense).max()) < 1e-5
-    y, slots, overflow = held_experts_ffn(x, idx, w, valid, wg, wu, wd, offset=0, capacity=56, dtype=jnp.float32)
+    y, slots, overflow, _ = held_experts_ffn(x, idx, w, valid, wg, wu, wd, offset=0, capacity=56, dtype=jnp.float32)
     assert int(overflow) == 2 * T - 56 and slots.tolist() == [0, T, T, 0]
     # expert 1's 40 slots are all in; of expert 2's the first 16 tokens'
     only_1 = w[:, :1] * ((jax.nn.silu(x @ wg[1]) * (x @ wu[1])) @ wd[1])
     assert float(jnp.abs(y[16:] - only_1[16:]).max()) < 1e-5 and float(jnp.abs(y[:16] - dense[:16]).max()) < 1e-5
     # padding is routed nowhere
     valid[30:] = False
-    y, slots, _ = held_experts_ffn(x, idx, w, valid, wg, wu, wd, offset=0, capacity=cap, dtype=jnp.float32)
+    y, slots, _, _ = held_experts_ffn(x, idx, w, valid, wg, wu, wd, offset=0, capacity=cap, dtype=jnp.float32)
     assert slots.tolist() == [0, 30, 30, 0] and float(jnp.abs(y[30:]).max()) == 0.0
     # gradients reach the experts' weights, the rows and the gate weights
     f = lambda x, w, wg: held_experts_ffn(x, idx, w, np.ones(T, bool), wg, wu, wd, offset=0, capacity=cap, dtype=jnp.float32)[0].sum()  # noqa: E731
     g = lambda x, w, wg: sum((w[:, j : j + 1] * ((jax.nn.silu(x @ wg[e]) * (x @ wu[e])) @ wd[e])).sum() for j, e in enumerate((1, 2)))  # noqa: E731
     for a, b in zip(jax.grad(f, (0, 1, 2))(x, w, wg), jax.grad(g, (0, 1, 2))(x, w, wg)):
         assert _rel(a, b) < 1e-5
+
+
+def test_the_ladder_is_the_capacity_at_one_and_four_times_the_mean():
+    """The three window cells' ladders, a bound that cuts the top short, and a
+    shape whose two rungs are one."""
+    assert expert_rungs(16384, 8, 256, 8) == (4096, 16384)
+    assert expert_rungs(16384, 8, 256, 32) == (16384, 65536)
+    assert expert_rungs(16384, 10, 512, 32) == (10240, 40960)
+    for args in ((16384, 8, 256, 8), (64, 2, 32, 4), (40, 2, 16, 4), (3, 2, 4, 4)):
+        assert expert_rungs(*args)[-1] == expert_capacity(*args)
+    assert expert_rungs(40, 2, 16, 4) == (24, 80) and expert_rungs(40, 2, 8, 4) == (40, 80)  # T * k bounds the top
+    assert expert_rungs(3, 2, 4, 4) == (6,)
+
+
+#: 64 tokens' two slots over 4 held of 32 experts, a mean total of 16 rows, with a rung between the program's two:
+#: ``held_experts_ffn`` takes whatever prefixes it is handed.
+LADDER = (16, 32, 64)
+
+
+def _filling(n, T=64):
+    """A routing whose first ``n`` token-slots name held experts (a token's
+    two name two experts) and whose others name absent ones."""
+    idx = np.empty((T, 2), np.int32)
+    for t in range(T):
+        for j in range(2):
+            idx[t, j] = (t % 2) * 2 + j if 2 * t + j < n else 8 + j
+    return idx
+
+
+@pytest.mark.parametrize("rung", range(3))
+@pytest.mark.parametrize("beside", [-1, 0, 1])
+def test_a_call_moves_the_shortest_rung_that_holds_its_slots(rung, beside):
+    """Filled to one row under a rung, to the rung and to one row over it: the
+    call takes the shortest rung that holds the slots (``rows`` says which),
+    and the result, the counts and the gradients in the rows, the slots'
+    weights and the experts' weights are the top rung's alone; one row over
+    the top is the overflow, counted as ever."""
+    assert (LADDER[0], LADDER[-1]) == expert_rungs(64, 2, 32, 4)
+    rng = np.random.default_rng(3)
+    T, D = 64, 8
+    filled = LADDER[rung] + beside
+    idx = _filling(filled)
+    x = rng.normal(size=(T, D)).astype(np.float32)
+    w = rng.uniform(0.2, 1.0, size=(T, 2)).astype(np.float32)
+    wg, wu, wd = _experts(rng, 4)
+    cot = rng.normal(size=(T, D)).astype(np.float32)
+
+    def layer(rungs):
+        return lambda x, w, wg: held_experts_ffn(  # noqa: E731
+            x, idx, w, np.ones(T, bool), wg, wu, wd, offset=0, capacity=LADDER[-1], dtype=jnp.float32, rungs=rungs
+        )
+
+    y, slots, overflow, rows = layer(LADDER)(x, w, wg)
+    top_y, top_slots, top_overflow, top_rows = layer(())(x, w, wg)
+    assert int(top_rows) == LADDER[-1]
+    assert int(rows) == next((r for r in LADDER if filled <= r), LADDER[-1])
+    assert int(slots.sum()) == filled and slots.tolist() == top_slots.tolist()
+    assert int(overflow) == int(top_overflow) == max(0, filled - LADDER[-1])
+    assert float(jnp.abs(y - top_y).max()) <= 1e-6 and float(jnp.abs(top_y).max()) > 0.1
+    grads = lambda rungs: jax.grad(lambda *a: (layer(rungs)(*a)[0] * cot).sum(), (0, 1, 2))(x, w, wg)  # noqa: E731
+    for a, b in zip(grads(LADDER), grads(())):
+        assert 1e-3 < float(jnp.abs(b).max()) and float(jnp.abs(a - b).max()) <= 1e-6 * max(1.0, float(jnp.abs(b).max()))
+
+
+def test_an_overflowing_call_moves_the_top_rung_and_counts_what_it_left_out():
+    """The overflowing case above (every token on experts 1 and 2, a buffer
+    of 56 rows) with a ladder beneath it: the whole buffer moves, the slots it
+    cannot take are counted, and what it took is what the top alone took."""
+    rng = np.random.default_rng(0)
+    T, held = 40, 4
+    x = rng.normal(size=(T, 8)).astype(np.float32)
+    wg, wu, wd = _experts(rng, held)
+    idx = np.tile(np.array([[1, 2]], np.int32), (T, 1))
+    w = rng.uniform(0.2, 1.0, size=(T, 2)).astype(np.float32)
+    call = lambda rungs: held_experts_ffn(  # noqa: E731
+        x, idx, w, np.ones(T, bool), wg, wu, wd, offset=0, capacity=56, dtype=jnp.float32, rungs=rungs
+    )
+    y, slots, overflow, rows = call((16, 32, 56, 80))  # a rung that is no prefix of the buffer is left out
+    assert int(rows) == 56 and int(overflow) == 2 * T - 56 and slots.tolist() == [0, T, T, 0]
+    assert float(jnp.abs(y - call(())[0]).max()) <= 1e-6
+    # and half the tokens padding: 40 slots, the middle rung
+    valid = np.arange(T) < 20
+    _, slots, overflow, rows = held_experts_ffn(
+        x, idx, w, valid, wg, wu, wd, offset=0, capacity=56, dtype=jnp.float32, rungs=(16, 40)
+    )
+    assert int(rows) == 40 and int(overflow) == 0 and slots.tolist() == [0, 20, 20, 0]
+
+
+def test_a_recomputed_layer_takes_the_rung_its_forward_pass_took():
+    """Under ``jax.checkpoint`` that keeps the router's choice by name (what
+    ``models/blocks.py::decoder`` does to a block) the gradients are the
+    un-checkpointed layer's: the rung is chosen again from the kept ``idx``."""
+    rng = np.random.default_rng(4)
+    T, D, E, held, k = 64, 8, 32, 4, 2
+    x = rng.normal(size=(T, D)).astype(np.float32)
+    router = rng.normal(size=(D, E)).astype(np.float32)
+    wg, wu, wd = _experts(rng, held)
+    rungs = expert_rungs(T, k, E, held)
+
+    def layer(x, router, wg):
+        idx, w = route_topk(jax.nn.sigmoid(x @ router), 0.0, k, 1.0)
+        y, _, _, rows = held_experts_ffn(
+            x, idx, w, np.ones(T, bool), wg, wu, wd, offset=0, capacity=rungs[-1], dtype=jnp.float32, rungs=rungs
+        )
+        return (y**2).sum(), rows
+
+    kept = jax.checkpoint(layer, policy=jax.checkpoint_policies.save_only_these_names(ROUTE_CHOICE))
+    (_, rows), plain = jax.value_and_grad(layer, (0, 1, 2), has_aux=True)(x, router, wg)
+    (_, again), recomputed = jax.jit(jax.value_and_grad(kept, (0, 1, 2), has_aux=True))(x, router, wg)
+    assert int(rows) == int(again) == rungs[0] < rungs[-1]  # the lower rung, or the test tests nothing
+    for a, b in zip(recomputed, plain):
+        assert float(jnp.abs(a - b).max()) <= 1e-5 * float(jnp.abs(b).max()) and float(jnp.abs(b).max()) > 0
 
 
 @pytest.mark.parametrize("which, shares", [("kimi_linear", 4), ("laguna", 8)])
@@ -351,7 +465,7 @@ def test_the_shares_add_up_to_the_uncut_layer(tiny_params, which, shares):
     )
     total, seen = shared, 0
     for share in range(shares):
-        y, slots, overflow = part_of(held * share)
+        y, slots, overflow, _ = part_of(held * share)
         assert int(overflow) == 0
         total, seen = total + y, seen + int(slots.sum())
     assert seen == 60 * cfg.experts_per_token  # every slot lands on exactly one share

@@ -13,8 +13,10 @@ the scopes the program's own files opened while the step was traced, and
 every literal a ``jax.named_scope(`` of ``models/``, ``ops/`` and ``train/``
 holds, are in the vocabulary; (d) the readers that read paths and not scopes
 give the hand-computed answer on a made-up table; (e) the buffer rows the
-expert layers count are ``expert_capacity`` a layer call and reset with the
-slots.
+expert layers count are the rungs their calls took (``ops/moe.py::expert_rungs``)
+and reset with the slots; (f) a pass of an expert layer over its buffer is one
+``conditional`` of a branch a rung, and no instruction outside one moves the
+whole buffer.
 """
 
 from __future__ import annotations
@@ -38,7 +40,7 @@ from detecting_cyber_attacks_with_distilled_large_language_models_in_distributed
 from detecting_cyber_attacks_with_distilled_large_language_models_in_distributed_networks_tpu.obs import metrics as obs_metrics
 from detecting_cyber_attacks_with_distilled_large_language_models_in_distributed_networks_tpu.obs.trace import SCOPES
 from detecting_cyber_attacks_with_distilled_large_language_models_in_distributed_networks_tpu.ops.causal_attention import KERNEL_SCOPE
-from detecting_cyber_attacks_with_distilled_large_language_models_in_distributed_networks_tpu.ops.moe import expert_capacity
+from detecting_cyber_attacks_with_distilled_large_language_models_in_distributed_networks_tpu.ops.moe import expert_rungs
 from detecting_cyber_attacks_with_distilled_large_language_models_in_distributed_networks_tpu.train.engine import Trainer
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -68,6 +70,13 @@ NEW_SCOPES = {
     "laguna-window-fit-l8k": ("qkv", "rope", "out"),  # the scopes were there; their metrics are new
 }
 EVERYWHERE = ("moe/experts/dispatch", "moe/experts/grouped", "moe/experts/combine")
+#: Scopes with no instruction in a block's recomputation. The buffer's pass is
+#: differentiated by hand (``ops/moe.py::_buffer_pass``): its backward rule
+#: computes the chosen rung's forward again itself, at the rung's length, so the
+#: recomputation's own switch has no consumer and is gone from the program;
+#: what a recomputation keeps under ``dispatch`` is the slots' index arithmetic,
+#: which the rule's switch is chosen by.
+NEVER_RECOMPUTED = {"moe/experts/grouped", "moe/experts/combine"}
 B, L = 2, 64
 
 
@@ -108,11 +117,12 @@ def programs():
                 _opened.add(name)
             return real(name)
 
+        jax.clear_caches()  # a cell traces every body itself: ``ops/moe.py``'s jitted rungs would come from the cell before
         with pytest.MonkeyPatch.context() as patch:
             patch.setattr(jax, "named_scope", recording)
             text = trainer.train_step.__wrapped__.lower(state, batch).compile().as_text()
         (paths,) = scope_ops.paths_by_program([text]).values()
-        out[cell] = {"trainer": trainer, "state": state, "paths": sorted(set(paths.values())), "opened": opened}
+        out[cell] = {"trainer": trainer, "state": state, "paths": sorted(set(paths.values())), "opened": opened, "text": text}
     return out
 
 
@@ -133,12 +143,16 @@ def test_a_scope_metrics_fragment_is_a_path_of_a_cell_that_lists_it(programs, me
     [(cell, scope) for cell in WINDOW_CELLS for scope in NEW_SCOPES[cell] + EVERYWHERE],
 )
 def test_a_new_scope_names_the_forward_the_recomputation_and_the_backward(programs, cell, scope):
-    paths = [p for p in programs[cell]["paths"] if under(scope).search(p)]
+    # (a jitted rung of ``ops/moe.py`` is lowered once for its call sites, and the combiner of a scatter-add
+    # inside it carries the rung's own scopes alone: a path that does not start at the step is no pass's)
+    paths = [p for p in programs[cell]["paths"] if under(scope).search(p) and p.startswith("jit(engine_train_step)/")]
     passes = {
         "forward": [p for p in paths if "transpose(jvp(" not in p and "/jvp(" in p],
         "recomputed": [p for p in paths if "transpose(jvp(" in p and "/rematted_computation/" in p],
         "backward": [p for p in paths if "transpose(jvp(" in p and "/rematted_computation/" not in p],
     }
+    if scope in NEVER_RECOMPUTED:
+        assert not passes.pop("recomputed")
     assert all(passes.values()), {k: len(v) for k, v in passes.items()}
     assert sum(len(v) for v in passes.values()) == len(paths)
 
@@ -238,7 +252,7 @@ def test_the_buffers_fill_is_the_registrys_slots_over_its_rows(monkeypatch):
 
 
 @pytest.mark.parametrize("cell", WINDOW_CELLS)
-def test_the_rows_counted_are_the_capacity_a_layer_call_and_reset_with_the_slots(programs, cell):
+def test_the_rows_counted_are_the_rungs_taken_and_reset_with_the_slots(programs, cell):
     trainer, state = programs[cell]["trainer"], programs[cell]["state"]
     cfg = trainer.model_cfg
     moe_layers = sum(1 for i in range(cfg.n_layers) if cfg.is_moe(i))
@@ -249,9 +263,63 @@ def test_the_rows_counted_are_the_capacity_a_layer_call_and_reset_with_the_slots
     assert set(state.route) == {"slots", "overflow", "rows"}
     trainer.last_route = None
     state, _ = trainer.fit(state, split, batch_size=B, epochs=2)
-    steps = 2 * rows // B
+    calls = moe_layers * 2 * rows // B
     route = trainer.last_route
-    capacity = expert_capacity(B * L, cfg.experts_per_token, cfg.n_experts, cfg.experts_held)
-    assert route["rows"] == capacity * moe_layers * steps
-    assert 0 < int(route["slots"].sum()) <= route["rows"] and route["overflow"] == 0
+    rungs = expert_rungs(B * L, cfg.experts_per_token, cfg.n_experts, cfg.experts_held)
+    low, top = rungs
+    # every call moved one of the rungs, the shorter if it held the call's slots: the rows are a sum of ``calls`` rung lengths
+    assert 0 < int(route["slots"].sum()) <= route["rows"] <= top * calls and route["overflow"] == 0
+    assert route["rows"] in {a * low + (calls - a) * top for a in range(calls + 1)}
+    assert route["rows"] < top * calls  # a quarter of the slots name a held expert: some call took the lower rung
     assert int(state.route["rows"]) == 0 and int(state.route["slots"].sum()) == 0  # read and started again
+
+
+def _computations(text: str) -> dict[str, list[str]]:
+    """A compiled program's text as ``{computation: its instructions' lines}``."""
+    out, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(.*\{$", line)
+        if head:
+            name = head.group(1)
+            out[name] = []
+        elif name and line.startswith("  "):
+            out[name].append(line)
+    return out
+
+
+@pytest.mark.parametrize("cell", WINDOW_CELLS)
+def test_a_pass_over_the_buffer_is_one_conditional_of_a_branch_a_rung(programs, cell):
+    """In the compiled step every expert layer holds two ``conditional``s of
+    a branch a rung (the forward pass and the backward rule; the
+    recomputation's has no consumer), and the layer's every gather and scatter
+    of rows of the width ``D`` is an instruction of a branch: outside the
+    switch nothing moves the buffer's rows, at the capacity or at a rung."""
+    cfg = programs[cell]["trainer"].model_cfg
+    moe_layers = sum(1 for i in range(cfg.n_layers) if cfg.is_moe(i))
+    rungs = expert_rungs(B * L, cfg.experts_per_token, cfg.n_experts, cfg.experts_held)
+    comps = _computations(programs[cell]["text"])
+    callee = r"(?:calls|to_apply|body|condition|branch_computations|true_computation|false_computation)=(\{[^}]*\}|%[\w.\-]+)"
+    called = lambda line: re.findall(r"%([\w.\-]+)", " ".join(re.findall(callee, line)))  # noqa: E731
+    # the expert layers' own (off the TPU the interpreted kernels hold conditionals too)
+    switches = [line for lines in comps.values() for line in lines if re.search(r" conditional\(", line) and "/moe/experts/cond" in line]
+    assert len(switches) == 2 * moe_layers
+    inside, todo = set(), []
+    for line in switches:
+        branches = called(line)  # two branches are a true and a false computation
+        assert len(branches) == len(rungs) == 2
+        todo += branches
+    while todo:
+        name = todo.pop()
+        if name not in inside:
+            inside.add(name)
+            todo += [c for line in comps[name] for c in called(line)]
+    wide = re.compile(r" = \w+\[(\d+),(?:1,)?%d\]\S* (gather|scatter)\(" % cfg.dim)  # rows of the layer's width
+    moved = {rows: 0 for rows in rungs}
+    for name, lines in comps.items():
+        for line in lines:
+            m = wide.search(line)
+            if m and "/moe/experts/" in line:
+                assert name in inside, line
+                moved[int(m.group(1))] = moved.get(int(m.group(1)), 0) + (m.group(2) == "gather")
+    # the rungs' gathers (the rows in; the cotangent's rows in the rule), and beside them only the scatter-adds per token
+    assert all(moved[rows] >= 2 * moe_layers for rows in rungs) and set(moved) <= {*rungs, B * L + 1}, moved

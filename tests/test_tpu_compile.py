@@ -14,7 +14,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
-from detecting_cyber_attacks_with_distilled_large_language_models_in_distributed_networks_tpu.ops import kda
+from detecting_cyber_attacks_with_distilled_large_language_models_in_distributed_networks_tpu.ops import kda, moe
 from detecting_cyber_attacks_with_distilled_large_language_models_in_distributed_networks_tpu.ops.causal_attention import (
     BLOCK,
     causal_attention,
@@ -140,3 +140,56 @@ def test_the_blocked_attention_compiles_for_the_v5e_at_the_cells_widths(
     # delta, the log-sum-exp and the key bias; of the latent attention also the copies a compile of the
     # function alone makes of its 192-wide parameters (the XLA blocks of PR 32: 2.0-2.5 GB, compiled here)
     assert compiled.memory_analysis().temp_size_in_bytes < (0.4e9 if dqk == dv else 1.2e9)
+
+
+@pytest.mark.parametrize(
+    "D, F, held, k, E",
+    [(2304, 1024, 8, 8, 256), (2048, 512, 32, 8, 256), (2048, 512, 32, 10, 512)],
+    ids=["kimilinear-8-of-256", "laguna-32-of-256", "qwen3next-32-of-512"],
+)
+def test_the_expert_buffers_ladder_compiles_for_the_v5e_and_holds_one_rungs_residuals(one_chip, D, F, held, k, E):
+    """A step's 16,384 tokens through ``held_experts_ffn`` at the three window
+    cells' widths (buffers of 16,384, 65,536 and 40,960 rows), float32 weights
+    and bfloat16 products, value and gradient under the block's
+    ``jax.checkpoint``: the pass over the buffer is a ``conditional`` of two
+    branches in the forward pass and one in the backward rule (the
+    recomputation's has no consumer), and the program's temporaries are the
+    top rung's alone, give or take what the scheduler does with a switch. A
+    ``switch`` differentiated by JAX hands back every rung's residuals from
+    every branch and, with three rungs, held 3.5 times the top rung's here
+    (5.26 GB against 1.49 at the Laguna cell's widths)."""
+    import re
+
+    T = 16384
+    arg = lambda dtype, *shape: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    rungs = moe.expert_rungs(T, k, E, held)
+    assert len(rungs) == 2 and rungs[-1] == moe.expert_capacity(T, k, E, held)
+
+    def compiled(ladder):
+        @jax.checkpoint
+        def layer(x, scores, valid, wg, wu, wd):
+            idx, w = moe.route_topk(scores, 0.0, k, 1.0)
+            y, _, _, rows = moe.held_experts_ffn(
+                x, idx, w, valid, wg, wu, wd, offset=0, capacity=rungs[-1], dtype=bf16, rungs=ladder
+            )
+            return y.astype(bf16), rows
+
+        def both(x, scores, valid, wg, wu, wd, cot):
+            loss = lambda x, scores, wg, wu, wd: (lambda y, rows: ((y * cot).astype(f32).sum(), rows))(  # noqa: E731
+                *layer(x, scores, valid, wg, wu, wd)
+            )
+            return jax.value_and_grad(loss, (0, 1, 2, 3, 4), has_aux=True)(x, scores, wg, wu, wd)
+
+        return jax.jit(both).lower(
+            arg(bf16, T, D), arg(f32, T, E), arg(jnp.bool_, T), arg(f32, held, D, F), arg(f32, held, D, F),
+            arg(f32, held, F, D), arg(bf16, T, D),
+        ).compile()
+
+    whole, top = compiled(rungs), compiled(())
+    # a switch of two is a conditional of a true and a false computation, of more one of ``branch_computations``
+    branches = r"branch_computations=\{[^}]*\}|(?:true|false)_computation=%[\w.\-]+"
+    switches = [line for line in whole.as_text().splitlines() if " conditional(" in line]
+    assert [len(re.findall(r"%", "".join(re.findall(branches, line)))) for line in switches] == [2, 2]
+    assert " conditional(" not in top.as_text()
+    assert whole.memory_analysis().temp_size_in_bytes < 1.3 * top.memory_analysis().temp_size_in_bytes
