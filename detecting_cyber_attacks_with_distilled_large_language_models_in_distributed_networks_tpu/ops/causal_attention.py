@@ -2,22 +2,27 @@
 their value width (latent attention: 192 against 128), for query heads that
 share a key/value head in groups, and for a sliding window.
 
-Which path runs is decided by the shapes, by nothing a caller sets:
+Which path runs is decided by the shapes, by nothing a caller sets
+(``ops/flash_attention.py::causal_path``):
 
-* **no window, a length that tiles** (``ops/flash_attention.py::causal_tile``:
-  a multiple of 128 whose head fits the kernels' VMEM, which hold a whole
-  head's row: 128-wide heads at 8,192 tokens and 192 / 128-wide ones at 4,096,
-  two of the window cells' shapes): the Pallas kernels ``flash_fwd`` / ``flash_bwd``
+* **no window, a length that tiles, a head that fits** (a multiple of 128
+  whose whole head fits the resident kernels' VMEM: 128-wide heads at 8,192
+  tokens and at 4,096, 192 / 128-wide ones at 4,096, the Laguna, Olmo-Hybrid
+  and Kimi cells' shapes): the Pallas kernels ``flash_fwd`` / ``flash_bwd``
   (``ops/flash_attention.py``), under the named scope :data:`KERNEL_SCOPE`. A
   query tile's scores, softmax and product with the values stay in VMEM, key
   tile by key tile with the running maximum and sum; key tiles after a query
   tile are not visited; the backward pass recomputes score tiles from ``q``,
   ``k``, the key mask and the rows' log-sum-exp. The result and the
   log-sum-exp carry :data:`ATTENTION_RESULT` as their ``checkpoint_name``.
-* **a window, a head too wide or a row too long for that VMEM** (a 256-wide
-  head passes the budget from 8,192 tokens on: the third window cell's gated
-  attention at 16,384), **or any other length** (the tiny presets of the CPU
-  tests): the XLA query blocks below. (A window of 512 in the kernels measured nothing of
+* **no window, a length that tiles, a head too wide or a row too long for
+  that VMEM** (a 256-wide head from 8,192 tokens on: the Qwen3-Next cell's
+  gated attention at 16,384): the same algorithm in the streamed kernels
+  ``flash_fwd_streamed``, ``flash_dkv_streamed`` and ``flash_dq_streamed``,
+  which copy a head's tiles in from HBM one at a time, under the same scope
+  and with the same residuals and their names.
+* **a window, or any other length** (the tiny presets of the CPU tests): the
+  XLA query blocks below. (A window of 512 in the kernels measured nothing of
   the Laguna cell's step for 0.8 GB more of temporaries: PERF.md, PR 33.)
 
 The XLA blocks: the dot path would hold ``[B, H, L, L]`` scores (4.3 GB in
@@ -54,7 +59,7 @@ import jax
 import jax.numpy as jnp
 
 from .attention import NEG_INF
-from .flash_attention import causal_flash_attention, causal_tile
+from .flash_attention import causal_flash_attention, causal_path
 
 #: Query rows a block: an implementation size (the scores of a block of a
 #: 4,096-token row at batch 4 and 32 heads are 0.5 GB in float32).
@@ -87,10 +92,10 @@ def causal_attention(q, k, v, key_mask, window: int | None = None):
     Hkv = k.shape[1]
     G = H // Hkv
     pad_bias = (1.0 - key_mask.astype(jnp.float32)) * NEG_INF  # [B, L]
-    tile = None if window is not None else causal_tile(L, q.shape[3], v.shape[3], q.dtype.itemsize)
-    if tile is not None:
+    path, tile = causal_path(L, q.shape[3], v.shape[3], q.dtype.itemsize, window)
+    if path != "blocks":
         with jax.named_scope(KERNEL_SCOPE):
-            return causal_flash_attention(q, k, v, pad_bias, tile, ATTENTION_RESULT)
+            return causal_flash_attention(q, k, v, pad_bias, tile, ATTENTION_RESULT, path == "streamed")
     scale = q.shape[-1] ** -0.5
     # A windowed row's block, and the keys it meets before its own rows', in whole blocks.
     block_w = min(BLOCK, L)

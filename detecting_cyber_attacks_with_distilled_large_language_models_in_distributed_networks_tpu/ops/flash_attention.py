@@ -1,8 +1,10 @@
-"""Pallas flash attention for the TPU: one forward and one backward kernel.
+"""Pallas flash attention for the TPU: kernels that hold a whole head in VMEM,
+and causal ones that stream a head tile by tile from HBM.
 
-No ``[Lq, Lk]`` score ever reaches HBM. Both kernels hold a head's keys and
-values in VMEM and walk score tiles, bf16 (the inputs' type) into the MXU
-with float32 accumulation, the softmax statistics in float32:
+No ``[Lq, Lk]`` score ever reaches HBM. Every kernel walks score tiles, bf16
+(the inputs' type) into the MXU with float32 accumulation, the softmax
+statistics in float32. The resident pair holds a head's keys and values in
+VMEM:
 
 * ``flash_fwd`` - grid over (row, query head, query tile): a query tile
   meets its key tiles one after the other with the running maximum, sum and
@@ -16,21 +18,45 @@ with float32 accumulation, the softmax statistics in float32:
   and feeds all three gradients; ``dq``, ``dk`` and ``dv`` of a head
   accumulate in float32 in VMEM. ``delta = rowsum(dO * O)`` comes from XLA.
 
-Who calls it, and which path runs when:
+The streamed kernels (causal only) hold one tile of each operand, and the
+float32 statistics and accumulators of one output tile as VMEM scratch; the
+grid's last axes walk the tiles and the pipeline copies them in from HBM, so
+what a launch asks of VMEM does not grow with the length:
 
-* ``ops/causal_attention.py::causal_attention`` without a window, when
-  :func:`causal_tile` finds a tile for the length (``causal=True``: key tiles
-  wholly after a query tile are not visited, the loop bounds skip them; the
-  diagonal tile is masked in registers; query head ``h`` reads key head ``h
-  // group`` through the key block's index map and ``dk`` / ``dv`` sum over
-  the group in VMEM; ``dqk`` may differ from ``dv``). This is the path of the
-  window cells (``laguna-window-fit-l8k``'s full layers, the Kimi cell's
-  latent attention). A sliding window stays on that module's XLA blocks: in
-  these kernels it measured 16.3 against 26.4 ms a layer alone and nothing
-  of a step in the cell, for 0.8 GB more of temporaries (PERF.md, PR 33).
+* ``flash_fwd_streamed`` - grid over (row, query head, query tile, key
+  tile): the same online softmax, result and log-sum-exp as ``flash_fwd``.
+* ``flash_dkv_streamed`` - grid over (row, key head, key tile, query head of
+  the group, query tile): ``dk`` and ``dv`` of a key tile summed in VMEM over
+  the group's query heads and the query tiles, score tiles transposed as in
+  ``flash_bwd``.
+* ``flash_dq_streamed`` - grid over (row, query head, query tile, key tile):
+  ``dq`` of a query tile summed over its key tiles. A second walk, because a
+  ``dq`` streamed out tile by tile cannot be summed in place by the key-tile
+  walk of ``dk`` and ``dv``.
+
+Key tiles wholly after a query tile are not computed: their grid steps repeat
+the diagonal tile's block index, so no copy is issued, and do nothing. Query
+head ``h`` reads key head ``h // group`` through the key block's index map.
+
+Who calls it, and which path runs when (:func:`causal_path`, from the shapes
+alone):
+
+* ``ops/causal_attention.py::causal_attention`` without a window, when a tile
+  of :data:`CAUSAL_TILES` divides the length (``causal=True``: the diagonal
+  tile is masked in registers; ``dk`` / ``dv`` sum over the group in VMEM;
+  ``dqk`` may differ from ``dv``): the resident pair where a whole head fits
+  :data:`VMEM_BUDGET` (``laguna-window-fit-l8k``'s and
+  ``olmohybrid-window-fit-l4k``'s full layers, the Kimi cell's latent
+  attention), the streamed kernels where it does not
+  (``qwen3next-window-fit-l16k``'s 256-wide gated attention at 16,384
+  tokens: 186 MiB for a head). A sliding window stays on that module's XLA
+  blocks: in these kernels it measured 16.3 against 26.4 ms a layer alone and
+  nothing of a step in the cell, for 0.8 GB more of temporaries (PERF.md
+  §6).
 * :func:`flash_attention`, the encoder's ``ModelConfig.attention_impl="flash"``
-  (no cell runs it): every key tile, attention dropout, a gradient for the
-  key bias. Lengths that do not tile take the XLA dot path.
+  (no cell runs it): the resident pair over every key tile, attention
+  dropout, a gradient for the key bias. Lengths that do not tile take the XLA
+  dot path.
 
 Attention dropout: a counter-based hash (murmur-style finalizer) over the
 GLOBAL (batch, head, q, k) position and a per-call seed - forward and
@@ -81,6 +107,19 @@ CAUSAL_TILES = (512, 256, 128)
 #: What one launch may ask of the v5e's 128 MiB of VMEM (the backward kernel
 #: holds a head's q, k, v, dO and three float32 accumulators there).
 VMEM_BUDGET = 96 * 2**20
+#: Score tiles of the streamed kernels, largest first (query and key tiles the
+#: same size, as in :data:`CAUSAL_TILES`): implementation sizes, measured on
+#: the v5e at the Qwen3-Next cell's shape, where a head's forward and gradient
+#: took 61.3 ms at 1,024 and 68.0 at 512 (PERF.md §6).
+STREAM_TILES = (1024, 512, 256, 128)
+#: Buffers the pipeline keeps of each block a streamed kernel walks (the key
+#: tiles of the forward and of ``dq``, the query tiles of ``dk`` / ``dv``);
+#: Mosaic lowers one or two, not three.
+STREAM_BUFFERS = 2
+#: What one launch of the streamed kernels may ask of VMEM: those tiles, the
+#: float32 accumulators of one tile and the score tile's temporaries, whatever
+#: the length.
+STREAM_VMEM = 64 * 2**20
 
 
 def _fit(block: int, length: int) -> int:
@@ -118,13 +157,22 @@ def _vmem_bytes(lq: int, lk: int, dqk: int, dv: int, itemsize: int) -> int:
     return 2 * blocks + scratch + 2 * 4 * 128 * lk + 4 * 4 * 8 * lq + 8 * 4 * 512 * 512
 
 
-def causal_tile(length: int, dqk: int, dv: int, itemsize: int) -> int | None:
-    """The score tile the causal path runs a row of ``length`` tokens with, or
-    None where the row takes the XLA blocks: no tile of :data:`CAUSAL_TILES`
-    divides it, or a head does not fit :data:`VMEM_BUDGET`."""
-    if _vmem_bytes(length, length, dqk, dv, itemsize) > VMEM_BUDGET:
-        return None
-    return next((t for t in CAUSAL_TILES if length % t == 0), None)
+def causal_path(length: int, dqk: int, dv: int, itemsize: int, window: int | None = None) -> tuple[str, int | None]:
+    """Which path a causal row of ``length`` tokens takes, read from its shapes
+    alone, and its score tile: ``("resident", tile)`` where a tile of
+    :data:`CAUSAL_TILES` divides the length and a whole head fits
+    :data:`VMEM_BUDGET` (``flash_fwd`` / ``flash_bwd``); ``("streamed",
+    tile)`` where a tile divides it and the head does not fit
+    (``flash_fwd_streamed``, ``flash_dkv_streamed``, ``flash_dq_streamed``,
+    the largest of :data:`STREAM_TILES` that divides the length);
+    ``("blocks", None)`` with a window or where no tile divides it (the XLA
+    query blocks of ``ops/causal_attention.py``)."""
+    tile = next((t for t in CAUSAL_TILES if length % t == 0), None)
+    if window is not None or tile is None:
+        return "blocks", None
+    if _vmem_bytes(length, length, dqk, dv, itemsize) <= VMEM_BUDGET:
+        return "resident", tile
+    return "streamed", next(t for t in STREAM_TILES if length % t == 0)
 
 
 def _keep_mask(seed, b, h, q0, k0, shape: tuple[int, int], q_axis: int, rate: float):
@@ -306,11 +354,126 @@ def _bwd_kernel(
         dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
 
 
+def _column(row):
+    """A lane-dense ``[1, n]`` row of statistics as a ``[n, 128]`` column block
+    (every lane the same number)."""
+    return jnp.transpose(jnp.broadcast_to(row, (128, row.shape[1])))
+
+
+def _stream_fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, m_acc, l_acc, o_acc, *, scale: float):
+    """Query tile ``i`` (``q``, ``o``: ``[t, d]``) meets key tile ``j`` (``k``,
+    ``v``: ``[t, d]``; ``bias``: ``[1, t]``), the grid's last axis; the online
+    softmax's maximum, sum and result live in VMEM scratch from ``j == 0`` to
+    the diagonal tile ``j == i``, which writes the result and the rows'
+    log-sum-exp (``lse``: ``[1, t]``). Steps ``j > i`` do nothing."""
+    i, j = pl.program_id(2), pl.program_id(3)
+
+    @pl.when(j == 0)
+    def _():
+        m_acc[...] = jnp.full_like(m_acc, -jnp.inf)
+        l_acc[...] = jnp.zeros_like(l_acc)
+        o_acc[...] = jnp.zeros_like(o_acc)
+
+    def tile(diagonal: bool):
+        v = v_ref[...]
+        s = _dot(q_ref[...], k_ref[...], (1, 1)) * scale + bias_ref[...]  # [t, t] fp32
+        if diagonal:
+            s = s + _later(s.shape, 0)
+        m = m_acc[...]
+        m_new = jnp.maximum(m, s.max(axis=1, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.exp(s - m_new)
+        l_acc[...] = l_acc[...] * alpha + p.sum(axis=1, keepdims=True)
+        o_acc[...] = o_acc[...] * alpha + _dot(p.astype(v.dtype), v, (1, 0))
+        m_acc[...] = m_new
+
+    pl.when(j < i)(lambda: tile(False))
+
+    @pl.when(j == i)
+    def _():
+        tile(True)
+        l = l_acc[...]
+        o_ref[...] = (o_acc[...] / l).astype(o_ref.dtype)
+        lse_ref[...] = jnp.transpose(jnp.broadcast_to(m_acc[...] + jnp.log(l), (l.shape[0], 128)))[:1]
+
+
+def _stream_dkv_kernel(
+    q_ref, k_ref, v_ref, bias_ref, lse_ref, delta_ref, do_ref, dk_ref, dv_ref, dk_acc, dv_acc, *, scale: float
+):
+    """Key tile ``j`` of a key head (``k``, ``v``, ``dk``, ``dv``: ``[t, d]``;
+    ``bias``: ``[t, 1]``) meets query tile ``i`` (``q``, ``dO``: ``[t, d]``;
+    ``lse``, ``delta``: ``[1, t]``) of the group's query head ``g``, the
+    grid's last two axes; score tiles recomputed transposed (``[keys,
+    queries]``), ``dk`` / ``dv`` summed in VMEM scratch over every ``g`` and
+    ``i >= j`` and written at the last step. Steps ``i < j`` do nothing."""
+    j, g, i = pl.program_id(2), pl.program_id(3), pl.program_id(4)
+
+    @pl.when((g == 0) & (i == 0))
+    def _():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    def tile(diagonal: bool):
+        q, do = q_ref[...], do_ref[...]
+        s = _dot(k_ref[...], q, (1, 1)) * scale + bias_ref[...]  # [t, t] fp32
+        if diagonal:
+            s = s + _later(s.shape, 1)
+        p = jnp.exp(s - lse_ref[...])  # normalized weights (softmax columns)
+        dp = _dot(v_ref[...], do, (1, 1))  # v @ dO^T
+        ds = p * (dp - delta_ref[...])  # softmax jacobian
+        dv_acc[...] += _dot(p.astype(do.dtype), do, (1, 0))
+        dk_acc[...] += _dot((ds * scale).astype(q.dtype), q, (1, 0))
+
+    pl.when(i > j)(lambda: tile(False))
+    pl.when(i == j)(lambda: tile(True))
+
+    @pl.when((g == pl.num_programs(3) - 1) & (i == pl.num_programs(4) - 1))
+    def _():
+        dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
+
+
+def _stream_dq_kernel(
+    q_ref, k_ref, v_ref, bias_ref, lse_ref, delta_ref, do_ref, dq_ref, dq_acc, lse_col, delta_col, *, scale: float
+):
+    """Query tile ``i`` (``q``, ``dO``, ``dq``: ``[t, d]``; ``lse``,
+    ``delta``: ``[1, t]``, turned into columns in VMEM at ``j == 0``) meets
+    key tile ``j`` (``k``, ``v``: ``[t, d]``; ``bias``: ``[1, t]``), the
+    grid's last axis; ``dq`` sums in VMEM scratch up to the diagonal tile,
+    which writes it. Steps ``j > i`` do nothing."""
+    i, j = pl.program_id(2), pl.program_id(3)
+
+    @pl.when(j == 0)
+    def _():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+        lse_col[...] = _column(lse_ref[...])
+        delta_col[...] = _column(delta_ref[...])
+
+    def tile(diagonal: bool):
+        k = k_ref[...]
+        s = _dot(q_ref[...], k, (1, 1)) * scale + bias_ref[...]  # [t, t] fp32
+        if diagonal:
+            s = s + _later(s.shape, 0)
+        p = jnp.exp(s - lse_col[:, :1])
+        dp = _dot(do_ref[...], v_ref[...], (1, 1))  # dO @ v^T
+        ds = p * (dp - delta_col[:, :1])
+        dq_acc[...] += _dot((ds * scale).astype(k.dtype), k, (1, 0))
+
+    pl.when(j < i)(lambda: tile(False))
+
+    @pl.when(j == i)
+    def _():
+        tile(True)
+        dq_ref[...] = dq_acc[...].astype(dq_ref.dtype)
+
+
 class _How(NamedTuple):
     """What a call of the kernels is, beside its arrays (static). ``keep_name``:
     the ``checkpoint_name`` the result and the rows' log-sum-exp carry as
     residuals, so that a caller's recomputation that keeps that name does not
-    launch the forward kernel again to have them."""
+    launch the forward kernel again to have them. ``streamed``: the causal
+    kernels that stream a head tile by tile (no dropout, no key-bias
+    gradient)."""
 
     rate: float
     causal: bool
@@ -319,6 +482,7 @@ class _How(NamedTuple):
     bias_grad: bool
     keep_name: str | None
     interpret: bool
+    streamed: bool = False
 
 
 def _head_block(rows: int, d: int, where):
@@ -404,31 +568,131 @@ def _flash_backward(q, k, v, bias, seed, out, lse, do, how: _How):
     return dq, dk, dv_, dbias
 
 
+def _walked_block(rows: int, d: int, where):
+    """:func:`_head_block` for the block a streamed kernel's last grid axis
+    walks, in :data:`STREAM_BUFFERS` buffers."""
+    return pl.BlockSpec(
+        (None, None, rows, d), lambda *grid: (*where(*grid), 0), pipeline_mode=pl.Buffered(STREAM_BUFFERS)
+    )
+
+
+def _stream_params(semantics: tuple[str, ...]):
+    return pltpu.CompilerParams(dimension_semantics=semantics, vmem_limit_bytes=STREAM_VMEM)
+
+
+def _query_walk(t: int, group: int):
+    """The blocks of a grid (row, query head, query tile, key tile) with the
+    key tile last: a query tile's rows ``(d)``, the key tiles it walks ``(d)``
+    (the diagonal tile's again after it), their key bias ``[1, t]`` and the
+    query tile's lane-dense statistics ``[1, t]``."""
+    rows = lambda d: _head_block(t, d, lambda bi, hi, qi, kj: (bi, hi, qi))  # noqa: E731
+    keys = lambda d: _walked_block(t, d, lambda bi, hi, qi, kj: (bi, hi // group, jnp.minimum(qi, kj)))  # noqa: E731
+    bias = pl.BlockSpec((None, 1, t), lambda bi, hi, qi, kj: (bi, 0, jnp.minimum(qi, kj)))
+    stats = pl.BlockSpec((None, None, None, 1, t), lambda bi, hi, qi, kj: (bi, hi, qi, 0, 0))
+    return rows, keys, bias, stats
+
+
+def _stream_forward(q, k, v, bias, seed, how: _How):
+    """:func:`_flash_forward`'s result and log-sum-exp, causal, a head's keys
+    and values streamed a tile at a time: grid (row, query head, query tile,
+    key tile); a key tile after the query tile repeats the diagonal tile's
+    block index, so no copy is issued for it."""
+    del seed
+    b, h, lq, dqk = q.shape
+    group, dv, t = h // k.shape[1], v.shape[3], how.block_q
+    rows, keys, key_bias, stats = _query_walk(t, group)
+    return pl.pallas_call(
+        functools.partial(_stream_fwd_kernel, scale=dqk**-0.5),
+        grid=(b, h, lq // t, lq // t),
+        in_specs=[rows(dqk), keys(dqk), keys(dv), key_bias],
+        out_specs=[rows(dv), stats],
+        out_shape=[
+            jax.ShapeDtypeStruct((b, h, lq, dv), q.dtype),
+            jax.ShapeDtypeStruct((b, h, lq // t, 1, t), jnp.float32),
+        ],
+        scratch_shapes=[pltpu.VMEM((t, 1), jnp.float32), pltpu.VMEM((t, 1), jnp.float32), pltpu.VMEM((t, dv), jnp.float32)],
+        compiler_params=_stream_params(("parallel", "parallel", "parallel", "arbitrary")),
+        name="flash_fwd_streamed",
+        interpret=how.interpret,
+    )(q, k, v, bias[:, None, :])
+
+
+def _stream_backward(q, k, v, bias, seed, out, lse, do, how: _How):
+    """:func:`_flash_backward` for the streamed path, in two kernels: ``dk`` and
+    ``dv`` over (row, key head, key tile, the group's query head, query tile),
+    ``dq`` over (row, query head, query tile, key tile); tiles before the
+    diagonal (after it, for ``dq``) repeat its block index and do nothing."""
+    del seed
+    b, h, lq, dqk = q.shape
+    hkv, dv = k.shape[1], v.shape[3]
+    group, t, n = h // hkv, how.block_q, lq // how.block_q
+    scale = dqk**-0.5
+    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1).reshape(lse.shape)
+
+    # dk, dv: key tile kj of key head ki against query tile qi of query head ki * group + gi
+    q_tile = lambda d: _walked_block(t, d, lambda bi, ki, kj, gi, qi: (bi, ki * group + gi, jnp.maximum(qi, kj)))  # noqa: E731
+    k_tile = lambda d: _head_block(t, d, lambda bi, ki, kj, gi, qi: (bi, ki, kj))  # noqa: E731
+    q_rows = pl.BlockSpec(
+        (None, None, None, 1, t), lambda bi, ki, kj, gi, qi: (bi, ki * group + gi, jnp.maximum(qi, kj), 0, 0)
+    )
+    dk, dv_ = pl.pallas_call(
+        functools.partial(_stream_dkv_kernel, scale=scale),
+        grid=(b, hkv, n, group, n),
+        in_specs=[
+            q_tile(dqk), k_tile(dqk), k_tile(dv),
+            pl.BlockSpec((None, t, 1), lambda bi, ki, kj, gi, qi: (bi, kj, 0)),
+            q_rows, q_rows, q_tile(dv),
+        ],
+        out_specs=[k_tile(dqk), k_tile(dv)],
+        out_shape=[jax.ShapeDtypeStruct(a.shape, a.dtype) for a in (k, v)],
+        scratch_shapes=[pltpu.VMEM((t, dqk), jnp.float32), pltpu.VMEM((t, dv), jnp.float32)],
+        compiler_params=_stream_params(("parallel", "parallel", "parallel", "arbitrary", "arbitrary")),
+        name="flash_dkv_streamed",
+        interpret=how.interpret,
+    )(q, k, v, bias[:, :, None], lse, delta, do)
+
+    # dq: query tile qi of query head hi against key tile kj of key head hi // group
+    rows, keys, key_bias, stats = _query_walk(t, group)
+    dq = pl.pallas_call(
+        functools.partial(_stream_dq_kernel, scale=scale),
+        grid=(b, h, n, n),
+        in_specs=[rows(dqk), keys(dqk), keys(dv), key_bias, stats, stats, rows(dv)],
+        out_specs=rows(dqk),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        scratch_shapes=[pltpu.VMEM((t, dqk), jnp.float32), pltpu.VMEM((t, 128), jnp.float32), pltpu.VMEM((t, 128), jnp.float32)],
+        compiler_params=_stream_params(("parallel", "parallel", "parallel", "arbitrary")),
+        name="flash_dq_streamed",
+        interpret=how.interpret,
+    )(q, k, v, bias[:, None, :], lse, delta, do)
+    return dq, dk, dv_, jnp.zeros_like(bias)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
 def _flash(q, k, v, bias, seed, how: _How):
     return _flash_fwd(q, k, v, bias, seed, how._replace(keep_name=None))[0]
 
 
 def _flash_fwd(q, k, v, bias, seed, how):
-    out, lse = _flash_forward(q, k, v, bias, seed, how)
+    out, lse = (_stream_forward if how.streamed else _flash_forward)(q, k, v, bias, seed, how)
     if how.keep_name is not None:
         out, lse = checkpoint_name(out, how.keep_name), checkpoint_name(lse, how.keep_name)
     return out, (q, k, v, bias, seed, out, lse)
 
 
 def _flash_bwd(how, res, do):
-    return *_flash_backward(*res, do, how), np.zeros(res[4].shape, dtype=jax.dtypes.float0)
+    backward = _stream_backward if how.streamed else _flash_backward
+    return *backward(*res, do, how), np.zeros(res[4].shape, dtype=jax.dtypes.float0)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-def causal_flash_attention(q, k, v, bias, tile: int, keep_name: str | None = None):
+def causal_flash_attention(q, k, v, bias, tile: int, keep_name: str | None = None, streamed: bool = False):
     """``ops/causal_attention.py::causal_attention``'s no-window path for a
-    length that :func:`causal_tile` gave ``tile`` for; its shapes and result,
-    the key mask as its additive ``bias`` ``[B, L]``. Interpreted off the TPU,
-    so the CPU tests run the kernels."""
-    how = _How(0.0, True, tile, tile, False, keep_name, jax.default_backend() != "tpu")
+    length that :func:`causal_path` gave ``tile`` for (``streamed`` where it
+    said so); its shapes and result, the key mask as its additive ``bias``
+    ``[B, L]``. Interpreted off the TPU, so the CPU tests run the kernels."""
+    how = _How(0.0, True, tile, tile, False, keep_name, jax.default_backend() != "tpu", streamed)
     return _flash(q, k, v, bias, jnp.zeros((1, 2), jnp.uint32), how)
 
 

@@ -52,7 +52,7 @@ from detecting_cyber_attacks_with_distilled_large_language_models_in_distributed
 )
 from detecting_cyber_attacks_with_distilled_large_language_models_in_distributed_networks_tpu.ops import kda
 from detecting_cyber_attacks_with_distilled_large_language_models_in_distributed_networks_tpu.ops.flash_attention import (
-    causal_tile,
+    causal_path,
 )
 from detecting_cyber_attacks_with_distilled_large_language_models_in_distributed_networks_tpu.ops.rope import (
     apply_rope,
@@ -207,7 +207,7 @@ def test_the_cells_attention_takes_the_causal_kernels():
     """30 heads of 128 at 4,096 tokens tile and fit: the full layer runs
     ``flash_fwd`` / ``flash_bwd`` under ``causal_flash`` on the chip."""
     cut = OlmoHybridConfig.pp8_cut()
-    assert causal_tile(cut.max_len, cut.head_dim, cut.head_dim, 2) is not None
+    assert causal_path(cut.max_len, cut.head_dim, cut.head_dim, 2)[0] == "resident"
 
 
 # ------------------------------------------- the program and the reference

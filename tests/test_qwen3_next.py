@@ -42,9 +42,10 @@ from detecting_cyber_attacks_with_distilled_large_language_models_in_distributed
 )
 from detecting_cyber_attacks_with_distilled_large_language_models_in_distributed_networks_tpu.ops import kda
 from detecting_cyber_attacks_with_distilled_large_language_models_in_distributed_networks_tpu.ops.flash_attention import (
+    STREAM_TILES,
     VMEM_BUDGET,
     _vmem_bytes,
-    causal_tile,
+    causal_path,
 )
 from detecting_cyber_attacks_with_distilled_large_language_models_in_distributed_networks_tpu.ops.moe import (
     route_topk,
@@ -231,17 +232,18 @@ def test_the_zero_centred_norm_is_one_plus_its_weight():
 
 
 @pytest.mark.parametrize(
-    "length, dqk, dv, takes_the_kernels",
-    [(16384, 256, 256, False), (8192, 256, 256, False), (4096, 256, 256, True), (8192, 128, 128, True), (16384, 128, 128, False)],
+    "length, dqk, dv, path",
+    [(16384, 256, 256, "streamed"), (8192, 256, 256, "streamed"), (4096, 256, 256, "resident"), (8192, 128, 128, "resident"),
+     (16384, 128, 128, "streamed")],
     ids=["this-cell", "256-wide-at-8k", "256-wide-at-4k", "laguna-cell", "128-wide-at-16k"],
 )
-def test_which_rows_the_causal_kernels_take_is_pinned(length, dqk, dv, takes_the_kernels):
-    """``causal_tile`` sends a head to the XLA blocks when a whole head's row
-    passes the kernels' VMEM budget. Pinned to what the code does: the day a
-    kernel walks a long row tile by tile through HBM, this says so, and the
-    new cell's ``attn_kernel_share`` leaves 0."""
-    assert (causal_tile(length, dqk, dv, 2) is not None) is takes_the_kernels
-    assert (_vmem_bytes(length, length, dqk, dv, 2) <= VMEM_BUDGET) is takes_the_kernels
+def test_which_rows_the_causal_kernels_take_is_pinned(length, dqk, dv, path):
+    """``causal_path`` keeps a head on the resident kernels while a whole
+    head's row fits their VMEM budget, and streams it tile by tile from HBM
+    once it passes: this cell's 256-wide heads at 16,384 tokens stream, so its
+    ``attn_kernel_share`` is no longer 0."""
+    assert causal_path(length, dqk, dv, 2) == (path, 512 if path == "resident" else STREAM_TILES[0])
+    assert (_vmem_bytes(length, length, dqk, dv, 2) <= VMEM_BUDGET) is (path == "resident")
     if (length, dqk) in ((16384, 256), (8192, 256)):  # the two readings ROADMAP.md quotes
         assert round(_vmem_bytes(length, length, dqk, dv, 2) / 2**20) == {16384: 186, 8192: 97}[length]
 

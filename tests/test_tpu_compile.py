@@ -100,7 +100,7 @@ def test_the_undifferentiated_kda_forward_compiles_for_the_v5e_at_the_cells_widt
         ("full", 2, 48, 8, 8192, 128, 128, None),
         ("full", 2, 64, 8, 8192, 128, 128, None),
         ("full", 4, 32, 32, 4096, 192, 128, None),
-        ("blocks", 1, 16, 2, 16384, 256, 256, None),
+        ("streamed", 1, 16, 2, 16384, 256, 256, None),
         ("full", 1, 30, 30, 4096, 128, 128, None),
     ],
     ids=["window", "full-48-over-8", "full-64-over-8", "latent-192-128", "gated-256-at-16k", "nope-30-at-4k"],
@@ -120,9 +120,10 @@ def test_the_blocked_attention_compiles_for_the_v5e_at_the_cells_widths(
     cell, and nothing of the XLA blocks is left in the program: no block of
     float32 scores, none of their loops. ``qwen3next-window-fit-l16k``'s gated
     attention (1 row of 16,384 tokens, 16 query heads over 2 key/value heads of
-    256) passes the kernels' VMEM budget and is the XLA blocks: the group's 8
-    heads folded into a block's 2,048 rows, against the keys up to the group's
-    end, 268 MB of float32 scores a block."""
+    256, the key mask) passes the resident kernels' VMEM budget and takes the
+    three streamed kernels, ``flash_fwd_streamed``, ``flash_dkv_streamed`` and
+    ``flash_dq_streamed``, which hold a few tiles of a head in VMEM whatever
+    the length: no loop and no float32 score block in the program either."""
     import re
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the wrapper asks whether to interpret
@@ -142,14 +143,11 @@ def test_the_blocked_attention_compiles_for_the_v5e_at_the_cells_widths(
         assert compiled.memory_analysis().temp_size_in_bytes < 4e9
         assert keys and max(keys) == BLOCK + 512, sorted(keys)
         return
-    if kind == "blocks":
-        assert "tpu_custom_call" not in text and "while" in text
-        # a single row: the compiler drops the batch's axis of 1 from a block's scores
-        keys = {int(m.group(1)) for m in re.finditer(rf"f32\[{Hkv},{rows},(\d+)\]", text)} - {dqk, dv}
-        assert keys and max(keys) == L and rows == 2048, sorted(keys)
-        assert compiled.memory_analysis().temp_size_in_bytes < 3e9
-        return
-    assert text.count('custom_call_target="tpu_custom_call"') == 2 and "flash_fwd" in text and "flash_bwd" in text
+    kernels = ("flash_fwd_streamed", "flash_dkv_streamed", "flash_dq_streamed") if kind == "streamed" else ("flash_fwd", "flash_bwd")
+    assert text.count('custom_call_target="tpu_custom_call"') == len(kernels), kernels
+    assert all(f"/{name}/pallas_call" in text for name in kernels), kernels
+    # a single row: the compiler drops the batch's axis of 1 from a block's scores
+    keys |= {int(m.group(1)) for m in re.finditer(rf"f32\[{Hkv},{rows},(\d+)\]", text)} - {dqk, dv}
     assert not keys and "while" not in text, sorted(keys)
     # delta, the log-sum-exp and the key bias; of the latent attention also the copies a compile of the
     # function alone makes of its 192-wide parameters (the XLA blocks of PR 32: 2.0-2.5 GB, compiled here)
